@@ -1318,6 +1318,9 @@ def _spawn_fleet(ncoord, sf, concurrency, affinity="proxy"):
     map; returns (procs, uris) once every child reports ready."""
     import subprocess
 
+    from presto_tpu.parallel.mesh import refuse_cpu_children
+
+    refuse_cpu_children("bench.py's coordinator fleet")
     ports = _free_ports(ncoord)
     ids = [f"coord{i}" for i in range(ncoord)]
     uris = [f"http://127.0.0.1:{p}" for p in ports]
@@ -1329,7 +1332,7 @@ def _spawn_fleet(ncoord, sf, concurrency, affinity="proxy"):
                          for j in range(ncoord) if j != i}}
         env = dict(os.environ)
         env["BENCH_FLEET_CHILD"] = json.dumps(cfg)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         procs.append(subprocess.Popen(
             [sys.executable, os.path.abspath(__file__), "--serve-child"],
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
@@ -1789,6 +1792,8 @@ def multichip_bench(hosts=0):
         tpch_catalog(sf, cache_dir="/tmp/presto_tpu_cache"))
     worker = None
     if hosts >= 2:
+        # CPU worker processes: launch_local_cluster refuses under a
+        # TPU parent, so the record's platform is the workers' own
         ldev = int(os.environ.get("BENCH_MULTICHIP_LOCAL_DEVICES", "2"))
         ndev = hosts * ldev
         cs = C.launch_local_cluster(

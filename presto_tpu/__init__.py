@@ -26,9 +26,10 @@ jax.config.update("jax_enable_x64", True)
 # projections/filters; compiled classes are reused across queries).  XLA
 # compiles a whole fragment per (query shape, sf) — at SF100 a single
 # compile runs tens of minutes, so cold costs must be paid once per
-# machine, not once per process.  Dir from PRESTO_TPU_COMPILE_CACHE
-# (legacy alias PRESTO_TPU_XLA_CACHE, =0 disables) or the
-# compile_cache_dir session property, re-checked per query.
+# machine, not once per process.  Dir from JAX_COMPILATION_CACHE_DIR
+# where set (JAX reads it, nothing is set in code), else the
+# compile_cache_dir session property (re-checked per query),
+# PRESTO_TPU_COMPILE_CACHE (=0 disables) or <checkout>/.jax_cache.
 from presto_tpu.exec import compile_cache as _compile_cache  # noqa: E402
 
 _compile_cache.configure()

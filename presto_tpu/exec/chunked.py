@@ -1227,10 +1227,7 @@ class _FragmentRunner:
         across nodes (execution/scheduler/group/LifespanScheduler.java).
         Callers stream it over a _MeshGridView whose "chunks" are
         supersteps."""
-        try:
-            from jax import shard_map
-        except ImportError:  # moved to core in newer jax; 0.4.x path:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PS
 
         from presto_tpu.parallel.mesh import AXIS, make_mesh

@@ -6,16 +6,19 @@ compiled projections/filters in a guava cache keyed by the row expression
 (sql/gen/PageFunctionCompiler.java:105), and compiled classes are reused
 across queries for the life of the JVM.  Our XLA analogue compiles a
 whole fragment per (plan shape, chunk mult, mesh), which at SF100 runs
-into MINUTES per program (BENCH_r05: q64 938s cold vs 226s warm), so the
-compile bill must be paid once per MACHINE, not once per process — and
-never serially in front of a waiting query when it can overlap.
+into MINUTES per program, so the compile bill must be paid once per
+MACHINE, not once per process — and never serially in front of a
+waiting query when it can overlap.
 
 Three layers, all fronted by this module:
 
-1. the JAX persistent compilation cache (disk, keyed by HLO hash): wired
-   from `PRESTO_TPU_COMPILE_CACHE` (legacy alias `PRESTO_TPU_XLA_CACHE`)
-   or the `compile_cache_dir` session property.  A cold process with a
-   warmed cache dir loads executables instead of compiling them.
+1. the JAX persistent compilation cache (disk, keyed by HLO hash).
+   Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it and this
+   module sets no directory in code; otherwise the directory is the
+   `compile_cache_dir` session property, `PRESTO_TPU_COMPILE_CACHE`, or
+   `<checkout>/.jax_cache` (a fixed path: the path is part of the
+   cache key).  A cold process with a warmed cache dir loads
+   executables instead of compiling them.
 2. a process-wide executable memo keyed by engine-level fingerprints
    (plan serde bytes x chunk mult x mesh shape x dtype layout, see
    `fingerprint`/`plan_fingerprint`): the per-session `_jit` /
@@ -53,7 +56,12 @@ import jax
 
 from presto_tpu.observe import trace as TR
 
-DEFAULT_CACHE_DIR = "/tmp/presto_tpu_xla_cache"
+#: JAX's own variable: where it is set the cache is placed from outside
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 #: QueryStats counter names this module maintains (observe/stats.py
 #: declares the same fields; bench.py emits them as compile_economics)
@@ -119,18 +127,21 @@ _listener_installed = False
 
 
 def resolve_cache_dir(session=None) -> Optional[str]:
-    """Cache dir precedence: `compile_cache_dir` session property >
-    PRESTO_TPU_COMPILE_CACHE > PRESTO_TPU_XLA_CACHE (legacy) > default.
-    '0' / 'off' / '' disables (returns None)."""
+    """The persistent cache's directory.  JAX_COMPILATION_CACHE_DIR,
+    where set, decides alone (JAX reads it; configure() then sets no
+    directory).  Otherwise: `compile_cache_dir` session property >
+    PRESTO_TPU_COMPILE_CACHE > <checkout>/.jax_cache, and '0' / 'off'
+    disables (returns None)."""
+    outside = os.environ.get(JAX_CACHE_ENV)
+    if outside:
+        return outside
     d = None
     if session is not None:
         d = session.properties.get("compile_cache_dir") or None
     if d is None:
-        d = os.environ.get("PRESTO_TPU_COMPILE_CACHE") \
-            or os.environ.get("PRESTO_TPU_XLA_CACHE") \
-            or DEFAULT_CACHE_DIR
+        d = os.environ.get("PRESTO_TPU_COMPILE_CACHE") or DEFAULT_CACHE_DIR
     d = str(d)
-    return None if d in ("0", "off", "") else d
+    return None if d in ("0", "off") else d
 
 
 def _on_event(event, **kw) -> None:
@@ -140,23 +151,23 @@ def _on_event(event, **kw) -> None:
 
 def configure(session=None) -> None:
     """Idempotently point JAX's persistent compilation cache at the
-    resolved dir and install the disk-hit listener.  Safe to call per
-    query: only reconfigures when the resolved dir changes."""
+    resolved dir — unless JAX_COMPILATION_CACHE_DIR placed it from
+    outside, in which case no directory is set here — and install the
+    disk-hit listener.  Safe to call per query: only reconfigures when
+    the resolved dir changes."""
     global _configured_dir, _listener_installed
     d = resolve_cache_dir(session)
     with _conf_lock:
         if not _listener_installed:
-            try:
-                jax.monitoring.register_event_listener(_on_event)
-                _listener_installed = True
-            except Exception:
-                _listener_installed = True  # older jax: no disk-hit counts
+            jax.monitoring.register_event_listener(_on_event)
+            _listener_installed = True
         if d == _configured_dir:
             return
         _configured_dir = d
         if d is None:
             return
-        jax.config.update("jax_compilation_cache_dir", d)
+        if not os.environ.get(JAX_CACHE_ENV):
+            jax.config.update("jax_compilation_cache_dir", d)
         # cache every compile that takes noticeable time (default 1s
         # would skip the many small per-fragment programs whose compiles
         # still add up across the 22-query suite); tests set the env to
@@ -165,11 +176,7 @@ def configure(session=None) -> None:
                                      "0.2"))
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           min_s)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except Exception:
-            pass  # knob absent on older jax
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,7 @@ from presto_tpu.exec.compiler import EvalContext, eval_expr, eval_predicate, to_
 from presto_tpu.plan import ir
 from presto_tpu.plan import nodes as P
 from presto_tpu.plan.optimizer import optimize
-from presto_tpu.plan.planner import Planner
+from presto_tpu.plan.planner import Planner, SemanticError
 from presto_tpu.session import QueryResult
 from presto_tpu.sql import ast
 from presto_tpu.sql.parser import parse
@@ -333,8 +333,8 @@ def _dispatch_statement(session, text: str, stmt, mon) -> QueryResult:
                 return run_distributed(session, text, stmt)
         except (Undistributable, StaticFallback,
                 jax.errors.ConcretizationTypeError,
-                jax.errors.TracerArrayConversionError):
-            pass  # single-device paths below
+                jax.errors.TracerArrayConversionError) as e:
+            _note_fallback(mon, "distributed", e)  # single-device below
     mode = session.properties.get("execution_mode", "auto")
     if mode in ("auto", "compiled", "chunked"):
         # grouped/chunked execution when a scanned table exceeds the HBM
@@ -350,17 +350,20 @@ def _dispatch_statement(session, text: str, stmt, mon) -> QueryResult:
             try:
                 plan_probe = plan_statement(session, stmt)
                 needs_chunks = CH.chunk_plan_needed(session, plan_probe)
-            except Exception:
-                needs_chunks = False
+            except (SemanticError, ExecutionError, KeyError) as e:
+                # the planner's own refusals: the path below plans again
+                # and reports them to the user as the query's error
+                _note_fallback(mon, "chunk probe", e)
         if needs_chunks or mode == "chunked":
             try:
                 with mon.phase("execute"):
                     mon.stats.execution_mode = "chunked"
                     return CH.run_chunked(session, stmt, text, mon=mon)
             except (CH.Unchunkable, jax.errors.ConcretizationTypeError,
-                    jax.errors.TracerArrayConversionError):
+                    jax.errors.TracerArrayConversionError) as e:
                 if mode == "chunked":
                     raise
+                _note_fallback(mon, "chunked", e)
     if mode in ("auto", "compiled"):
         try:
             with mon.phase("execute"):
@@ -370,12 +373,22 @@ def _dispatch_statement(session, text: str, stmt, mon) -> QueryResult:
                 jax.errors.TracerArrayConversionError) as e:
             if mode == "compiled":
                 raise StaticFallback(str(e)) from e
+            _note_fallback(mon, "compiled", e)
     mon.stats.execution_mode = "dynamic"
     with mon.phase("plan"):
         plan = plan_statement(session, stmt)
     with mon.phase("execute"):
         ex = Executor(session, monitor=mon)
         return ex.run(plan)
+
+
+def _note_fallback(mon, dropped: str, e: Exception) -> None:
+    """A drop to the next execution mode is how `auto` works, but it is
+    never silent: QueryStats.fallback_reason names each mode that was
+    tried and why it gave up, next to the execution_mode that ran."""
+    why = f"{dropped}: {type(e).__name__}: {e}"[:300]
+    prev = mon.stats.fallback_reason
+    mon.stats.fallback_reason = f"{prev}; {why}" if prev else why
 
 
 def _substitute_parameters(sql: str, params) -> str:

@@ -40,9 +40,12 @@ The family (routing lives in kernels.take_rows):
    getSortedPages): produce output in the order the machine likes, not
    the order the rows arrived in.
 
-CPU test meshes run the kernel under the Pallas interpreter; routing
-constants were pinned with the gather microbench in tools/roofline.py
-(swept over index count x row width, see docs/PERF.md round 6).
+CPU test meshes run the kernel under the Pallas interpreter.  On the
+TPU backend step 2 is switched off (_block_gather_enabled: Mosaic
+refuses the kernel body) and staging runs steps 1 and 3 over the plain
+ascending-order XLA gather.  The routing constants come from a model
+(docs/PERF.md round 6); tools/roofline.py's gather sweep has not run on
+a chip.
 """
 
 from __future__ import annotations
@@ -86,8 +89,8 @@ _WINDOW_SLACK = 2
 def _env_mode() -> str:
     """PRESTO_TPU_GATHER: '' (auto: staged on TPU, flat elsewhere) |
     'flat' (disable staging) | 'sorted' (staging without the Pallas
-    kernel — the safety valve if Mosaic ever rejects the kernel on a
-    new TPU generation) | 'force' (staging even off-TPU: the CPU
+    kernel — what the TPU backend always runs today, see
+    _block_gather_enabled) | 'force' (staging even off-TPU: the CPU
     equivalence tests, which also shrink the routing constants)."""
     return os.environ.get("PRESTO_TPU_GATHER", "")
 
@@ -156,6 +159,22 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _block_gather_enabled() -> bool:
+    """The Pallas block-gather is OFF on the TPU backend: the chip's
+    compiler refuses its body at every shape (v5e, jax 0.9.0 / libtpu
+    0.0.34, tests/test_tpu_aot_compile.py pins the message): the
+    in-kernel `jnp.take` of IB rows from a (W, w) window fails with
+    "Shape mismatch in input, indices and output", and the only gather
+    Mosaic lowers, an equal-shape take_along_axis, stops at one vreg
+    ("Not implemented: Multiple source vregs along gather dimension").
+    A (n, 2..16)-wide u32 operand would also be lane-padded to 128 in
+    HBM.  Staging on the chip therefore runs as the ascending-order XLA
+    gather; the kernel stays reachable in interpret mode (the CPU
+    equivalence tests) until it is rebuilt lane-major or deleted
+    (ROADMAP Speed queue)."""
+    return _interpret()
+
+
 @partial(compile_cache.static_jit, static_argnames=("W", "IB"))
 def _blocked_gather_call(blk, idx2, src, *, W: int, IB: int):
     """One Pallas launch: grid step i copies source window
@@ -207,7 +226,8 @@ def staged_gather(src: jnp.ndarray, sidx: jnp.ndarray) -> jnp.ndarray:
     n, w = src.shape
     m = sidx.shape[0]
     W = window_rows(n, m)
-    if W is None or m < _IB or _env_mode() == "sorted":
+    if W is None or m < _IB or _env_mode() == "sorted" \
+            or not _block_gather_enabled():
         return src[sidx]
     m_pad = -(-m // _IB) * _IB
     if m_pad != m:

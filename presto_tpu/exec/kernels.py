@@ -1481,6 +1481,10 @@ def _pallas_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+#: group columns per grid step of fused_group_sums' TPU body
+_FUSED_GROUP_TILE = 512
+
+
 def fused_group_sums(vals: jnp.ndarray, gid: jnp.ndarray,
                      n_groups: int) -> jnp.ndarray:
     """ONE pass computing k segmented sums that share group ids.
@@ -1542,10 +1546,18 @@ def fused_group_sums(vals: jnp.ndarray, gid: jnp.ndarray,
         )(vals, gid2)
         return out[:, :n_groups]
 
+    # the one-hot is tiled over G so it never outgrows fast memory: at
+    # the top of the executor's gate (4096 groups) an untiled
+    # (BLOCK, G) f32 one-hot is 128 MiB; a (BLOCK, _GT) tile is 16 MiB
+    # of vector values Mosaic streams through the MXU.  The row block
+    # keeps its index across the inner grid axis, so it is fetched once.
+    GT = min(G, _FUSED_GROUP_TILE)
+    G = -(-G // GT) * GT
+
     def kernel32(vals_ref, gid_ref, out_ref):
-        g = gid_ref[0, :]
+        g = gid_ref[0, :] - pl.program_id(1) * GT
         onehot = (g[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (BLOCK, G), 1)).astype(jnp.float32)
+            jnp.int32, (BLOCK, GT), 1)).astype(jnp.float32)
         out_ref[0, :, :] = jax.lax.dot_general(
             vals_ref[:, :], onehot, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -1557,12 +1569,12 @@ def fused_group_sums(vals: jnp.ndarray, gid: jnp.ndarray,
     with jax.enable_x64(False):
         partials = pl.pallas_call(
             kernel32,
-            grid=(steps,),
+            grid=(steps, G // GT),
             in_specs=[
-                pl.BlockSpec((k, BLOCK), lambda i: (0, i)),
-                pl.BlockSpec((1, BLOCK), lambda i: (0, i)),
+                pl.BlockSpec((k, BLOCK), lambda i, j: (0, i)),
+                pl.BlockSpec((1, BLOCK), lambda i, j: (0, i)),
             ],
-            out_specs=pl.BlockSpec((1, k, G), lambda i: (i, 0, 0)),
+            out_specs=pl.BlockSpec((1, k, GT), lambda i, j: (i, 0, j)),
             out_shape=jax.ShapeDtypeStruct((steps, k, G), jnp.float32),
         )(vals32, gid2)
     return partials.astype(jnp.float64).sum(axis=0)[:, :n_groups]

@@ -13,6 +13,7 @@ Reference parity: this plays the role of presto-bytecode/sql-gen's
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,23 +23,33 @@ import numpy as np
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "src", "ptnative.cpp")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_SO = os.path.join(_BUILD_DIR, "libptnative.so")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """The library is keyed by a hash of its source, in the file name: a
+    copy of the checkout (which keeps no mtime order) can never pair a
+    stale binary with a newer source."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libptnative-{digest}.so")
+
+
+def _build(so: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{so}.{os.getpid()}.tmp"  # concurrent builders never share it
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-fvisibility=hidden",
-        "-std=c++17", "-o", _SO, _SRC,
+        "-std=c++17", "-o", tmp, _SRC,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)
         return True
-    except (subprocess.SubprocessError, FileNotFoundError):
+    except (subprocess.SubprocessError, OSError):
         return False
 
 
@@ -81,7 +92,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def get_lib():
-    """Load (building if stale/missing) the native library, or None."""
+    """Load (building if missing for this source) the native library,
+    or None."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
@@ -90,11 +102,10 @@ def get_lib():
             return _lib
         _tried = True
         try:
-            stale = (not os.path.exists(_SO)
-                     or os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-            if stale and not _build():
+            so = _so_path()
+            if not os.path.exists(so) and not _build(so):
                 return None
-            _lib = _bind(ctypes.CDLL(_SO))
+            _lib = _bind(ctypes.CDLL(so))
         except OSError:
             _lib = None
         return _lib

@@ -25,22 +25,23 @@ from contextlib import contextmanager
 from typing import Optional
 
 
-#: roofline peaks for the estimated-wall model; env-overridable so the
-#: operator can pin them to the real part (defaults: one TPU v4 core's
-#: order of magnitude; on CPU the estimate is labeled as such)
-DEFAULT_PEAK_FLOPS = 137e12
-DEFAULT_HBM_GBPS = 1200.0
-CPU_PEAK_FLOPS = 100e9
-CPU_MEM_GBPS = 20.0
+#: roofline peaks for the estimated-wall model: device_kind ->
+#: (peak FLOP/s, memory GB/s), each with its source.  A kind that is not
+#: here is an error in estimate_wall_ms, never a default; the env vars
+#: PRESTO_TPU_PEAK_FLOPS / PRESTO_TPU_HBM_GBPS pin both for a part the
+#: table does not know.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    "TPU v5 lite": (197e12, 819.0),
+    # the XLA CPU backend: an order of magnitude for one host socket,
+    # only so EXPLAIN ANALYZE prints an estimate in CPU tests
+    "cpu": (100e9, 20.0),
+}
 
 
 def _normalize(raw) -> Optional[dict]:
-    """XLA cost_analysis payload (dict, or [dict] on older jax) ->
+    """XLA cost_analysis payload (a dict on the installed jax) ->
     {"flops": float, "bytes_accessed": float, ...extras}."""
-    if raw is None:
-        return None
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else None
     if not isinstance(raw, dict):
         return None
     out = {}
@@ -88,27 +89,36 @@ def merge_costs(costs) -> Optional[dict]:
 
 
 def platform() -> str:
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0].platform
-    except Exception:  # noqa: BLE001 — no backend: call it cpu
-        return "cpu"
+    return jax.devices()[0].platform
+
+
+def device_peaks() -> tuple:
+    """(peak FLOP/s, memory bytes/s) of the first device: the env
+    overrides, else DEVICE_PEAKS by device_kind; an unknown kind
+    raises."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    known = DEVICE_PEAKS.get(kind)
+    flops = os.environ.get("PRESTO_TPU_PEAK_FLOPS")
+    gbps = os.environ.get("PRESTO_TPU_HBM_GBPS")
+    if known is None and (flops is None or gbps is None):
+        raise LookupError(
+            f"no roofline peaks for device_kind {kind!r}: add it to "
+            "observe/profile.DEVICE_PEAKS with its source, or set "
+            "PRESTO_TPU_PEAK_FLOPS and PRESTO_TPU_HBM_GBPS")
+    return (float(flops if flops is not None else known[0]),
+            float(gbps if gbps is not None else known[1]) * 1e9)
 
 
 def estimate_wall_ms(cost: Optional[dict]) -> Optional[float]:
     """Roofline estimate: max(compute, memory) time for the program's
-    FLOPs / bytes at the platform's peak rates (env overrides
-    PRESTO_TPU_PEAK_FLOPS / PRESTO_TPU_HBM_GBPS)."""
+    FLOPs / bytes at the device's peak rates (device_peaks)."""
     if not cost:
         return None
-    cpu = platform() == "cpu"
-    peak = float(os.environ.get(
-        "PRESTO_TPU_PEAK_FLOPS",
-        CPU_PEAK_FLOPS if cpu else DEFAULT_PEAK_FLOPS))
-    bw = float(os.environ.get(
-        "PRESTO_TPU_HBM_GBPS",
-        CPU_MEM_GBPS if cpu else DEFAULT_HBM_GBPS)) * 1e9
+    peak, bw = device_peaks()
     t_flops = cost.get("flops", 0.0) / max(peak, 1.0)
     t_bytes = cost.get("bytes_accessed", 0.0) / max(bw, 1.0)
     return max(t_flops, t_bytes) * 1e3

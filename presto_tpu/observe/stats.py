@@ -47,6 +47,9 @@ class QueryStats:
     end_time: float = 0.0
     phase_ns: Dict[str, int] = dataclasses.field(default_factory=dict)
     execution_mode: str = ""  # dynamic | compiled | distributed
+    # every mode `auto` tried and dropped before execution_mode ran,
+    # with the error that dropped it ("" = the first choice ran)
+    fallback_reason: str = ""
     output_rows: int = 0
     error: Optional[str] = None
     peak_memory_bytes: int = 0

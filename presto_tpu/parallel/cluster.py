@@ -3798,6 +3798,9 @@ def launch_local_cluster(session, catalog_spec: str, nworkers: int = 2,
     import subprocess
     import sys
 
+    from presto_tpu.parallel.mesh import refuse_cpu_children
+
+    refuse_cpu_children("launch_local_cluster")
     timeout = R.STARTUP_TIMEOUT_S if timeout is None else timeout
     if cluster_secret() is None:
         set_cluster_secret(_pysecrets.token_hex(32))

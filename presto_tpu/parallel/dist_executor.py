@@ -22,12 +22,8 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as PS
-
-try:
-    from jax import shard_map
-except ImportError:  # moved to core in newer jax; 0.4.x path:
-    from jax.experimental.shard_map import shard_map
 
 from presto_tpu.batch import Batch, Column
 from presto_tpu.exec import compile_cache as CC
@@ -170,13 +166,8 @@ def _traced_single_value(b: Batch, guards: list):
 
 
 def _shard_mapped(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions (check_vma vs pre-0.5 check_rep)."""
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -89,18 +89,33 @@ def multihost_spec() -> dict:
             else 0}
 
 
+def refuse_cpu_children(launcher: str) -> None:
+    """The cluster, fleet and multi-process launchers start their
+    children on the CPU backend (a chip belongs to one process, so a
+    child of a parent that holds it could not have it anyway).  Under a
+    TPU parent that would time CPU work and stamp the parent's platform
+    on the record, so the launch is refused; run it with
+    JAX_PLATFORMS=cpu, where it is the CPU topology it says it is."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{launcher} starts CPU child processes and this process "
+            "runs on a TPU: these launchers are CPU topologies today — "
+            "run them under JAX_PLATFORMS=cpu (one process drives the "
+            "chips: distributed=True / mesh_devices=N)")
+
+
 def make_mesh(n_devices: int | None = None) -> Mesh:
+    """Mesh over the first `n_devices` devices of the default backend
+    (all of them when None).  Too few devices is an error: a mesh never
+    moves to another backend than the one the process runs on."""
     devs = jax.devices()
-    if n_devices is not None and len(devs) < n_devices:
-        # fall back to the virtual CPU backend (multi-chip dry-run path;
-        # XLA_FLAGS=--xla_force_host_platform_device_count=N must be set
-        # before backend init)
-        devs = jax.devices("cpu")
     if n_devices is not None:
         if len(devs) < n_devices:
             raise RuntimeError(
-                f"need {n_devices} devices, have {len(devs)} "
-                "(set XLA_FLAGS=--xla_force_host_platform_device_count=N)")
+                f"need {n_devices} devices, the {devs[0].platform} "
+                f"backend has {len(devs)} (a CPU rehearsal sets XLA_FLAGS="
+                "--xla_force_host_platform_device_count=N before jax "
+                "starts)")
         devs = devs[:n_devices]
     import numpy as np
 

@@ -263,8 +263,19 @@ def load_profile(session=None, multihost: bool = False) -> FusionProfile:
     plat = OP.platform()
     if multihost and f"{plat}-multiproc" in DEFAULT_PROFILES:
         return _profile_from_dict(DEFAULT_PROFILES[f"{plat}-multiproc"])
-    return _profile_from_dict(
-        DEFAULT_PROFILES.get(plat, DEFAULT_PROFILES["cpu"]))
+    return _profile_from_dict(default_profile(plat))
+
+
+def default_profile(platform: str) -> dict:
+    """The baked constants of one platform; a platform nobody priced is
+    an error, never the CPU's numbers under another name."""
+    try:
+        return DEFAULT_PROFILES[platform]
+    except KeyError:
+        raise LookupError(
+            f"no fusion cost profile for platform {platform!r}: calibrate "
+            "one (tools/roofline.py exchange --calibrate) and point "
+            f"{PROFILE_ENV} at it, or add it to DEFAULT_PROFILES") from None
 
 
 def profile_from_exchange_sweep(sweep: dict, platform: str) -> dict:
@@ -313,8 +324,7 @@ def profile_from_exchange_sweep(sweep: dict, platform: str) -> dict:
                 np_ = int(k[len("dcn_np"):-len("_ms")])
                 dcn_pts.setdefault(np_, []).append((mb, float(v)))
     h_edge, h_mb = fit(host_pts)
-    base = DEFAULT_PROFILES.get(platform, DEFAULT_PROFILES["cpu"])
-    prof = dict(base)
+    prof = dict(default_profile(platform))
     prof["platform"] = platform
     if host_pts:
         prof["host_edge_ms"] = round(h_edge, 3)

@@ -618,6 +618,7 @@ class PrestoTpuServer:
             "queryId": st.query_id, "query": st.sql,
             "state": st.state, "error": st.error,
             "executionMode": st.execution_mode,
+            "fallbackReason": st.fallback_reason,
             "createTime": st.create_time, "endTime": st.end_time,
             "phaseMillis": {k: v / 1e6 for k, v in st.phase_ns.items()},
             "outputRows": st.output_rows,

@@ -181,8 +181,9 @@ DEFAULT_SESSION_PROPERTIES: Dict[str, Any] = {
     "query_journal_path": "",
     # compilation economics (exec/compile_cache.py): persistent XLA
     # executable cache directory ("" = env PRESTO_TPU_COMPILE_CACHE /
-    # legacy PRESTO_TPU_XLA_CACHE / the /tmp default; "0" or "off"
-    # disables persistence) and the background compile-ahead that
+    # <checkout>/.jax_cache; "0" or "off" disables persistence; where
+    # JAX_COMPILATION_CACHE_DIR is set it decides alone and this is
+    # ignored) and the background compile-ahead that
     # AOT-compiles chunked fragments 2..N while fragment 1 executes
     # (kill switch; env PRESTO_TPU_COMPILE_AHEAD=off|on overrides
     # process-wide, and the unforced default is on only with >1 usable

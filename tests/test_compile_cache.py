@@ -49,13 +49,11 @@ def test_second_run_compiles_zero(qid, compiled_session):
 
 
 def test_q1_warm_path_stays_lean(compiled_session):
-    """The q1 regression flagged in BENCH_r05 (102.3ms vs 67.7ms at
-    r04) was investigated for this round: neither the gather-routing
-    nor the ordering-aware change recompiles or re-materializes on
-    q1's path — the current trace has ZERO warm compiles and (with
-    ordering-aware grouping) ZERO sorts; the r04->r05 shift predates
-    both (seed-era round 5's grouping-path change, q6 was flat while
-    q1 moved).  This test LOCKS the current lean shape: any future
+    """An old chip record once showed q1's warm time moving between
+    two rounds; neither the gather-routing nor the ordering-aware
+    change recompiles or re-materializes on q1's path — the current
+    trace has ZERO warm compiles and (with ordering-aware grouping)
+    ZERO sorts.  This test LOCKS the current lean shape: any future
     warm-path retrace or grouping sort on q1 fails tier-1."""
     compiled_session.sql(QUERIES[1])
     r = compiled_session.sql(QUERIES[1])
@@ -248,6 +246,38 @@ print(json.dumps({{"compiles": r.stats.compiles,
 """
 
 
+def test_cache_dir_placed_from_outside_is_left_alone(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself:
+    configure() sets no directory in code and the session property and
+    PRESTO_TPU_COMPILE_CACHE do not move it."""
+    import jax
+
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v)))
+    monkeypatch.setattr(CC, "_configured_dir", "UNSET")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "out"))
+    monkeypatch.setenv("PRESTO_TPU_COMPILE_CACHE", str(tmp_path / "ours"))
+    s = presto_tpu.connect(None, compile_cache_dir=str(tmp_path / "prop"))
+    assert CC.resolve_cache_dir(s) == str(tmp_path / "out")
+    CC.configure(s)
+    assert updates and "jax_compilation_cache_dir" not in updates
+    # unset, the directory is set in code: property > env > .jax_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert CC.resolve_cache_dir(s) == str(tmp_path / "prop")
+        CC.configure(s)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "prop")
+        monkeypatch.delenv("PRESTO_TPU_COMPILE_CACHE")
+        assert CC.resolve_cache_dir(None) == os.path.join(ROOT, ".jax_cache")
+    finally:
+        real_update("jax_compilation_cache_dir", before)
+        monkeypatch.setattr(CC, "_configured_dir", before)
+
+
 def test_persistent_cache_across_processes(tmp_path):
     """Two fresh subprocesses over one persistent cache dir: the first
     compiles cold into it; the second reports compile_cache_hits > 0
@@ -258,6 +288,8 @@ def test_persistent_cache_across_processes(tmp_path):
                PRESTO_TPU_COMPILE_CACHE=str(tmp_path / "cc"),
                PRESTO_TPU_COMPILE_CACHE_MIN_S="0",
                PRESTO_TPU_COMPILE_AHEAD="off")
+    # the child's cache is the tmp dir whatever the caller exports
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     script = _SUBPROC.format(root=ROOT)
 
     def run():

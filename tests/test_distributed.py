@@ -26,6 +26,27 @@ def test_mesh_has_8_devices():
 
 # q21's mesh program alone costs ~40s of compile on the 1-core CI box;
 # test_all_22_tpch_queries_distribute still covers it in tier 1
+def test_make_mesh_refuses_more_devices_than_present():
+    """Too few devices is an error, never a quiet move to another
+    backend's (virtual) devices."""
+    from presto_tpu.parallel.mesh import make_mesh
+
+    have = len(jax.devices())
+    assert make_mesh(have).devices.size == have
+    with pytest.raises(RuntimeError, match=f"need {have + 1} devices"):
+        make_mesh(have + 1)
+
+
+def test_cpu_child_launchers_refuse_under_a_tpu_parent(monkeypatch):
+    from presto_tpu.parallel import cluster as C
+    from presto_tpu.parallel.mesh import refuse_cpu_children
+
+    refuse_cpu_children("a CPU parent")  # fine on the CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU child processes"):
+        C.launch_local_cluster(None, "tpch:0.01:", nworkers=2)
+
+
 @pytest.mark.parametrize("qid", [
     pytest.param(q, marks=pytest.mark.slow) if q == 21 else q
     for q in sorted(QUERIES)])

@@ -70,6 +70,20 @@ def test_profile_loads_file_env_and_default(tmp_path, monkeypatch,
     assert FC.load_profile(s).host_ms_per_mb == base.host_ms_per_mb
 
 
+def test_unknown_platform_is_an_error(monkeypatch):
+    """A platform nobody priced raises; it is never handed the CPU's
+    constants (load_profile and the sweep fitter both go through
+    default_profile)."""
+    from presto_tpu.observe import profile as OP
+
+    monkeypatch.delenv(FC.PROFILE_ENV, raising=False)
+    monkeypatch.setattr(OP, "platform", lambda: "npu9000")
+    with pytest.raises(LookupError, match="npu9000"):
+        FC.load_profile(None)
+    with pytest.raises(LookupError, match="npu9000"):
+        FC.profile_from_exchange_sweep({}, "npu9000")
+
+
 def test_profile_fit_from_exchange_sweep():
     """--calibrate's least-squares fit: a synthetic sweep with known
     intercept+slope per lane round-trips through the fitter."""

@@ -109,3 +109,27 @@ def test_history_tracks_queries(session):
     session.sql("SELECT 2")
     assert len(session.history) == n0 + 2
     assert session.history[-1].sql == "SELECT 2"
+
+
+def test_roofline_peaks_come_from_the_device_kind(monkeypatch):
+    """observe/profile: peaks are looked up by device_kind; a kind that
+    is not in the table raises instead of borrowing another part's."""
+    import types
+
+    import jax
+
+    from presto_tpu.observe import profile as OP
+
+    monkeypatch.delenv("PRESTO_TPU_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("PRESTO_TPU_HBM_GBPS", raising=False)
+    cost = {"flops": 1e9, "bytes_accessed": 1e9}
+    assert OP.estimate_wall_ms(cost) == pytest.approx(50.0)  # cpu row
+    fake = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda *a: [fake])
+    assert OP.device_peaks() == (197e12, 819e9)
+    fake.device_kind = "TPU v99"
+    with pytest.raises(LookupError, match="TPU v99"):
+        OP.estimate_wall_ms(cost)
+    monkeypatch.setenv("PRESTO_TPU_PEAK_FLOPS", "1e12")
+    monkeypatch.setenv("PRESTO_TPU_HBM_GBPS", "100")
+    assert OP.estimate_wall_ms(cost) == pytest.approx(10.0)

@@ -1,5 +1,6 @@
-"""Pallas kernel + float-key tests (CPU interpreter path; the TPU
-compiled path is exercised by bench.py on hardware)."""
+"""Pallas kernel + float-key tests on the CPU interpreter path.  The
+chip's compiler sees the TPU bodies in tests/test_tpu_aot_compile.py,
+and chip_smoke.py runs them on the device."""
 
 import numpy as np
 import pytest
@@ -32,6 +33,25 @@ def test_fused_group_sums_f32_inputs():
     ref = np.stack([jax.ops.segment_sum(vals[i].astype(jnp.float64), gid,
                                         num_segments=G) for i in range(2)])
     assert np.allclose(np.asarray(out, dtype=np.float64), ref, rtol=1e-5)
+
+
+@pytest.mark.parametrize("n_groups", [6, 1500, 4096])
+def test_fused_group_sums_tpu_body_interpreted(monkeypatch, n_groups):
+    """The body only a TPU runs (f32 block partials, one-hot tiled over
+    the groups), driven on the CPU through Pallas' TPU interpret mode."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(K, "_pallas_interpret", lambda: False)
+    rng = np.random.default_rng(2)
+    n, k = 40_000, 3
+    vals = rng.random((k, n)).astype(np.float32)
+    gid = rng.integers(0, n_groups, n).astype(np.int32)
+    with pltpu.force_tpu_interpret_mode():
+        out = K.fused_group_sums(jnp.asarray(vals), jnp.asarray(gid),
+                                 n_groups)
+    ref = np.stack([np.bincount(gid, vals[i], n_groups) for i in range(k)])
+    assert out.shape == (k, n_groups)
+    assert np.allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-4)
 
 
 def _check_orderable(fn, vals):

@@ -1,0 +1,123 @@
+"""Ask the chip's compiler, without the chip: the TPU-only kernel bodies
+and backend branches of the served path, compiled at production shapes
+for a described v5e (on-chip-measurement guide, section 2).  Nothing
+runs, so these say nothing about results or times — chip_smoke.py does.
+
+The topology is described inside a module-scoped fixture and everything
+compiles in the test's own process: only one process may hold libtpu,
+and under xdist every worker imports this file.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from presto_tpu import types as T
+from presto_tpu.batch import Batch, Column
+from presto_tpu.exec import gather as G
+from presto_tpu.exec import kernels as K
+
+N_ROWS = 6_000_000  # one SF1 lineitem
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an AOT TPU executable is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """jax.default_backend() still says cpu here: steer the engine's
+    TPU branches from the test, not through an option of the program."""
+    monkeypatch.setattr(K, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(G, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("n_groups", [6, 128, 4096])
+def test_fused_group_sums_tpu_body(one_chip, as_tpu, n_groups):
+    c = _compile(lambda v, g: K.fused_group_sums(v, g, n_groups), one_chip,
+                 ((8, N_ROWS), jnp.float32), ((N_ROWS,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("W,w", [(8192, 16), (1024, 2)])
+def test_blocked_gather_refused_by_mosaic(one_chip, as_tpu, W, w):
+    """Why gather._block_gather_enabled() is off on the TPU backend:
+    the compiler's own message.  When the kernel is rebuilt so that
+    this compiles, flip the assertion and the routing together."""
+    IB, m = 1024, 2_000_000
+    m_pad = -(-m // IB) * IB
+    n_pad = -(-N_ROWS // W) * W
+    with pytest.raises(Exception, match="Shape mismatch in input, "
+                                        "indices and output"):
+        _compile(lambda b, i, s: G._blocked_gather_call(b, i, s, W=W, IB=IB),
+                 one_chip, ((m_pad // IB,), jnp.int32),
+                 ((1, m_pad), jnp.int32), ((n_pad, w), jnp.uint32))
+
+
+def test_staged_gather_runs_as_xla_on_tpu(one_chip, as_tpu):
+    c = _compile(G.staged_gather, one_chip,
+                 ((N_ROWS, 16), jnp.uint32), ((2_000_000,), jnp.int32))
+    assert "tpu_custom_call" not in c.as_text()
+
+
+def test_f64_orderable_pair(one_chip):
+    _compile(K._f64_orderable_pair, one_chip, ((N_ROWS,), jnp.float64))
+
+
+def test_orderable_int_takes_the_pair_on_tpu(one_chip, as_tpu):
+    _compile(lambda d: K._orderable_int(Column(d, None, T.DOUBLE)),
+             one_chip, ((N_ROWS,), jnp.float64))
+
+
+def test_f32_sort_key(one_chip, as_tpu):
+    def key_and_sort(d):
+        k = K._sort_operand_native(Column(d, None, T.REAL))
+        return jax.lax.sort((k, jnp.arange(d.shape[0], dtype=jnp.int32)),
+                            num_keys=1)
+
+    c = _compile(key_and_sort, one_chip, ((N_ROWS,), jnp.float32))
+    assert "bitcast" in c.as_text()
+
+
+def test_pack_fetch_12_columns(one_chip):
+    n = 100
+    dts = [jnp.int64, jnp.int32, jnp.float32, jnp.float64, jnp.bool_,
+           jnp.int16] * 2
+    typs = [T.BIGINT, T.INTEGER, T.REAL, T.DOUBLE, T.BOOLEAN,
+            T.SMALLINT] * 2
+
+    def pack(sel, guard, *cols):
+        b = Batch({f"c{i}": Column(c, sel if i % 3 == 0 else None, typs[i])
+                   for i, c in enumerate(cols)}, sel)
+        return K.pack_fetch(b, guard)[0]
+
+    _compile(pack, one_chip, ((n,), jnp.bool_), ((), jnp.int32),
+             *[((n,), d) for d in dts])

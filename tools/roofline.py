@@ -218,6 +218,9 @@ def dcn_sweep(nprocs=(2, 4), local_devices=2):
     import socket
     import subprocess
 
+    from presto_tpu.parallel.mesh import refuse_cpu_children
+
+    refuse_cpu_children("roofline.py's multi-process sweep")
     cells = {}
     for nproc in nprocs:
         with socket.socket() as s:
