@@ -43,6 +43,12 @@ def emit(phase, **kw):
     print(json.dumps({"phase": phase, **kw}), flush=True)
 
 
+def check(cond, *what):
+    """An assert that -O cannot remove: a failed check fails the phase."""
+    if not cond:
+        raise AssertionError(*what)
+
+
 def ms_since(t0):
     return round((time.perf_counter() - t0) * 1e3, 1)
 
@@ -123,11 +129,11 @@ def phase_kernels(on_tpu):
         want = np.stack([np.asarray(jax.ops.segment_sum(
             vals[i].astype(jnp.float64), gid, num_segments=n_groups))
             for i in range(k)])
-        assert got.shape == (k, n_groups), got.shape
-        assert np.isfinite(got).all()
+        check(got.shape == (k, n_groups), got.shape)
+        check(np.isfinite(got).all())
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
         if on_tpu:
-            assert is_kernel, "fused_group_sums ran without tpu_custom_call"
+            check(is_kernel, "fused_group_sums ran without tpu_custom_call")
         emit("kernels", kernel="fused_group_sums", k=k, n=n, groups=n_groups,
              tpu_custom_call=is_kernel, ms=ms_since(t0))
 
@@ -147,12 +153,12 @@ def phase_kernels(on_tpu):
                                           jnp.int32))
         c, is_kernel = _compiled(G.staged_gather, src, idx)
         got = c(src, idx)
-        assert got.shape == (m, w)
-        assert bool(jnp.array_equal(got, rows_of(idx)))
-        assert bool(jnp.array_equal(got, src[idx]))
+        check(got.shape == (m, w))
+        check(bool(jnp.array_equal(got, rows_of(idx))))
+        check(bool(jnp.array_equal(got, src[idx])))
         # on the TPU backend the Pallas block-gather is switched off in
         # the routing (gather._block_gather_enabled): XLA gather expected
-        assert is_kernel == (on_tpu and G._block_gather_enabled())
+        check(is_kernel == (on_tpu and G._block_gather_enabled()))
         emit("kernels", kernel="staged_gather", n=n, m=m, w=w,
              tpu_custom_call=is_kernel,
              block_gather_enabled=G._block_gather_enabled(),
@@ -169,7 +175,7 @@ def phase_kernels(on_tpu):
     got = jax.jit(lambda a, b, c_, i: K.take_rows([a, b, c_], i))(
         a64, a32, ab, idx)
     for g, a in zip(got, (a64, a32, ab)):
-        assert bool(jnp.array_equal(g, a[idx]))
+        check(bool(jnp.array_equal(g, a[idx])))
     emit("kernels", kernel="take_rows", n=n, m=m, route=route,
          ms=ms_since(t0))
 
@@ -205,15 +211,15 @@ def _check_orderable(vals, keys, strict):
     finite = np.isfinite(vals)
     order = np.argsort(vals[finite], kind="stable")
     v, k = vals[finite][order], keys[finite][order]
-    assert (k[1:] >= k[:-1]).all(), "orderable key is not monotone"
+    check((k[1:] >= k[:-1]).all(), "orderable key is not monotone")
     if strict:
-        assert ((k[1:] > k[:-1]) | (v[1:] == v[:-1])).all(), \
-            "orderable key merges distinct values"
-    assert keys[np.isneginf(vals)].max() <= k.min()
-    assert keys[np.isposinf(vals)].min() >= k.max()
-    assert keys[np.isnan(vals)].min() > keys[np.isposinf(vals)].max()
+        check(((k[1:] > k[:-1]) | (v[1:] == v[:-1])).all(),
+            "orderable key merges distinct values")
+    check(keys[np.isneginf(vals)].max() <= k.min())
+    check(keys[np.isposinf(vals)].min() >= k.max())
+    check(keys[np.isnan(vals)].min() > keys[np.isposinf(vals)].max())
     zeros = keys[vals == 0]
-    assert (zeros == zeros[0]).all()
+    check((zeros == zeros[0]).all())
 
 
 # ---------------------------------------------------------------------------
@@ -258,7 +264,7 @@ def phase_serve(sf):
     session.set("result_cache_enabled", False)
     uncached_tier = ServingTier(session)
     session.set("result_cache_enabled", True)
-    assert uncached_tier.result_cache is None
+    check(uncached_tier.result_cache is None)
     srv = PrestoTpuServer(session).start()
     srv_uncached = PrestoTpuServer(session, serving=uncached_tier).start()
     answers = {}
@@ -269,22 +275,22 @@ def phase_serve(sf):
             rows = list(StatementClient(srv.uri, sql).rows())
             cold_ms = ms_since(t0)
             cold = _last_stats(session, sql)
-            assert cold.execution_mode == "compiled", \
-                (qid, cold.execution_mode, cold.fallback_reason)
+            check(cold.execution_mode == "compiled",
+                (qid, cold.execution_mode, cold.fallback_reason))
             # the identical text again on the default tier: the result
             # cache answers — that is the served path working
             again = list(StatementClient(srv.uri, sql).rows())
             cached = _last_stats(session, sql)
-            assert cached.execution_mode == "cached", cached.execution_mode
-            assert again == rows
+            check(cached.execution_mode == "cached", cached.execution_mode)
+            check(again == rows)
             t0 = time.perf_counter()
             warm_rows = list(StatementClient(srv_uncached.uri, sql).rows())
             warm_ms = ms_since(t0)
             warm = _last_stats(session, sql)
-            assert warm.execution_mode == "compiled", \
-                (qid, warm.execution_mode, warm.fallback_reason)
-            assert warm.compiles == 0, (qid, warm.compiles)
-            assert warm_rows == rows
+            check(warm.execution_mode == "compiled",
+                (qid, warm.execution_mode, warm.fallback_reason))
+            check(warm.compiles == 0, (qid, warm.compiles))
+            check(warm_rows == rows)
             answers[qid] = rows
             emit("serve", query=f"q{qid}", rows=len(rows), cold_ms=cold_ms,
                  warm_ms=warm_ms, execution_mode=warm.execution_mode,
@@ -311,8 +317,8 @@ def phase_serve(sf):
     # no scanned table fell to host generation (executor reads the WHOLE
     # table on the host once one column is not device-generable)
     for name in ("lineitem", "orders", "customer"):
-        assert not hasattr(session.catalog.get(name), "_data"), \
-            f"{name} was generated on the host"
+        check(not hasattr(session.catalog.get(name), "_data"),
+            f"{name} was generated on the host")
     return answers
 
 
@@ -340,10 +346,10 @@ def reference(sf, queries, order_slice=ORDER_SLICE):
         li = H.generate("lineitem", sf, r0, r0 + order_slice)
         od = H.generate("orders", sf, r0, r0 + order_slice)
         o_key = od["o_orderkey"]
-        assert (o_key[1:] > o_key[:-1]).all()
+        check((o_key[1:] > o_key[:-1]).all())
         # every lineitem of these orders is in this slice
         l_pos = np.searchsorted(o_key, li["l_orderkey"])
-        assert (o_key[l_pos] == li["l_orderkey"]).all()
+        check((o_key[l_pos] == li["l_orderkey"]).all())
         px, disc, qty = (li["l_extendedprice"], li["l_discount"],
                          li["l_quantity"])
         ship = li["l_shipdate"]
@@ -390,7 +396,7 @@ def reference(sf, queries, order_slice=ORDER_SLICE):
     if 18 in queries:
         q18.sort(key=lambda r: (-r[3], r[2]))
         c_key = cu["c_custkey"]
-        assert (c_key[1:] > c_key[:-1]).all()
+        check((c_key[1:] > c_key[:-1]).all())
         out[18] = [(str(cu["c_name"][np.searchsorted(c_key, ck)]), ck, ok,
                     d, tp, q) for ck, ok, d, tp, q in q18[:100]]
     return out
@@ -402,7 +408,7 @@ def reference_points(sf):
     out = []
     for row, key in zip(*point_binds(sf)):
         li = H.generate("lineitem", sf, row, row + 1)
-        assert (li["l_orderkey"] == key).all()
+        check((li["l_orderkey"] == key).all())
         out.append([(len(li["l_orderkey"]),
                      float(np.sum(li["l_extendedprice"])))])
     return out
@@ -410,16 +416,16 @@ def reference_points(sf):
 
 def assert_rows(label, got, want, rel=REL):
     """Row count, row order, keys and counts exact; floats to `rel`."""
-    assert len(got) == len(want), (label, len(got), len(want))
+    check(len(got) == len(want), (label, len(got), len(want)))
     for i, (g, w) in enumerate(zip(got, want)):
-        assert len(g) == len(w), (label, i, g, w)
+        check(len(g) == len(w), (label, i, g, w))
         for a, b in zip(g, w):
             if isinstance(b, float):
-                assert isinstance(a, (int, float)) and np.isfinite(a) \
-                    and abs(a - b) <= rel * max(abs(b), 1.0), \
-                    (label, i, g, w)
+                check(isinstance(a, (int, float)) and np.isfinite(a)
+                    and abs(a - b) <= rel * max(abs(b), 1.0),
+                    (label, i, g, w))
             else:
-                assert a == b, (label, i, g, w)
+                check(a == b, (label, i, g, w))
 
 
 def phase_check(sf, answers):
@@ -457,17 +463,17 @@ def phase_mesh(sf=1.0, ndev=4):
         t0 = time.perf_counter()
         r = dist.sql(sql)
         dist_ms = ms_since(t0)
-        assert r.stats.execution_mode == "distributed", \
-            (qid, r.stats.execution_mode, r.stats.fallback_reason)
+        check(r.stats.execution_mode == "distributed",
+            (qid, r.stats.execution_mode, r.stats.fallback_reason))
         entries = [v for k, v in dist._dist_cache.items()
                    if k[0] == " ".join(sql.split())]
-        assert entries and all(e != "DYNAMIC" for e in entries), \
-            f"q{qid}: the mesh program was dropped"
+        check(entries and all(e != "DYNAMIC" for e in entries),
+            f"q{qid}: the mesh program was dropped")
         t0 = time.perf_counter()
         r1 = one.sql(sql)
         one_ms = ms_since(t0)
-        assert r1.stats.execution_mode == "compiled", \
-            (qid, r1.stats.execution_mode, r1.stats.fallback_reason)
+        check(r1.stats.execution_mode == "compiled",
+            (qid, r1.stats.execution_mode, r1.stats.fallback_reason))
         rows = [_client_row(x) for x in r.rows]
         rows1 = [_client_row(x) for x in r1.rows]
         assert_rows(f"mesh q{qid} vs one chip", rows, rows1, rel=MESH_REL)
@@ -482,9 +488,9 @@ def phase_mesh(sf=1.0, ndev=4):
     # first: a scanned column's shards sit on ndev distinct devices
     col = getattr(cat.get("lineitem"), f"_dist_cols_{ndev}")["l_quantity"]
     shard_devs = {s.device for s in col.data.addressable_shards}
-    assert len(shard_devs) == ndev, shard_devs
+    check(len(shard_devs) == ndev, shard_devs)
     platforms = {d.platform for d in shard_devs}
-    assert platforms == {jax.devices()[0].platform}, platforms
+    check(platforms == {jax.devices()[0].platform}, platforms)
     emit("mesh", lineitem_shards=len(col.data.addressable_shards),
          devices=sorted(str(d) for d in shard_devs))
 
