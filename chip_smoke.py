@@ -23,6 +23,7 @@ import argparse
 import json
 import os
 import sys
+import threading
 import time
 
 import numpy as np
@@ -115,20 +116,23 @@ def phase_kernels(on_tpu):
     from presto_tpu.exec import kernels as K
 
     # production shapes on the chip; a few blocks under --allow-cpu (an
-    # interpreted grid of production size does not finish compiling)
+    # interpreted grid of production size does not finish compiling).
+    # Inputs come from the host and every check is ONE jitted program:
+    # on the chip each eager op would be a compile of its own.
+    rng = np.random.default_rng(0)
     n = 6_000_000 if on_tpu else 4 * 8192
     k = 8
-    t0 = time.perf_counter()
-    vals = jax.random.uniform(jax.random.key(0), (k, n), jnp.float32) * 1e3
+    vals = jnp.asarray(rng.random((k, n), np.float32) * 1e3)
     for n_groups in (6, 4096):
-        gid = jax.random.randint(jax.random.key(n_groups), (n,), 0, n_groups,
-                                 jnp.int32)
+        t0 = time.perf_counter()
+        gid = jnp.asarray(rng.integers(0, n_groups, n, np.int32))
         c, is_kernel = _compiled(
             lambda v, g: K.fused_group_sums(v, g, n_groups), vals, gid)
         got = np.asarray(c(vals, gid))
-        want = np.stack([np.asarray(jax.ops.segment_sum(
-            vals[i].astype(jnp.float64), gid, num_segments=n_groups))
-            for i in range(k)])
+        want = np.asarray(jax.jit(jax.vmap(
+            lambda v, g: jax.ops.segment_sum(v.astype(jnp.float64), g,
+                                             num_segments=n_groups),
+            in_axes=(0, None)))(vals, gid))
         check(got.shape == (k, n_groups), got.shape)
         check(np.isfinite(got).all())
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-3)
@@ -137,25 +141,24 @@ def phase_kernels(on_tpu):
         emit("kernels", kernel="fused_group_sums", k=k, n=n, groups=n_groups,
              tpu_custom_call=is_kernel, ms=ms_since(t0))
 
-    # staged gather: ascending indices, rows recomputed from their index
-    # (no second gather in the reference)
+    # staged gather at ascending indices, against src[idx] and against
+    # the rows recomputed from their index (no gather in that reference)
     n, m = (6_000_000, 2_000_000) if on_tpu else (3 * 4096, 4096)
+    idx = jnp.asarray(np.sort(rng.integers(0, n, m, np.int32)))
     for w in (2, 16):
         t0 = time.perf_counter()
-        lane = jnp.arange(w, dtype=jnp.uint32)[None, :] * jnp.uint32(40503)
 
         def rows_of(i):
             return i.astype(jnp.uint32)[:, None] * jnp.uint32(2654435761) \
-                + lane
+                + jnp.arange(w, dtype=jnp.uint32)[None, :] * jnp.uint32(40503)
 
-        src = rows_of(jnp.arange(n, dtype=jnp.int32))
-        idx = jnp.sort(jax.random.randint(jax.random.key(w), (m,), 0, n,
-                                          jnp.int32))
+        src = jax.jit(lambda: rows_of(jnp.arange(n, dtype=jnp.int32)))()
         c, is_kernel = _compiled(G.staged_gather, src, idx)
         got = c(src, idx)
         check(got.shape == (m, w))
-        check(bool(jnp.array_equal(got, rows_of(idx))))
-        check(bool(jnp.array_equal(got, src[idx])))
+        same = jax.jit(lambda g, s, i: jnp.array_equal(g, s[i])
+                       & jnp.array_equal(g, rows_of(i)))(got, src, idx)
+        check(bool(same), "staged_gather != src[idx]")
         # on the TPU backend the Pallas block-gather is switched off in
         # the routing (gather._block_gather_enabled): XLA gather expected
         check(is_kernel == (on_tpu and G._block_gather_enabled()))
@@ -164,23 +167,23 @@ def phase_kernels(on_tpu):
              block_gather_enabled=G._block_gather_enabled(),
              ms=ms_since(t0))
 
-    # the route the engine takes by itself: request-order gather of a
+    # the route the engine takes by itself: a request-order gather of a
     # mixed-width row through take_rows (staged on the chip)
     t0 = time.perf_counter()
-    a64 = jnp.arange(n, dtype=jnp.int64) * 7_000_000_011
-    a32 = jnp.arange(n, dtype=jnp.float32) * 0.5
-    ab = (jnp.arange(n, dtype=jnp.int32) % 3) == 0
-    idx = jax.random.randint(jax.random.key(7), (m,), 0, n, jnp.int32)
-    route = G.gather_route(n, m, 4)
-    got = jax.jit(lambda a, b, c_, i: K.take_rows([a, b, c_], i))(
-        a64, a32, ab, idx)
-    for g, a in zip(got, (a64, a32, ab)):
-        check(bool(jnp.array_equal(g, a[idx])))
-    emit("kernels", kernel="take_rows", n=n, m=m, route=route,
-         ms=ms_since(t0))
+    idx = jnp.asarray(rng.integers(0, n, m, np.int32))
+
+    def take_and_compare(i):
+        rows = jnp.arange(n, dtype=jnp.int64)
+        cols = [rows * 7_000_000_011, rows.astype(jnp.float32) * 0.5,
+                rows % 3 == 0]
+        return jnp.stack([jnp.array_equal(g, a[i])
+                          for g, a in zip(K.take_rows(cols, i), cols)])
+
+    check(bool(jax.jit(take_and_compare)(idx).all()), "take_rows != a[idx]")
+    emit("kernels", kernel="take_rows", n=n, m=m,
+         route=G.gather_route(n, m, 4), ms=ms_since(t0))
 
     # orderable keys against a host sort
-    rng = np.random.default_rng(0)
     nk = 1_000_000 if on_tpu else 20_000
     special = np.array([0.0, -0.0, np.inf, -np.inf, np.nan, 1e-300, -1e-300,
                         1e300, -1e300, 1.0, -1.0])
@@ -428,16 +431,43 @@ def assert_rows(label, got, want, rel=REL):
                 check(a == b, (label, i, g, w))
 
 
-def phase_check(sf, answers):
+class Background:
+    """fn(*args) on a daemon thread; result() joins and re-raises.  The
+    reference is host numpy only (minutes at SF10), so it runs beside
+    the device phases, which spend their time compiling."""
+
+    def __init__(self, fn, *args):
+        self._out = self._exc = None
+
+        def run():
+            t0 = time.perf_counter()
+            try:
+                self._out = fn(*args)
+            except BaseException as e:  # noqa: BLE001 — re-raised in result()
+                self._exc = e
+            self.ms = ms_since(t0)
+
+        self._thread = threading.Thread(target=run, daemon=True)
+        self._thread.start()
+
+    def result(self):
+        self._thread.join()
+        if self._exc is not None:
+            raise self._exc
+        return self._out
+
+
+def phase_check(answers, ref):
     t0 = time.perf_counter()
-    want = reference(sf, set(SERVED))
+    want, want_points = ref.result()
+    waited = ms_since(t0)
     for qid in SERVED:
         assert_rows(f"q{qid}", answers[qid], want[qid])
         emit("check", query=f"q{qid}", rows=len(want[qid]), equal=True)
-    for got, ref in zip(answers["point"], reference_points(sf)):
-        assert_rows("point", got, ref)
+    for got, point in zip(answers["point"], want_points):
+        assert_rows("point", got, point)
     emit("check", query="point", binds=len(answers["point"]), equal=True,
-         reference_ms=ms_since(t0))
+         reference_ms=ref.ms, waited_for_reference_ms=waited)
 
 
 # ---------------------------------------------------------------------------
@@ -457,33 +487,38 @@ def phase_mesh(sf=1.0, ndev=4):
     dist.set("distributed", True)
     dist.set("mesh_devices", ndev)
     one = presto_tpu.connect(cat)
-    want = reference(sf, set(MESH))
+
+    def run_all(session, mode):
+        out = {}
+        for qid in MESH:
+            r = session.sql(QUERIES[qid])
+            check(r.stats.execution_mode == mode,
+                  (qid, r.stats.execution_mode, r.stats.fallback_reason))
+            out[qid] = list(r.rows)
+        return out
+
+    # three lanes side by side — four-chip seconds are billed fourfold
+    # and nearly all of them are compiles: the mesh programs on a
+    # thread, the one-chip programs here, the numpy reference on another
+    ref = Background(reference, sf, set(MESH))
+    mesh_lane = Background(run_all, dist, "distributed")
+    one_rows = run_all(one, "compiled")
+    mesh_rows = mesh_lane.result()
+    want = ref.result()
     for qid in MESH:
-        sql = QUERIES[qid]
-        t0 = time.perf_counter()
-        r = dist.sql(sql)
-        dist_ms = ms_since(t0)
-        check(r.stats.execution_mode == "distributed",
-            (qid, r.stats.execution_mode, r.stats.fallback_reason))
-        entries = [v for k, v in dist._dist_cache.items()
-                   if k[0] == " ".join(sql.split())]
+        text = " ".join(QUERIES[qid].split())
+        entries = [v for k, v in dist._dist_cache.items() if k[0] == text]
         check(entries and all(e != "DYNAMIC" for e in entries),
-            f"q{qid}: the mesh program was dropped")
-        t0 = time.perf_counter()
-        r1 = one.sql(sql)
-        one_ms = ms_since(t0)
-        check(r1.stats.execution_mode == "compiled",
-            (qid, r1.stats.execution_mode, r1.stats.fallback_reason))
-        rows = [_client_row(x) for x in r.rows]
-        rows1 = [_client_row(x) for x in r1.rows]
-        assert_rows(f"mesh q{qid} vs one chip", rows, rows1, rel=MESH_REL)
-        assert_rows(f"mesh q{qid} vs reference", rows, want[qid], rel=MESH_REL)
-        assert_rows(f"one-chip q{qid} vs reference", rows1, want[qid],
+              f"q{qid}: the mesh program was dropped")
+        assert_rows(f"mesh q{qid} vs one chip", mesh_rows[qid], one_rows[qid],
                     rel=MESH_REL)
-        emit("mesh", query=f"q{qid}", rows=len(rows),
-             execution_mode=r.stats.execution_mode, dist_ms=dist_ms,
-             one_chip_mode=r1.stats.execution_mode, one_chip_ms=one_ms,
-             equal=True)
+        assert_rows(f"mesh q{qid} vs reference", mesh_rows[qid], want[qid],
+                    rel=MESH_REL)
+        assert_rows(f"one-chip q{qid} vs reference", one_rows[qid], want[qid],
+                    rel=MESH_REL)
+        emit("mesh", query=f"q{qid}", rows=len(mesh_rows[qid]),
+             execution_mode="distributed", one_chip_mode="compiled",
+             equal_to_one_chip=True, equal_to_reference=True)
     # code that has only met virtual devices may put everything on the
     # first: a scanned column's shards sit on ndev distinct devices
     col = getattr(cat.get("lineitem"), f"_dist_cols_{ndev}")["l_quantity"]
@@ -493,14 +528,6 @@ def phase_mesh(sf=1.0, ndev=4):
     check(platforms == {jax.devices()[0].platform}, platforms)
     emit("mesh", lineitem_shards=len(col.data.addressable_shards),
          devices=sorted(str(d) for d in shard_devs))
-
-
-def _client_row(row):
-    """An embedded-session row as the protocol client would show it."""
-    import datetime
-
-    return tuple(v.isoformat() if isinstance(v, datetime.date) else v
-                 for v in row)
 
 
 # ---------------------------------------------------------------------------
@@ -523,9 +550,11 @@ def main(argv=None):
     if args.mesh:
         phase_mesh()
     else:
+        ref = Background(lambda sf: (reference(sf, set(SERVED)),
+                                     reference_points(sf)), args.sf)
         phase_kernels(on_tpu)
         answers = phase_serve(args.sf)
-        phase_check(args.sf, answers)
+        phase_check(answers, ref)
     print(json.dumps({"ok": on_tpu, "device": device}), flush=True)
     return 0 if on_tpu else 1
 
