@@ -217,7 +217,7 @@ def _check_orderable(vals, keys, strict):
     check((k[1:] >= k[:-1]).all(), "orderable key is not monotone")
     if strict:
         check(((k[1:] > k[:-1]) | (v[1:] == v[:-1])).all(),
-            "orderable key merges distinct values")
+              "orderable key merges distinct values")
     check(keys[np.isneginf(vals)].max() <= k.min())
     check(keys[np.isposinf(vals)].min() >= k.max())
     check(keys[np.isnan(vals)].min() > keys[np.isposinf(vals)].max())
@@ -279,7 +279,7 @@ def phase_serve(sf):
             cold_ms = ms_since(t0)
             cold = _last_stats(session, sql)
             check(cold.execution_mode == "compiled",
-                (qid, cold.execution_mode, cold.fallback_reason))
+                  (qid, cold.execution_mode, cold.fallback_reason))
             # the identical text again on the default tier: the result
             # cache answers — that is the served path working
             again = list(StatementClient(srv.uri, sql).rows())
@@ -291,7 +291,7 @@ def phase_serve(sf):
             warm_ms = ms_since(t0)
             warm = _last_stats(session, sql)
             check(warm.execution_mode == "compiled",
-                (qid, warm.execution_mode, warm.fallback_reason))
+                  (qid, warm.execution_mode, warm.fallback_reason))
             check(warm.compiles == 0, (qid, warm.compiles))
             check(warm_rows == rows)
             answers[qid] = rows
@@ -317,11 +317,15 @@ def phase_serve(sf):
     finally:
         srv.stop()
         srv_uncached.stop()
+    import jax
+
+    emit("serve", peak_device_bytes=(jax.devices()[0].memory_stats() or {})
+         .get("peak_bytes_in_use"))
     # no scanned table fell to host generation (executor reads the WHOLE
     # table on the host once one column is not device-generable)
     for name in ("lineitem", "orders", "customer"):
         check(not hasattr(session.catalog.get(name), "_data"),
-            f"{name} was generated on the host")
+              f"{name} was generated on the host")
     return answers
 
 
@@ -425,8 +429,8 @@ def assert_rows(label, got, want, rel=REL):
         for a, b in zip(g, w):
             if isinstance(b, float):
                 check(isinstance(a, (int, float)) and np.isfinite(a)
-                    and abs(a - b) <= rel * max(abs(b), 1.0),
-                    (label, i, g, w))
+                      and abs(a - b) <= rel * max(abs(b), 1.0),
+                      (label, i, g, w))
             else:
                 check(a == b, (label, i, g, w))
 
@@ -443,7 +447,7 @@ class Background:
             t0 = time.perf_counter()
             try:
                 self._out = fn(*args)
-            except BaseException as e:  # noqa: BLE001 — re-raised in result()
+            except BaseException as e:  # noqa: BLE001 — result() re-raises
                 self._exc = e
             self.ms = ms_since(t0)
 

@@ -1549,7 +1549,7 @@ def fused_group_sums(vals: jnp.ndarray, gid: jnp.ndarray,
 
     # the one-hot is tiled over G so it never outgrows fast memory: at
     # the top of the executor's gate (4096 groups) an untiled
-    # (BLOCK, G) f32 one-hot is 128 MiB; a (BLOCK, _GT) tile is 16 MiB
+    # (BLOCK, G) f32 one-hot is 128 MiB; a (BLOCK, GT) tile is 16 MiB
     # of vector values Mosaic streams through the MXU.  The row block
     # keeps its index across the inner grid axis, so it is fetched once.
     GT = min(G, _FUSED_GROUP_TILE)

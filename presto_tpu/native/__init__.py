@@ -93,21 +93,22 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def get_lib():
     """Load (building if missing for this source) the native library,
-    or None."""
+    or None.  Only a loaded library is returned without the lock: a
+    caller that arrives while the first one is still loading waits for
+    it, instead of seeing "tried, none" and checksumming a page with
+    the fallback function its reader will not use."""
     global _lib, _tried
-    if _lib is not None or _tried:
+    if _lib is not None:
         return _lib
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        try:
-            so = _so_path()
-            if not os.path.exists(so) and not _build(so):
-                return None
-            _lib = _bind(ctypes.CDLL(so))
-        except OSError:
-            _lib = None
+        if _lib is None and not _tried:
+            _tried = True
+            try:
+                so = _so_path()
+                if os.path.exists(so) or _build(so):
+                    _lib = _bind(ctypes.CDLL(so))
+            except OSError:
+                _lib = None
         return _lib
 
 

@@ -1766,7 +1766,10 @@ class WorkerServer:
                 # encoding rides in the file name
                 bdir = os.path.join(attempt_dir, f"b{bucket}")
                 os.makedirs(bdir, exist_ok=True)
-                tmp = os.path.join(bdir, f".tmp{seq}")
+                # the tmp name is the task's own: two byte-identical
+                # fragments of one query (q18 scans lineitem twice)
+                # share a durable key and publish the same page here
+                tmp = os.path.join(bdir, f".tmp{seq}.{spec.task_id}")
                 with open(tmp, "wb") as f:
                     f.write(page)
                 os.replace(tmp,

@@ -166,6 +166,8 @@ def _traced_single_value(b: Batch, guards: list):
 
 
 def _shard_mapped(fn, mesh, in_specs, out_specs):
+    """The engine's one shard_map spelling (check_vma off: fragments
+    mix replicated and per-shard values freely)."""
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
                      out_specs=out_specs, check_vma=False)
 

@@ -275,7 +275,6 @@ def test_cache_dir_placed_from_outside_is_left_alone(monkeypatch, tmp_path):
         assert CC.resolve_cache_dir(None) == os.path.join(ROOT, ".jax_cache")
     finally:
         real_update("jax_compilation_cache_dir", before)
-        monkeypatch.setattr(CC, "_configured_dir", before)
 
 
 def test_persistent_cache_across_processes(tmp_path):

@@ -10,7 +10,6 @@ and under xdist every worker imports this file.
 
 import os
 
-import numpy as np
 import pytest
 
 import jax
