@@ -20,6 +20,8 @@ import urllib.error
 import urllib.request
 from typing import Iterator, List, Optional, Tuple
 
+from presto_tpu.observe import trace as TR
+
 
 class QueryError(Exception):
     pass
@@ -106,13 +108,16 @@ class StatementClient:
         """Fetch the next page; returns False when the stream is done."""
         if not self._started:
             self._started = True
-            payload = self._request("POST", f"{self.server_uri}/v1/statement",
-                                    self.sql.encode())
+            with TR.span("client.post"):
+                payload = self._request("POST",
+                                        f"{self.server_uri}/v1/statement",
+                                        self.sql.encode())
             self._absorb(payload)
             return True
         if self._next_uri is None:
             return False
-        payload = self._request("GET", self._next_uri)
+        with TR.span("client.get", statement_id=self.query_id):
+            payload = self._request("GET", self._next_uri)
         self._absorb(payload)
         return True
 
@@ -123,7 +128,9 @@ class StatementClient:
                 yield tuple(r)
             state = self.stats.get("state")
             if state in ("QUEUED", "RUNNING") and not self._current_data:
-                time.sleep(self.poll_interval)
+                with TR.span("client.poll_sleep",
+                             statement_id=self.query_id):
+                    time.sleep(self.poll_interval)
 
     def cancel(self) -> None:
         if self.query_id is not None:

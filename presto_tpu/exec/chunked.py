@@ -265,8 +265,8 @@ def _run_fragments(session, frags, runner, table_family, consumer_eid):
         _collect_scans(frag.root, fscans)
         chunked = any(s.table in table_family for s in fscans)
         t0 = TR.clock_ns()
-        span_cm = TR.maybe_span(f"fragment f{frag.fid}", kind="fragment",
-                                fid=frag.fid, chunked=chunked)
+        span_cm = TR.span(f"fragment f{frag.fid}", kind="fragment",
+                          fid=frag.fid, chunked=chunked)
         span_cm.__enter__()
         try:
             if chunked:

@@ -49,11 +49,13 @@ import hashlib
 import itertools
 import os
 import threading
+import weakref
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, Optional
 
 import jax
 
+from presto_tpu.observe import names as NM
 from presto_tpu.observe import trace as TR
 
 #: JAX's own variable: where it is set the cache is placed from outside
@@ -304,6 +306,55 @@ def _shape_struct(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
 
+#: every live Executable, for scope_tables()
+_executables: "weakref.WeakSet[Executable]" = weakref.WeakSet()
+
+
+def _versioned(fn: Callable, tag: Optional[str]) -> Callable:
+    """`fn` under the name every engine program is jitted by:
+    <name>_s<SCOPE_VERSION>[_<tag>].  The name becomes the HLO module's
+    (`jit_<name>`), which JAX's persistent-cache key hashes while it
+    strips the debug info that holds the kernel scopes: with the version
+    in the name a change of vocabulary is a change of key, and a program
+    compiled before the scopes existed is never loaded for one that has
+    them (observe/names.SCOPE_VERSION)."""
+    suffix = f"_s{NM.SCOPE_VERSION}" + (f"_{tag}" if tag else "")
+    name = getattr(fn, "__name__", "fn")
+    if not name.endswith(suffix):   # a function built twice keeps one
+        name += suffix
+    try:
+        fn.__name__ = name
+        return fn
+    except (AttributeError, TypeError):     # a partial, a callable object
+        def named(*args, **kwargs):
+            return fn(*args, **kwargs)
+
+        named.__name__ = name
+        return named
+
+
+def scope_tables() -> Dict[str, Dict[str, str]]:
+    """{HLO module name: {instruction name: op_name}} of every live
+    AOT-compiled program: how a profile's device events, which carry the
+    module's and the instruction's names, get the engine's scopes where the
+    event itself does not hold the op_name (observe/names.hlo_op_names;
+    read by benchmarks/span_reduce.py).  Parsed on demand from the
+    executables' own HLO text: building and running a program pay nothing
+    for it."""
+    tables: Dict[str, Dict[str, str]] = {}
+    for ex in list(_executables):
+        compiled = ex._compiled
+        if compiled is None:
+            continue
+        try:
+            for mod in compiled.runtime_executable().hlo_modules():
+                tables.setdefault(mod.name, {}).update(
+                    NM.hlo_op_names(mod.to_string()))
+        except Exception:  # noqa: BLE001 — a backend without HLO text
+            continue
+    return tables
+
+
 class Executable:
     """A counted jax.jit product.  With example args it AOT-compiles
     immediately (lower+compile timed as compile_ms — execution excluded);
@@ -312,12 +363,13 @@ class Executable:
     they stop matching — e.g. an exchange-buffer capacity that changed
     between runs."""
 
-    __slots__ = ("_jitted", "_compiled", "_fellback")
+    __slots__ = ("_jitted", "_compiled", "_fellback", "__weakref__")
 
-    def __init__(self, fn, jit_kwargs):
-        self._jitted = jax.jit(fn, **jit_kwargs)
+    def __init__(self, fn, jit_kwargs, tag: Optional[str] = None):
+        self._jitted = jax.jit(_versioned(fn, tag), **jit_kwargs)
         self._compiled = None
         self._fellback = False
+        _executables.add(self)
 
     def aot_compile(self, example_args) -> None:
         t0 = TR.clock_ns()
@@ -327,7 +379,7 @@ class Executable:
         # weak-typed scalar lowered strong would mismatch at call time.
         # The span puts the compile on the query's trace timeline —
         # compile-ahead builds appear on their own pool-thread lane.
-        with TR.maybe_span("xla_compile", kind="compile"):
+        with TR.span("xla_compile", kind="compile"):
             shapes = jax.tree_util.tree_map(_shape_struct, example_args)
             self._compiled = self._jitted.lower(*shapes).compile()
         _note("compiles")
@@ -353,15 +405,19 @@ class Executable:
         return self._jitted(*args)
 
 
-def build_jit(fn: Callable, *, example=None, **jit_kwargs) -> Executable:
+def build_jit(fn: Callable, *, example=None, tag: Optional[str] = None,
+              **jit_kwargs) -> Executable:
     """THE routed constructor for engine-level jax.jit programs (the
     test_lint AST rule forbids raw jax.jit outside this module and the
     two executors).  `example`: concrete args to AOT-compile against —
     exact compile timing, and the executable is ready before first use.
     Without example the first call traces+compiles inside jit (counted
     as one compile; its wall time is indistinguishable from execution,
-    so compile_ms only grows by AOT builds)."""
-    ex = Executable(fn, jit_kwargs)
+    so compile_ms only grows by AOT builds).  `tag`: a fingerprint of
+    the program that is the same in every process (the plan's): part of
+    the module's name, so a profile tells one query's program from
+    another's (`_versioned`)."""
+    ex = Executable(fn, jit_kwargs, tag)
     if example is not None:
         try:
             ex.aot_compile(example)

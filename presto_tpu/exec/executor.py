@@ -27,6 +27,8 @@ from presto_tpu.exec import compile_cache as CC
 from presto_tpu.exec import gather as GA
 from presto_tpu.exec import kernels as K
 from presto_tpu.exec.compiler import EvalContext, eval_expr, eval_predicate, to_column
+from presto_tpu.observe import names as NM
+from presto_tpu.observe import trace as TR
 from presto_tpu.plan import ir
 from presto_tpu.plan import nodes as P
 from presto_tpu.plan.optimizer import optimize
@@ -513,6 +515,7 @@ def _static_root_bound(node: P.PlanNode):
     return None
 
 
+@NM.scoped("k:compact")
 def _compact_batch(out: Batch, bound: int) -> Batch:
     """Order-preserving on-device compaction to a fixed capacity.
     top_k over a positional score finds the first `bound` live rows —
@@ -592,6 +595,15 @@ def query_cache_key(session, text: str) -> tuple:
             tuple(sorted((k, repr(v))
                          for k, v in session.properties.items())),
             _volatile_nonce(text))
+
+
+def _program_tag(plan_fp: Optional[str], batch: int = 0) -> Optional[str]:
+    """What tells this program's HLO module from another query's in a
+    profile (compile_cache.build_jit): the head of the plan's fingerprint,
+    the same in every process, and the batch width of a coalesced one."""
+    if plan_fp is None:
+        return None
+    return plan_fp[:8] + (f"b{batch}" if batch else "")
 
 
 def bind_param_values(session, params):
@@ -715,12 +727,14 @@ def run_compiled(session, text: str, stmt, mon=None, params=None) -> QueryResult
                 def fn(batches):
                     return trace(batches, None)
 
-                jitted = CC.build_jit(fn, example=(batches,))
+                jitted = CC.build_jit(fn, example=(batches,),
+                                       tag=_program_tag(plan_fp))
             else:
                 def fn(batches, pvals):
                     return trace(batches, pvals)
 
-                jitted = CC.build_jit(fn, example=(batches, pvals))
+                jitted = CC.build_jit(fn, example=(batches, pvals),
+                                       tag=_program_tag(plan_fp))
             return (plan, jitted, scan_nodes, meta_box[0],
                     dict(sort_counts))
 
@@ -729,28 +743,35 @@ def run_compiled(session, text: str, stmt, mon=None, params=None) -> QueryResult
         entry = CC.get_or_build(gkey, build)
         cache[key] = entry
         plan, jitted, scan_nodes, meta, sort_counts = entry
-        buf = jitted(batches) if params is None else jitted(batches, pvals)
+        with TR.span("exec.dispatch"):
+            buf = jitted(batches) if params is None \
+                else jitted(batches, pvals)
     else:
         plan, jitted, scan_nodes, meta, sort_counts = entry
-        f32 = bool(session.properties.get("float32_compute", False))
-        batches = [scan_batch(session.catalog.get(n.table), n, f32)
-                   for n in scan_nodes]
-        if params is None:
-            buf = jitted(batches)
-        else:
-            # warm prepared EXECUTE: binding is a device transfer into
-            # the cached executable — no parse, no plan, no compile
-            buf = jitted(batches, bind_param_values(session, params))
+        with TR.span("exec.dispatch"):
+            f32 = bool(session.properties.get("float32_compute", False))
+            batches = [scan_batch(session.catalog.get(n.table), n, f32)
+                       for n in scan_nodes]
+            if params is None:
+                buf = jitted(batches)
+            else:
+                # warm prepared EXECUTE: binding is a device transfer into
+                # the cached executable — no parse, no plan, no compile
+                buf = jitted(batches, bind_param_values(session, params))
     if mon is not None:
         _merge_sort_stats(mon.stats, sort_counts)
     ex = Executor(session)
     if meta is None:  # sparse/unbounded result: selective to_numpy fetch
         out_batch, guard = buf
-        result, guard_h = ex.materialize(plan, out_batch, extra=guard)
+        with TR.span("exec.materialize"):  # its own fetches are inside
+            result, guard_h = ex.materialize(plan, out_batch, extra=guard)
     else:
         # single device fetch: result columns + guard ride one buffer
-        datas, sel, guard_h = K.unpack_fetch(jax.device_get(buf), meta)
-        result = ex.materialize_host(plan, meta, datas, sel)
+        with TR.span("exec.wait_fetch"):
+            fetched = jax.device_get(buf)
+        with TR.span("exec.materialize"):
+            datas, sel, guard_h = K.unpack_fetch(fetched, meta)
+            result = ex.materialize_host(plan, meta, datas, sel)
     if bool(guard_h):
         # static assumption violated (incl. a tripped ordering-claim
         # monotonicity guard); data is static so it will trip again —
@@ -875,7 +896,8 @@ def run_compiled_batched(session, text: str, stmt, params_list,
                     in_axes=(0,))(stacked)
 
             try:
-                jitted = CC.build_jit(fn, example=(batches, stacked))
+                jitted = CC.build_jit(fn, example=(batches, stacked),
+                                       tag=_program_tag(plan_fp, bpad))
             except Unbatchable:
                 raise
             except (StaticFallback, jax.errors.ConcretizationTypeError,
@@ -888,21 +910,26 @@ def run_compiled_batched(session, text: str, stmt, params_list,
         cache[key] = entry
         warm = False
     else:
-        stacked = stack_params()
+        with TR.span("exec.dispatch"):
+            stacked = stack_params()
         warm = True
     plan, jitted, scan_nodes, meta, sort_counts = entry
-    f32 = bool(session.properties.get("float32_compute", False))
-    batches = [scan_batch(session.catalog.get(n.table), n, f32)
-               for n in scan_nodes]
-    buf, side = jax.device_get(jitted(batches, stacked))
-    results = []
-    any_guard = False
-    for i in range(nbatch):
-        datas, sel, guard_h = K.unpack_fetch(
-            (buf[i], [s[i] for s in side]), meta)
-        any_guard = any_guard or bool(guard_h)
-        results.append(Executor(session).materialize_host(
-            plan, meta, datas, sel))
+    with TR.span("exec.dispatch"):
+        f32 = bool(session.properties.get("float32_compute", False))
+        batches = [scan_batch(session.catalog.get(n.table), n, f32)
+                   for n in scan_nodes]
+        launched = jitted(batches, stacked)
+    with TR.span("exec.wait_fetch"):
+        buf, side = jax.device_get(launched)
+    with TR.span("exec.materialize"):
+        results = []
+        any_guard = False
+        for i in range(nbatch):
+            datas, sel, guard_h = K.unpack_fetch(
+                (buf[i], [s[i] for s in side]), meta)
+            any_guard = any_guard or bool(guard_h)
+            results.append(Executor(session).materialize_host(
+                plan, meta, datas, sel))
     if any_guard:
         # a static assumption tripped for at least one binding; the data
         # is static so it would trip again — degrade the whole signature
@@ -1717,7 +1744,8 @@ class Executor:
     # ---- row-wise ----------------------------------------------------
     def _exec_filter(self, node: P.Filter) -> Batch:
         b = self.exec_node(node.source)
-        mask = eval_predicate(node.predicate, b, self.ctx)
+        with NM.kernel_scope("k:scan_filter"):
+            mask = eval_predicate(node.predicate, b, self.ctx)
         out = b.with_sel(b.sel & mask)
         # masking never moves rows, but it punches interior holes
         self._copy_order(b, out, tail_ok=False)
@@ -2260,8 +2288,10 @@ class Executor:
         # exact-integer semantics)
         acc_t = (jnp.float32 if any_f32 and not K._pallas_interpret()
                  else jnp.float64)
+        with NM.kernel_scope("k:fused_group_sums.operand"):
+            operand = jnp.stack([r.astype(acc_t) for r in rows])
         sums = K.fused_group_sums(
-            jnp.stack([r.astype(acc_t) for r in rows]),
+            operand,
             jnp.clip(gid, 0, n_groups - 1).astype(jnp.int32),
             n_groups)
         out: Dict[str, Column] = {}
@@ -3634,16 +3664,17 @@ class Executor:
         if total > 100_000_000:
             raise StaticFallback(
                 f"static expansion too large: {n} x fanout {bound}")
-        counts = jnp.where(left.sel, counts, 0)
-        lidx = jnp.repeat(jnp.arange(n, dtype=jnp.int32), bound,
-                          total_repeat_length=total)
-        k = jnp.tile(jnp.arange(bound, dtype=jnp.int32), n)
-        cnt_l, lb_l = K.take_rows(
-            [jnp.minimum(counts, bound).astype(jnp.int32),
-             lb.astype(jnp.int32)], lidx, presorted=True)
-        slot_live = k < cnt_l
-        rpos = jnp.clip(lb_l + k, 0, max(order.shape[0] - 1, 0))
-        ridx = order[rpos]
+        with NM.kernel_scope("k:join_expand"):
+            counts = jnp.where(left.sel, counts, 0)
+            lidx = jnp.repeat(jnp.arange(n, dtype=jnp.int32), bound,
+                              total_repeat_length=total)
+            k = jnp.tile(jnp.arange(bound, dtype=jnp.int32), n)
+            cnt_l, lb_l = K.take_rows(
+                [jnp.minimum(counts, bound).astype(jnp.int32),
+                 lb.astype(jnp.int32)], lidx, presorted=True)
+            slot_live = k < cnt_l
+            rpos = jnp.clip(lb_l + k, 0, max(order.shape[0] - 1, 0))
+            ridx = order[rpos]
         if self._order_ok(node) and GA.sort_order_worthwhile(
                 total, K.batch_word_width(right) - K.batch_word_width(left)):
             # sort-order materialization: every consumer up the tree is
@@ -3702,12 +3733,13 @@ class Executor:
             empty = {n: Column(c.data[:0], None if c.valid is None else c.valid[:0],
                                c.type, c.dictionary) for n, c in merged.items()}
             return Batch(empty, jnp.zeros((0,), bool))
-        lidx = jnp.repeat(jnp.arange(left.capacity), eff_counts,
-                          total_repeat_length=total)
-        k = jnp.arange(total) - offsets[lidx]
-        has_match = counts[lidx] > 0
-        rpos = jnp.clip(lb[lidx] + k, 0, max(order.shape[0] - 1, 0))
-        ridx = order[rpos]
+        with NM.kernel_scope("k:join_expand"):
+            lidx = jnp.repeat(jnp.arange(left.capacity), eff_counts,
+                              total_repeat_length=total)
+            k = jnp.arange(total) - offsets[lidx]
+            has_match = counts[lidx] > 0
+            rpos = jnp.clip(lb[lidx] + k, 0, max(order.shape[0] - 1, 0))
+            ridx = order[rpos]
         if self._order_ok(node) and GA.sort_order_worthwhile(
                 total, K.batch_word_width(right) - K.batch_word_width(left)):
             # sort-order materialization (see _expanding_join_static)

@@ -30,6 +30,7 @@ import numpy as np
 from presto_tpu.batch import Batch, Column, Dictionary
 from presto_tpu.exec import gather as G
 from presto_tpu.exec.colval import translate_codes
+from presto_tpu.observe import names as NM
 
 I64_MIN = np.iinfo(np.int64).min
 I64_MAX = np.iinfo(np.int64).max
@@ -317,6 +318,7 @@ def unpermute(order: jnp.ndarray, *payloads):
     return out[0] if len(out) == 1 else out
 
 
+@NM.scoped("k:sort")
 def sort_pair(key: jnp.ndarray):
     """(sorted key, permutation) — THE routed entry point for key sorts,
     so the executor's sort-permutation memo can cache and replay the
@@ -358,6 +360,7 @@ def _live_runs(key: jnp.ndarray):
     return live, newgrp, guard
 
 
+@NM.scoped("k:group_ids")
 def group_ids_presorted(key: jnp.ndarray, sel):
     """Sort-free grouping for a key already nondecreasing over its live
     rows (scan order from an ordering-declaring connector, or a
@@ -378,6 +381,7 @@ def group_ids_presorted(key: jnp.ndarray, sel):
     return gid, newgrp, n_groups_t, guard
 
 
+@NM.scoped("k:group_ids")
 def group_ids_presorted_static(key: jnp.ndarray, cap: int):
     """Static-capacity twin of group_ids_presorted: returns (gid,
     rep_rows[cap], exists[cap], overflow, guard) matching the
@@ -397,6 +401,7 @@ def group_ids_presorted_static(key: jnp.ndarray, cap: int):
     return gid, rep_rows, exists, n_groups > cap, guard
 
 
+@NM.scoped("k:group_ids")
 def group_ids_static(key: jnp.ndarray, cap: int, sorted_pair=None):
     """Static-shape grouping: same sort-based scheme as group_ids but with
     a fixed group capacity.  Returns (gid, rep_rows[cap], exists[cap],
@@ -421,6 +426,7 @@ def group_ids_static(key: jnp.ndarray, cap: int, sorted_pair=None):
     return gid, rep_rows, exists, n_groups > cap
 
 
+@NM.scoped("k:group_ids")
 def group_ids(key: jnp.ndarray, sel,
               sorted_pair=None) -> Tuple[jnp.ndarray, jnp.ndarray, int]:
     """Sort-based grouping. Returns (gid[n] in [0, n_groups) for live rows,
@@ -448,6 +454,7 @@ _MATMUL_GROUPS = 4096  # few-group segment sums go through the MXU instead
 # measured, vs ~48ms per column for the TPU scatter-add lowering)
 
 
+@NM.scoped("k:segment")
 def segment_sum(x, gid, n_groups):
     if n_groups == 1:
         # global aggregate: a plain reduction — segment scatter-add into
@@ -485,6 +492,7 @@ def _reduce_identity(dtype, for_min: bool):
     return info.max if for_min else info.min
 
 
+@NM.scoped("k:segment")
 def segment_min(x, gid, n_groups):
     if n_groups == 1:
         if x.shape[0] == 0:  # empty split/partition: the identity, like
@@ -493,6 +501,7 @@ def segment_min(x, gid, n_groups):
     return jax.ops.segment_min(x, gid, num_segments=n_groups + 1)[:n_groups]
 
 
+@NM.scoped("k:segment")
 def segment_max(x, gid, n_groups):
     if n_groups == 1:
         if x.shape[0] == 0:
@@ -501,6 +510,7 @@ def segment_max(x, gid, n_groups):
     return jax.ops.segment_max(x, gid, num_segments=n_groups + 1)[:n_groups]
 
 
+@NM.scoped("k:segment")
 def segment_any(mask, gid, n: int):
     """True where ANY row of the segment has `mask` set — the join
     layer's "any passing match per probe row" reduction.  Exact
@@ -734,6 +744,7 @@ def group_percentile(x: jnp.ndarray, valid: jnp.ndarray, gid: jnp.ndarray,
     return vals, cnt > 0
 
 
+@NM.scoped("k:build_probe")
 def build_probe(build_key: jnp.ndarray, probe_key: jnp.ndarray,
                 build_order=None):
     """Sort build side; position every probe key among the build keys.
@@ -783,6 +794,7 @@ def build_probe(build_key: jnp.ndarray, probe_key: jnp.ndarray,
     return order, lb, ub
 
 
+@NM.scoped("k:sort")
 def sort_order_plan(idx: jnp.ndarray, *aligned):
     """Pre-permute a gather's request-aligned operands into ASCENDING
     index order — the sort-order materialization primitive (reference
@@ -875,15 +887,17 @@ def take_rows(arrays: List[jnp.ndarray], idx: jnp.ndarray,
     # across row width (measured: two separate 8M 1-col gathers 140ms
     # vs one (8M,2) packed gather 35-50ms on chip), so a single i64
     # column (= 2 u32 words) already wins
-    if len(words) >= 2 and idx.shape[0] >= 65536:
-        packed = jnp.stack(words, axis=1)[idx]
-        col = lambda k: packed[:, k]
-    else:
-        taken = [w[idx] for w in words]
-        col = lambda k: taken[k]
-    return _rebuild_taken(arrays, idx, spec, col, out)
+    with NM.kernel_scope("k:take_rows.flat"):
+        if len(words) >= 2 and idx.shape[0] >= 65536:
+            packed = jnp.stack(words, axis=1)[idx]
+            col = lambda k: packed[:, k]
+        else:
+            taken = [w[idx] for w in words]
+            col = lambda k: taken[k]
+        return _rebuild_taken(arrays, idx, spec, col, out)
 
 
+@NM.scoped("k:take_rows.staged")
 def _take_rows_staged(arrays, idx, words, spec, presorted):
     """Sorted-index staging: ascending gather through exec/gather's
     VMEM-windowed kernel, then (for request-order callers) ONE co-sort
@@ -1078,6 +1092,7 @@ def unpack_fetch(fetched, meta: dict):
     return datas, sel, guard
 
 
+@NM.scoped("k:compact")
 def compact(batch: Batch) -> Batch:
     """Drop masked rows (host-sync on the live count). Used at fragment
     boundaries (exchange points), not inside fragments."""
@@ -1200,6 +1215,7 @@ def _rf_bloom_positions(h: jnp.ndarray, nbits: int):
             .astype(jnp.int64) for j in range(RF_BLOOM_K)]
 
 
+@NM.scoped("k:runtime_filter")
 def rf_build(col: Column, live, structure: str = "auto") -> dict:
     """Build-side runtime-filter summary over the live rows of an
     integer-orderable key column.  Returns an all-jnp dict (trace-safe):
@@ -1223,6 +1239,7 @@ def rf_build(col: Column, live, structure: str = "auto") -> dict:
     return {"kind": "bloom", "bits": bits[:nbits]}
 
 
+@NM.scoped("k:runtime_filter")
 def rf_probe(summary: dict, col: Column) -> jnp.ndarray:
     """Probe-side membership mask: True = the row MAY have a build match
     (exact/domain: iff; bloom: false positives possible, false negatives
@@ -1250,6 +1267,7 @@ def rf_probe(summary: dict, col: Column) -> jnp.ndarray:
     return m
 
 
+@NM.scoped("k:runtime_filter")
 def rf_domain(col: Column, live):
     """(lo, hi) traced min/max of the live key values — the runtime
     TupleDomain half of the filter.  Empty live set -> (I64_MAX,
@@ -1314,6 +1332,7 @@ def rf_host_to_device(summary: dict) -> Optional[dict]:
 # ---------------------------------------------------------------------------
 
 
+@NM.scoped("k:sort")
 def sort_perm(batch: Batch, keys: List[Tuple[Column, bool, Optional[bool]]]):
     """Lexicographic permutation; masked rows last.
     keys: (column, ascending, nulls_first). Default null order matches the
@@ -1384,18 +1403,21 @@ def _sort_operand_native(col: Column) -> jnp.ndarray:
     return d.astype(jnp.int64)
 
 
+@NM.scoped("k:sort")
 def argsort_stable(key: jnp.ndarray) -> jnp.ndarray:
     """Stable argsort (equal keys keep input order) — routed entry point
     for the exchange layer's destination-bucket ordering."""
     return jnp.argsort(key, stable=True)
 
 
+@NM.scoped("k:sort")
 def lexsort_pair(minor: jnp.ndarray, major: jnp.ndarray) -> jnp.ndarray:
     """Permutation sorting by (major, then minor) — routed entry point
     (jnp.lexsort order convention: last key is primary)."""
     return jnp.lexsort((minor, major))
 
 
+@NM.scoped("k:sort")
 def sort_values(x: jnp.ndarray) -> jnp.ndarray:
     """Ascending value sort — routed entry point for splitter sampling
     in the range exchange."""
@@ -1486,6 +1508,7 @@ def _pallas_interpret() -> bool:
 _FUSED_GROUP_TILE = 512
 
 
+@NM.scoped("k:fused_group_sums")
 def fused_group_sums(vals: jnp.ndarray, gid: jnp.ndarray,
                      n_groups: int) -> jnp.ndarray:
     """ONE pass computing k segmented sums that share group ids.
@@ -1516,8 +1539,10 @@ def fused_group_sums(vals: jnp.ndarray, gid: jnp.ndarray,
     BLOCK = 8192
     npad = int(np.ceil(n / BLOCK)) * BLOCK
     if npad != n:
-        vals = jnp.pad(vals, ((0, 0), (0, npad - n)))
-        gid = jnp.pad(gid, (0, npad - n))  # padded rows carry zeros: harmless
+        with NM.kernel_scope("k:fused_group_sums.operand"):
+            vals = jnp.pad(vals, ((0, 0), (0, npad - n)))
+            # padded rows carry zeros: harmless
+            gid = jnp.pad(gid, (0, npad - n))
     steps = npad // BLOCK
     gid2 = gid.reshape(1, -1)
 

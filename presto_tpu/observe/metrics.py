@@ -21,7 +21,10 @@ The registry is the process-wide sink every subsystem rolls into:
   and per-phase wall (`presto_tpu_query_phase_seconds_total{phase}`);
 - worker task counters (`presto_tpu_worker_*`, parallel/cluster.py);
 - event-listener failures (`presto_tpu_listener_errors_total`,
-  observe/events.py — previously swallowed silently).
+  observe/events.py — previously swallowed silently);
+- failures of the span helper's own code
+  (`presto_tpu_trace_errors_total`, observe/trace.py: swallowed, a query
+  is never failed by its instrumentation).
 
 Naming scheme (docs/OBSERVABILITY.md): `presto_tpu_<subsystem>_<what>
 _<unit-or-total>`; labels are bounded-cardinality enums only (state,
@@ -312,6 +315,7 @@ def ensure_query_metrics() -> None:
     REGISTRY.counter("presto_tpu_listener_errors_total",
                      "Event-listener exceptions swallowed by dispatch",
                      ("listener",))
+    REGISTRY.counter(TRACE_ERRORS, _TRACE_ERRORS_HELP)
 
 
 def observe_query(stats) -> None:
@@ -359,6 +363,17 @@ def listener_error(listener_class: str) -> None:
     REGISTRY.counter("presto_tpu_listener_errors_total",
                      "Event-listener exceptions swallowed by dispatch",
                      ("listener",)).inc(listener=listener_class)
+
+
+TRACE_ERRORS = "presto_tpu_trace_errors_total"
+_TRACE_ERRORS_HELP = ("Exceptions raised by the span helper's own code "
+                      "(observe/trace.span) and swallowed: a query is "
+                      "never failed by its instrumentation")
+
+
+def trace_error() -> None:
+    """Count one swallowed failure of the span helper itself."""
+    REGISTRY.counter(TRACE_ERRORS, _TRACE_ERRORS_HELP).inc()
 
 
 def set_fleet_gauges(fleet_stats: Dict[str, object]) -> None:

@@ -279,6 +279,7 @@ class QueryMonitor:
             self.tracer = TR.Tracer(lane=getattr(
                 session, "_trace_lane", None) or "coordinator")
             self.stats.trace_id = self.tracer.trace_id
+            self.tracer.query_id = self.stats.query_id
             self.tracer.begin_root(
                 "query", kind="query", query_id=self.stats.query_id,
                 sql=sql[:200])
@@ -297,22 +298,21 @@ class QueryMonitor:
 
     @contextmanager
     def phase(self, name: str):
+        from presto_tpu.observe import trace as TR
+
         self.stats.state = {"parse": "PLANNING", "plan": "PLANNING",
                             "execute": "RUNNING"}.get(name, "RUNNING")
-        t0 = time.perf_counter_ns()
-        # entered manually (not `with`) so spans recorded INSIDE the
-        # phase nest under it on this thread's stack
-        cm = self.tracer.span(name, kind="phase") \
-            if self.tracer is not None else None
-        if cm is not None:
-            cm.__enter__()
+        # one clock read at each end feeds the span, the profiler
+        # annotation and phase_ns; spans recorded INSIDE the phase nest
+        # under it on this thread's stack
+        sp = TR.span(name, kind="phase", tracer=self.tracer,
+                     query_id=self.stats.query_id)
         try:
-            yield
+            with sp:
+                yield
         finally:
-            if cm is not None:
-                cm.__exit__(None, None, None)
             self.stats.phase_ns[name] = (
-                self.stats.phase_ns.get(name, 0) + time.perf_counter_ns() - t0)
+                self.stats.phase_ns.get(name, 0) + sp.elapsed_ns)
 
     def record_node(self, node, rows_out: int, wall_ns: int) -> None:
         ns = self.stats.node_stats.setdefault(

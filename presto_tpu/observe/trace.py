@@ -45,6 +45,8 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from presto_tpu.observe.names import SPAN_PREFIX
+
 #: the trace-context propagation header (coordinator -> worker):
 #: "trace_id;parent_span_id"
 TRACE_HEADER = "X-Presto-Trace"
@@ -70,11 +72,20 @@ def wall_s() -> float:
     return time.time()
 
 
-def epoch_us() -> float:
-    """Unix wall clock in microseconds — the chrome trace `ts` unit.
-    Coordinator and worker spans align on it (same-host resolution is
-    more than enough for HTTP-hop-sized spans)."""
-    return time.time_ns() / 1_000.0
+#: Unix epoch of clock_ns()'s zero, read once: every span stamp is then ONE
+#: monotonic read (the same read feeds QueryStats.phase_ns), and stamps of
+#: one process cannot step backwards with the wall clock
+_EPOCH_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
+
+
+def epoch_us(ns: Optional[int] = None) -> float:
+    """Unix epoch microseconds — the chrome trace `ts` unit — of the
+    clock_ns() reading `ns` (default: now).  Coordinator and worker
+    spans align on it (same-host resolution is more than enough for
+    HTTP-hop-sized spans)."""
+    if ns is None:
+        ns = time.perf_counter_ns()
+    return (ns + _EPOCH_OFFSET_NS) / 1_000.0
 
 
 # ---------------------------------------------------------------------------
@@ -135,6 +146,9 @@ class Tracer:
         #: a worker-side tracer hangs its task span under)
         self.root_parent = root_parent
         self.root: Optional[Span] = None
+        #: QueryStats.query_id of the query this tracer records
+        #: (QueryMonitor sets it); every annotation of the query carries it
+        self.query_id: Optional[str] = None
         self.spans: List[Span] = []
         self.dropped = 0  # foreign-trace spans refused by add_spans
         self._lock = threading.Lock()
@@ -147,7 +161,8 @@ class Tracer:
 
     # -- manual begin/end (cross-thread spans) -------------------------
     def begin(self, name: str, kind: str = "span",
-              parent: Optional[object] = None, **args) -> Span:
+              parent: Optional[object] = None,
+              at_ns: Optional[int] = None, **args) -> Span:
         if parent is None:
             parent_id = self._thread_parent_id()
         elif isinstance(parent, Span):
@@ -156,16 +171,17 @@ class Tracer:
             parent_id = str(parent)
         sp = Span(trace_id=self.trace_id, span_id=self.new_id(),
                   parent_id=parent_id, name=name, kind=kind,
-                  start_us=epoch_us(), lane=self.lane,
+                  start_us=epoch_us(at_ns), lane=self.lane,
                   tid=threading.current_thread().name, args=dict(args))
         with self._lock:
             self.spans.append(sp)
         return sp
 
-    def end(self, sp: Optional[Span], **args) -> None:
+    def end(self, sp: Optional[Span], at_ns: Optional[int] = None,
+            **args) -> None:
         if sp is None:
             return
-        sp.end_us = epoch_us()
+        sp.end_us = epoch_us(at_ns)
         if args:
             sp.args.update(args)
 
@@ -185,14 +201,25 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, kind: str = "span", **args):
-        sp = self.begin(name, kind=kind, **args)
-        stack = self._stacks.setdefault(threading.get_ident(), [])
-        stack.append(sp)
+        sp = self.push(name, kind, None, args)
         try:
             yield sp
         finally:
+            self.pop(sp, None)
+
+    def push(self, name: str, kind: str, at_ns: Optional[int],
+             args: dict) -> Span:
+        """Open a span on this thread's nesting stack."""
+        sp = self.begin(name, kind=kind, at_ns=at_ns, **args)
+        self._stacks.setdefault(threading.get_ident(), []).append(sp)
+        return sp
+
+    def pop(self, sp: Span, at_ns: Optional[int]) -> None:
+        """Close the span push() opened (same thread)."""
+        stack = self._stacks.get(threading.get_ident())
+        if stack and stack[-1] is sp:
             stack.pop()
-            self.end(sp)
+        self.end(sp, at_ns=at_ns)
 
     # -- merge / export ------------------------------------------------
     def add_spans(self, span_dicts, require_trace: bool = True) -> int:
@@ -304,16 +331,89 @@ def current() -> Optional[Tracer]:
     return getattr(_tls, "tracer", None)
 
 
-@contextmanager
-def maybe_span(name: str, kind: str = "span", **args):
-    """Record a span on the thread's active tracer, or do nothing —
-    instrumentation sites stay one-liners either way."""
-    tr = current()
-    if tr is None:
-        yield None
-        return
-    with tr.span(name, kind=kind, **args) as sp:
-        yield sp
+_UNSET = object()
+_annotation = None      # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _helper_failed() -> None:
+    """The span helper's own code raised: never the query's problem.
+    Counted in presto_tpu_trace_errors_total (observe/metrics.py)."""
+    try:
+        from presto_tpu.observe import metrics as M
+
+        M.trace_error()
+    except Exception:  # noqa: BLE001 — instrumentation cannot fail a query
+        pass
+
+
+class span:
+    """`with span(name):` — THE instrumentation one-liner.  Two sinks, one
+    clock read each at entry and exit:
+
+    (a) a `jax.profiler.TraceAnnotation` named SPAN_PREFIX + name, so the
+        span lies on the profiler's clock beside the device's operations
+        (a fraction of a microsecond when no profile is being taken);
+    (b) a Span on the thread's active `Tracer` (or `tracer=`), when there
+        is one — what `/v1/query/{id}/trace` serves.
+
+    Same-thread `with` blocks only (annotations nest per thread);
+    `Tracer.begin/end` stay for cross-thread spans and annotate nothing.
+    Every annotation carries the query's id: `query_id=` where the caller
+    has one, else the active tracer's.  `elapsed_ns` holds the duration
+    after exit.  An exception raised by this class's own code — not by
+    the body — is swallowed and counted: instrumentation cannot fail a
+    query."""
+
+    __slots__ = ("name", "kind", "args", "tracer", "elapsed_ns",
+                 "_t0", "_ann", "_sp")
+
+    def __init__(self, name: str, kind: str = "span", tracer=_UNSET,
+                 **args):
+        self.name = name
+        self.kind = kind
+        self.args = args
+        self.tracer = tracer
+        self.elapsed_ns = 0
+        self._ann = self._sp = None
+
+    def __enter__(self) -> Optional[Span]:
+        self._t0 = t0 = time.perf_counter_ns()
+        try:
+            global _annotation
+            if _annotation is None:
+                from jax.profiler import TraceAnnotation
+
+                _annotation = TraceAnnotation
+            tr = current() if self.tracer is _UNSET else self.tracer
+            self.tracer = tr
+            ids = {k: str(v) for k, v in self.args.items()
+                   if k.endswith("_id") and v is not None} \
+                if self.args else {}
+            if tr is not None and tr.query_id is not None:
+                ids.setdefault("query_id", tr.query_id)
+            ann = _annotation(SPAN_PREFIX + self.name, **ids)
+            ann.__enter__()
+            self._ann = ann
+            if tr is not None:
+                self._sp = tr.push(self.name, self.kind, t0, self.args)
+        except Exception:  # noqa: BLE001 — see _helper_failed
+            _helper_failed()
+        return self._sp
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter_ns()
+        self.elapsed_ns = t1 - self._t0
+        try:
+            if self._sp is not None:
+                self.tracer.pop(self._sp, t1)
+        except Exception:  # noqa: BLE001
+            _helper_failed()
+        try:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+        except Exception:  # noqa: BLE001
+            _helper_failed()
+        return False
 
 
 def propagation_enabled() -> bool:

@@ -784,8 +784,8 @@ class _ClusterExecutor:
         # trace_detail=full: each exchange pull is its own span
         full = str(self.spec.properties.get(
             "trace_detail", "basic")).lower() == "full"
-        pull_cm = TR.maybe_span(f"pull eid{inp['eid']}",
-                                eid=inp["eid"], kind_=inp["kind"]) \
+        pull_cm = TR.span(f"pull eid{inp['eid']}",
+                          eid=inp["eid"], kind_=inp["kind"]) \
             if full else None
         if pull_cm is not None:
             pull_cm.__enter__()

@@ -25,8 +25,10 @@ import numpy as np
 
 from presto_tpu.batch import Batch, Column
 from presto_tpu.exec import kernels as K
+from presto_tpu.observe import names as NM
 
 
+@NM.scoped("x:all_gather")
 def all_gather_batch(b: Batch, axis: str) -> Batch:
     """P2/P5: replicate a sharded batch on every shard (broadcast build
     sides, gather-to-coordinator).  Dictionaries are host-side and already
@@ -137,6 +139,7 @@ def _exchange_by_dest(b: Batch, dest: jnp.ndarray, ndev: int, axis: str,
     return Batch(cols, sel_out), overflow
 
 
+@NM.scoped("x:repartition")
 def repartition_batch(b: Batch, key_cols: List[Column], ndev: int, axis: str,
                       slack: float = 2.0) -> Tuple[Batch, jnp.ndarray]:
     """P1 hash repartition: every live row moves to shard
@@ -161,6 +164,7 @@ def _sort_key_ints(col: Column, ascending: bool, nulls_first) -> jnp.ndarray:
     return k
 
 
+@NM.scoped("x:range_partition")
 def range_partition_batch(b: Batch, sort_keys, ndev: int, axis: str,
                          samples_per_shard: int = 64, slack: float = 2.0
                          ) -> Tuple[Batch, jnp.ndarray]:

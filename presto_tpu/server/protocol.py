@@ -40,6 +40,8 @@ import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional
 
+from presto_tpu.observe import trace as TR
+
 PAGE_ROWS = 4096  # rows per protocol page (client re-chunks as needed)
 
 # the ONLY timing constants of the protocol loop (the serving lint rule
@@ -175,9 +177,10 @@ class PrestoTpuServer:
             # starve other groups — head-of-line blocking).  The abort
             # hook drains the wait on client cancel AND on graceful
             # shutdown (queued jobs then end CANCELED, terminally).
-            slot = self.serving.admit(self.session.user,
-                                      self.session.source,
-                                      abort=job.cancel.is_set)
+            with TR.span("admission.wait", statement_id=job.query_id):
+                slot = self.serving.admit(self.session.user,
+                                          self.session.source,
+                                          abort=job.cancel.is_set)
         except QueryRejected as e:
             if e.code == "SERVER_SHUTTING_DOWN" or job.cancel.is_set():
                 job.error = "Query was canceled: server is shutting down"
@@ -245,7 +248,8 @@ class PrestoTpuServer:
                     return
                 job.columns = [{"name": n, "type": str(t).lower()}
                                for n, t in result.columns]
-                job.rows = [list(r) for r in result.rows]
+                with TR.span("result.rows", statement_id=job.query_id):
+                    job.rows = [list(r) for r in result.rows]
                 st = result.stats  # this query's stats (not last_stats —
                 job.stats = {      # concurrent jobs would race)
                     "state": "FINISHED",
@@ -862,10 +866,15 @@ def _make_handler(server: PrestoTpuServer):
                     return self._json(proxied)
                 # owner unreachable: routing is an optimization — run it
                 # here (the version-keyed caches keep this correct)
-            job = server.submit(sql)
-            # brief grace so fast queries return data on the first response
-            job.done.wait(timeout=FIRST_RESPONSE_GRACE_S)
-            self._json(server.results_payload(job, 0))
+            with TR.span("http.post"):
+                with TR.span("http.submit"):
+                    job = server.submit(sql)
+                # brief grace so fast queries return data on the first
+                # response
+                with TR.span("http.grace_wait", statement_id=job.query_id):
+                    job.done.wait(timeout=FIRST_RESPONSE_GRACE_S)
+                with TR.span("http.encode", statement_id=job.query_id):
+                    self._json(server.results_payload(job, 0))
 
         def _fleet_post(self, action: str, body: bytes):
             """Peer-to-peer fleet bus: invalidation broadcast, health
@@ -909,30 +918,33 @@ def _make_handler(server: PrestoTpuServer):
                 return
             parts = [p for p in self.path.split("/") if p]
             if parts[:2] == ["v1", "statement"] and len(parts) == 4:
-                job = server.jobs.get(parts[2])
-                if job is None:
-                    owner = server.proxied_owner(parts[2])
-                    if owner is not None:
-                        proxied = server.proxy_fetch(owner, self.path)
-                        if proxied is not None:
-                            return self._json(proxied)
-                    # coordinator-death-mid-poll: an unknown qid that
-                    # the fleet journal knows is in flight elsewhere
-                    # (or being adopted right here) keeps the client
-                    # polling instead of 404ing
-                    adopted = server.journal_lookup(parts[2], self.path)
-                    if adopted is not None:
-                        return self._json(adopted)
-                    return self._json({"error": "unknown query"}, 404)
-                try:
-                    token = int(parts[3])
-                except ValueError:
-                    return self._json({"error": "bad page token"}, 400)
-                if token < 0:
-                    return self._json({"error": "bad page token"}, 400)
-                if job.state in ("QUEUED", "RUNNING"):
-                    job.done.wait(timeout=LONG_POLL_S)  # long poll
-                return self._json(server.results_payload(job, token))
+                with TR.span("http.get", statement_id=parts[2]):
+                    job = server.jobs.get(parts[2])
+                    if job is None:
+                        owner = server.proxied_owner(parts[2])
+                        if owner is not None:
+                            proxied = server.proxy_fetch(owner, self.path)
+                            if proxied is not None:
+                                return self._json(proxied)
+                        # coordinator-death-mid-poll: an unknown qid that
+                        # the fleet journal knows is in flight elsewhere
+                        # (or being adopted right here) keeps the client
+                        # polling instead of 404ing
+                        adopted = server.journal_lookup(parts[2], self.path)
+                        if adopted is not None:
+                            return self._json(adopted)
+                        return self._json({"error": "unknown query"}, 404)
+                    try:
+                        token = int(parts[3])
+                    except ValueError:
+                        return self._json({"error": "bad page token"}, 400)
+                    if token < 0:
+                        return self._json({"error": "bad page token"}, 400)
+                    if job.state in ("QUEUED", "RUNNING"):
+                        with TR.span("http.long_poll", statement_id=parts[2]):
+                            job.done.wait(timeout=LONG_POLL_S)  # long poll
+                    with TR.span("http.encode", statement_id=parts[2]):
+                        return self._json(server.results_payload(job, token))
             if parts == ["v1", "query"]:
                 return self._json(server.query_list_payload())
             if parts[:2] == ["v1", "query"] and len(parts) == 4 \

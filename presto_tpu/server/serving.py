@@ -64,6 +64,7 @@ import time
 from collections import OrderedDict
 from typing import Dict, List, Optional
 
+from presto_tpu.observe import trace as TR
 from presto_tpu.server.resource_groups import (QueryRejected,
                                                ResourceGroupManager)
 from presto_tpu.sql import ast
@@ -215,45 +216,46 @@ def execute_prepared(session, stmt: ast.Execute, mon, dispatch):
             or EX._VOLATILE_RE.search(entry.text) is not None:
         return fallback()
 
-    # bind values: literal -> (host value, engine Type) via the SAME
-    # lowering the substitution path's re-parse would apply, so the two
-    # paths type identically
-    lits = _fold_param_literals(stmt.parameters)
-    if lits is None or len(lits) != entry.n_params:
-        # non-literal parameters or a count mismatch: the substitution
-        # path raises the canonical errors
-        return fallback()
-    bound = []
-    for lit in lits:
-        try:
-            from presto_tpu.plan.planner import _literal_to_ir
-            il = _literal_to_ir(lit)
-        except Exception:
+    with TR.span("prepared.bind"):
+        # bind values: literal -> (host value, engine Type) via the SAME
+        # lowering the substitution path's re-parse would apply, so the two
+        # paths type identically
+        lits = _fold_param_literals(stmt.parameters)
+        if lits is None or len(lits) != entry.n_params:
+            # non-literal parameters or a count mismatch: the substitution
+            # path raises the canonical errors
             return fallback()
-        t = il.type
-        if t == T.UNKNOWN or t.is_string \
-                or (t.is_decimal and t.is_long_decimal) \
-                or t.name in ("VARBINARY", "TIMESTAMP_TZ", "TIME_TZ"):
-            return fallback()
-        bound.append((il.value, t))
-    sig = tuple(str(t) for _v, t in bound)
+        bound = []
+        for lit in lits:
+            try:
+                from presto_tpu.plan.planner import _literal_to_ir
+                il = _literal_to_ir(lit)
+            except Exception:
+                return fallback()
+            t = il.type
+            if t == T.UNKNOWN or t.is_string \
+                    or (t.is_decimal and t.is_long_decimal) \
+                    or t.name in ("VARBINARY", "TIMESTAMP_TZ", "TIME_TZ"):
+                return fallback()
+            bound.append((il.value, t))
+        sig = tuple(str(t) for _v, t in bound)
 
-    # typed template per signature (deep copy: Parameter.type_ is bound
-    # per signature and templates are shared across threads)
-    typed = entry.typed.get(sig)
-    if typed is None:
-        typed = copy.deepcopy(entry.template)
-        types_by_pos = {i: t for i, (_v, t) in enumerate(bound)}
-        for p in _walk_params(typed):
-            p.type_ = types_by_pos[p.position]
-        if len(entry.typed) >= MAX_TYPED_ENTRIES:
-            entry.typed.clear()
-        entry.typed[sig] = typed
-    mon.stats.prepared_binds += 1
+        # typed template per signature (deep copy: Parameter.type_ is bound
+        # per signature and templates are shared across threads)
+        typed = entry.typed.get(sig)
+        if typed is None:
+            typed = copy.deepcopy(entry.template)
+            types_by_pos = {i: t for i, (_v, t) in enumerate(bound)}
+            for p in _walk_params(typed):
+                p.type_ = types_by_pos[p.position]
+            if len(entry.typed) >= MAX_TYPED_ENTRIES:
+                entry.typed.clear()
+            entry.typed[sig] = typed
+        mon.stats.prepared_binds += 1
 
-    # the VALUE-free cache key: template text + type signature (+ the
-    # session fingerprint inside run_compiled's own key)
-    key_text = "$prepared$" + CC.fingerprint(entry.text, sig)
+        # the VALUE-free cache key: template text + type signature (+ the
+        # session fingerprint inside run_compiled's own key)
+        key_text = "$prepared$" + CC.fingerprint(entry.text, sig)
 
     # result cache, per rider and BEFORE any batching: the substituted
     # template text is the canonical cache identity (identical to what
@@ -612,8 +614,9 @@ class QueryCoalescer:
     # -- leader --------------------------------------------------------
     def _lead(self, gk, g, mon, window_s, run_batched, run_solo):
         t0 = time.monotonic()
-        if window_s > 0:
-            g.full.wait(timeout=window_s)
+        with TR.span("coalesce.window"):
+            if window_s > 0:
+                g.full.wait(timeout=window_s)
         with self._lock:
             g.closed = True  # late arrivals form their own group
             if self._groups.get(gk) is g:
@@ -656,7 +659,8 @@ class QueryCoalescer:
 
     # -- rider ---------------------------------------------------------
     def _ride(self, g, idx, mon, run_solo):
-        g.done.wait(timeout=COALESCE_RIDER_WAIT_S)
+        with TR.span("coalesce.ride"):
+            g.done.wait(timeout=COALESCE_RIDER_WAIT_S)
         if g.fallback or g.results is None:
             mon.stats.coalesce_fallbacks += 1
             with self._lock:

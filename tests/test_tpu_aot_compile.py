@@ -120,3 +120,50 @@ def test_pack_fetch_12_columns(one_chip):
 
     _compile(pack, one_chip, ((n,), jnp.bool_), ((), jnp.int32),
              *[((n,), d) for d in dts])
+
+
+SCOPED_ROWS = 8_192   # the names are the point here, not the sizes: the
+# v5e's compiler takes minutes over 64-bit sorts of N_ROWS
+
+
+def _scoped_kernels():
+    """name -> (function, shapes, the scopes the v5e's HLO must name)."""
+    def sort_then_probe(build, probe):
+        with jax.named_scope("Join"):
+            return K.build_probe(build, probe)
+
+    def take(a, b, idx):
+        with jax.named_scope("Join"):
+            return K.take_rows([a, b], idx)
+
+    def agg(v, g):
+        with jax.named_scope("Aggregate"):
+            return K.fused_group_sums(v, g, 6), K.segment_max(v[0], g, 6)
+
+    return {
+        "build_probe": (sort_then_probe,
+                        (((SCOPED_ROWS // 4,), jnp.int32),
+                         ((SCOPED_ROWS,), jnp.int32)),
+                        ["Join/k:build_probe/", "k:build_probe/k:sort/"]),
+        "take_rows": (take, (((N_ROWS,), jnp.int32), ((N_ROWS,), jnp.float32),
+                             ((SCOPED_ROWS,), jnp.int32)),
+                      ["Join/k:take_rows."]),
+        "aggregate": (agg, (((8, N_ROWS + 5), jnp.float32),
+                            ((N_ROWS + 5,), jnp.int32)),
+                      ["Aggregate/k:fused_group_sums/",
+                       "Aggregate/k:fused_group_sums/"
+                       "k:fused_group_sums.operand/",
+                       "Aggregate/k:segment/"]),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["build_probe", "take_rows", "aggregate"])
+def test_scoped_kernels_lower_and_keep_their_names(one_chip, as_tpu, kernel):
+    """The kernel scopes (observe/names.py) are debug info only: the scoped
+    kernels still compile for the v5e, and the chip's HLO — whose
+    instruction lines are what a profile's device events are named by —
+    holds the scope in `op_name`."""
+    fn, shapes, scopes = _scoped_kernels()[kernel]
+    text = _compile(fn, one_chip, *shapes).as_text()
+    for scope in scopes:
+        assert scope in text, scope
