@@ -125,6 +125,7 @@ def main():
             df_econ[str(qid)] = {
                 "produced": r.stats.df_filters_produced,
                 "applied": r.stats.df_filters_applied,
+                "declined": r.stats.df_filters_declined,
                 "rows_pruned": r.stats.df_rows_pruned,
                 "chunks_pruned": r.stats.df_chunks_pruned,
                 "splits_pruned": r.stats.df_splits_pruned,

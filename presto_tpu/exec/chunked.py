@@ -1114,8 +1114,9 @@ class _FragmentRunner:
         available host-side BEFORE the loop) are compared against the
         grid's per-chunk zone maps; chunks whose ranges miss every
         runtime domain are never dispatched.  Strictly best-effort: no
-        grid hook or no resident build means no pruning, and the
-        in-trace row filter still applies inside every kept chunk."""
+        grid hook or no resident build means no pruning.  Inside a kept
+        chunk nothing is masked: the per-chunk programs are compiled and
+        decline the row filter (Executor._rf_mask_pays)."""
         from presto_tpu.plan import runtime_filters as RF
 
         if not RF.enabled(self.session):
@@ -1143,9 +1144,9 @@ class _FragmentRunner:
             return grid
         pruned = grid.nchunks - len(keep)
         if not keep:
-            # degenerate all-pruned grid: keep one chunk — the in-trace
-            # filter masks its rows, so the output is empty anyway and
-            # every downstream shape stays well-formed
+            # degenerate all-pruned grid: keep one chunk — the join
+            # finds no match for its rows, so the output is empty anyway
+            # and every downstream shape stays well-formed
             keep = [0]
             pruned = grid.nchunks - 1
         self.run_stats["df_chunks_pruned"] = \

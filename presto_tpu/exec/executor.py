@@ -53,6 +53,7 @@ def _merge_sort_stats(stats, counts: dict) -> None:
     for k in ("sorts_taken", "sorts_elided", "sort_memo_hits",
               "ordering_guard_trips",
               "df_filters_produced", "df_filters_applied",
+              "df_filters_declined",
               "df_rows_pruned", "df_chunks_pruned", "df_splits_pruned",
               "fragments_fused", "exchange_bytes_host",
               "exchange_bytes_collective", "exchange_bytes_sketch",
@@ -1361,6 +1362,20 @@ class Executor:
         and a membership filter over a partial set would prune probe
         rows that match on other shards."""
         return True
+
+    def _rf_mask_pays(self) -> bool:
+        """Does a consumer here do more with a filter's membership mask
+        than AND it into sel?  Dynamic mode does: pruned rows are
+        compacted away, counted, and turned into stripe/zone-map
+        Domains.  A static trace has fixed shapes — a masked row costs
+        what a live row costs in every operator up to the join, which
+        then drops it itself (an INNER/SEMI join IS the filter, without
+        false positives) — so compiled programs decline the mask
+        (df_filters_declined).  A cluster task overrides this: it does
+        not ship a pruned row.  The mesh executor overrides it too, but
+        only to stay as on the parent: its shapes are fixed like these,
+        and no cell has priced its mask yet (PERF.md section 7)."""
+        return not self.static
 
     def _rf_register(self, specs, right: Batch) -> None:
         """Producer side: derive + register the build-key summaries of
@@ -3325,8 +3340,15 @@ class Executor:
         from presto_tpu.memory.context import batch_bytes
 
         produce = getattr(node, "rf_produce", None)
-        if produce and node.join_type in ("INNER", "SEMI") \
-                and self._df_enabled() and self._rf_build_complete(node):
+        if not (produce and node.join_type in ("INNER", "SEMI")
+                and self._df_enabled() and self._rf_build_complete(node)):
+            produce = None
+        elif not self._rf_mask_pays():
+            # nothing is registered: the probe scans find no summary
+            # and run filter-free, as under an unannotated join
+            self._count("df_filters_declined", len(produce))
+            produce = None
+        if produce:
             # dynamic filtering: run the BUILD side first and register
             # its key summary, so the probe subtree's scans consume the
             # completed filter before they execute (the reference gates

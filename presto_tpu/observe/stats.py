@@ -105,14 +105,16 @@ class QueryStats:
     compile_cache_hits: int = 0
     compile_ahead_hits: int = 0
     # dynamic filtering (plan/runtime_filters.py): build-side runtime
-    # filters produced / applied at probe scans, rows pruned before the
-    # join (dynamic + cluster modes count rows; compiled/chunked modes
-    # count TRACE-TIME routing decisions, like the sort economics),
+    # filters produced / applied at probe scans, filters a compiled
+    # program DECLINED to trace (Executor._rf_mask_pays: fixed shapes,
+    # so a mask shrinks nothing — counted at trace time, like the sort
+    # economics), rows pruned before the join (dynamic + cluster modes),
     # whole chunks skipped by the chunked runner, shard stripes pruned
     # by runtime domains, and cluster-side wall spent waiting on the
     # filter side channel (bounded by dynamic_filtering_wait_ms).
     df_filters_produced: int = 0
     df_filters_applied: int = 0
+    df_filters_declined: int = 0
     df_rows_pruned: int = 0
     df_chunks_pruned: int = 0
     df_splits_pruned: int = 0

@@ -1007,6 +1007,10 @@ class _ClusterExecutor:
 
                 return complete(node.right)
 
+            def _rf_mask_pays(ex_self) -> bool:
+                # a cluster task does not ship a pruned row
+                return True
+
             def _exec_tablescan(ex_self, node: P.TableScan) -> Batch:
                 if node.table in exch:
                     b = exch[node.table]
@@ -1625,6 +1629,7 @@ class WorkerServer:
                          # per-task filter activity aggregates here so
                          # tests/operators can see cluster-wide pruning
                          "df_filters_produced": 0, "df_filters_applied": 0,
+                         "df_filters_declined": 0,
                          "df_rows_pruned": 0, "df_wait_ms": 0.0,
                          # fragment fusion (plan/distribute.py): fused
                          # super-fragment tasks executed here, original

@@ -97,6 +97,12 @@ class DistExecutor(Executor):
 
         return complete(node.right)
 
+    def _rf_mask_pays(self) -> bool:
+        # kept as on the parent until sf1_mesh4_join can price it, not
+        # because it pays: a shard_map program's shapes are fixed too
+        # (PERF.md section 7: goes if that cell shows what sf1_join did)
+        return True
+
     def _exchange_bytes(self, b: Batch) -> int:
         """Trace-time byte estimate of one collective exchange: every
         shard contributes its per-shard payload, so the mesh moves

@@ -633,6 +633,7 @@ class PrestoTpuServer:
             "dynamicFilters": {
                 "produced": getattr(st, "df_filters_produced", 0),
                 "applied": getattr(st, "df_filters_applied", 0),
+                "declined": getattr(st, "df_filters_declined", 0),
                 "rowsPruned": getattr(st, "df_rows_pruned", 0),
                 "chunksPruned": getattr(st, "df_chunks_pruned", 0),
                 "splitsPruned": getattr(st, "df_splits_pruned", 0),

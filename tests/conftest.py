@@ -58,3 +58,21 @@ def _bound_suite_memory():
     compile_cache.clear()  # executable memo would pin what jax frees
     _jax.clear_caches()
     gc.collect()
+
+
+@pytest.fixture
+def lowered_texts(monkeypatch):
+    """Every program the engine AOT-compiles, as lowered text with debug
+    info, in build order."""
+    from presto_tpu.exec import compile_cache as CC
+
+    texts = []
+    real = CC.Executable.aot_compile
+
+    def spy(self, example_args):
+        shapes = jax.tree_util.tree_map(CC._shape_struct, example_args)
+        texts.append(self._jitted.lower(*shapes).as_text(debug_info=True))
+        return real(self, example_args)
+
+    monkeypatch.setattr(CC.Executable, "aot_compile", spy)
+    return texts

@@ -8,10 +8,13 @@ Layers under test:
   heuristic's false-positive rate and the host summary/union twins.
 - plan/runtime_filters.py: producer/consumer annotation of q17-class
   plans, the kill switch, and domain merge (intersection) semantics.
-- executor: dynamic mode counts pruned rows; compiled mode keeps the
-  filter inside the trace; results are IDENTICAL with filtering on/off.
+- executor: dynamic mode counts pruned rows; a compiled program
+  DECLINES the mask (df_filters_declined: fixed shapes, nothing would
+  shrink) and traces nothing under k:runtime_filter; sharded executors
+  keep it; results are IDENTICAL with filtering on/off.
 - exec/chunked.py: whole chunks whose zone ranges miss the runtime
-  domain are skipped (df_chunks_pruned), results identical.
+  domain are skipped (df_chunks_pruned) while the per-chunk programs
+  decline the mask, results identical.
 - parallel/cluster.py: in-fragment filters on broadcast-build joins and
   the coordinator-routed side channel for partitioned joins (partial
   summaries unioned per repartition bucket), observable via /v1/info.
@@ -242,8 +245,9 @@ def test_q8_q19_dynamic_identical(dyn_sessions, qid):
 
 
 def test_q17_compiled_on_off_identical(tpch_catalog_tiny):
-    """Compiled mode: the filter is built and probed INSIDE the traced
-    program (trace-time df counters), results identical on/off."""
+    """Compiled mode: shapes are fixed, so the mask would shrink nothing
+    — the program declines it at trace time (df_filters_declined),
+    produces and applies no filter, results identical on/off."""
     on = presto_tpu.connect(tpch_catalog_tiny, execution_mode="compiled")
     off = presto_tpu.connect(tpch_catalog_tiny, execution_mode="compiled",
                              dynamic_filtering=False)
@@ -251,8 +255,122 @@ def test_q17_compiled_on_off_identical(tpch_catalog_tiny):
     r_off = off.sql(QUERIES[17])
     assert norm(r_on.rows) == norm(r_off.rows)
     assert r_on.stats.execution_mode == "compiled"
-    assert r_on.stats.df_filters_applied >= 1
+    assert r_on.stats.df_filters_declined >= 1
+    assert r_on.stats.df_filters_applied == 0
+    assert r_on.stats.df_filters_produced == 0
+    assert r_off.stats.df_filters_declined == 0
     assert r_off.stats.df_filters_applied == 0
+
+
+def _annotated_filters(session, sql):
+    """How many filters the planner wires into sql's joins."""
+    from presto_tpu.exec.executor import plan_statement
+    from presto_tpu.sql.parser import parse
+
+    plan = plan_statement(session, parse(sql))
+    specs, seen = [], set()
+
+    def walk(n):
+        if id(n) in seen:
+            return
+        seen.add(id(n))
+        specs.extend(getattr(n, "rf_produce", None) or [])
+        for s in n.sources:
+            walk(s)
+
+    walk(plan.root)
+    for sub in plan.subplans.values():
+        walk(sub)
+    return len(specs)
+
+
+@pytest.mark.parametrize("query", [3, 18], ids=["tpch_q3", "tpch_q18"])
+def test_compiled_program_traces_no_runtime_filter(
+        tpch_catalog_tiny, lowered_texts, monkeypatch, query):
+    """TPC-H's Q3 and Q18 (the join queries sf1_join serves): the
+    compiled program holds NO operation under k:runtime_filter and
+    counts every annotated filter as declined; dynamic mode still
+    builds, probes and prunes."""
+    from presto_tpu.exec import compile_cache as CC
+
+    # the tiny catalog's probes sit under the planner's 50k-row gate
+    monkeypatch.setenv("PRESTO_TPU_DF_MIN_PROBE", "1000")
+    calls = []
+    real_build, real_probe = K.rf_build, K.rf_probe
+    monkeypatch.setattr(K, "rf_build", lambda *a, **k: (
+        calls.append("build"), real_build(*a, **k))[1])
+    monkeypatch.setattr(K, "rf_probe", lambda *a, **k: (
+        calls.append("probe"), real_probe(*a, **k))[1])
+    sql = QUERIES[query]
+
+    compiled = presto_tpu.connect(tpch_catalog_tiny,
+                                  execution_mode="compiled")
+    filters = _annotated_filters(compiled, sql)
+    assert filters >= 2
+    CC.clear()      # nothing in the process-wide memo: built, spied on
+    r_c = compiled.sql(sql)
+    assert r_c.stats.execution_mode == "compiled"
+    assert lowered_texts, "no program was built"
+    assert "k:runtime_filter" not in "\n".join(lowered_texts)
+    assert not calls
+    assert r_c.stats.df_filters_declined == filters
+    assert r_c.stats.df_filters_produced == 0
+    assert r_c.stats.df_filters_applied == 0
+    assert r_c.stats.df_rows_pruned == 0
+
+    dynamic = presto_tpu.connect(tpch_catalog_tiny,
+                                 execution_mode="dynamic")
+    r_d = dynamic.sql(sql)
+    assert norm(r_d.rows) == norm(r_c.rows)
+    assert calls.count("build") == filters
+    assert calls.count("probe") == filters
+    assert r_d.stats.df_filters_declined == 0
+    assert r_d.stats.df_filters_produced == filters
+    assert r_d.stats.df_filters_applied == filters
+    assert r_d.stats.df_rows_pruned > 0
+
+
+def _probe_scan_and_batch():
+    """A probe-side scan annotated as the consumer of filter df0, its
+    batch (keys 0..15), and a device summary of the build keys."""
+    from presto_tpu.batch import Batch
+    from presto_tpu.plan import nodes as P
+
+    scan = P.TableScan("t", {"a": "a"}, {"a": T.BIGINT})
+    scan.rf_consume = [{"fid": "df0", "sym": "a", "column": "a"}]
+    batch = Batch({"a": Column(jnp.arange(16), None, T.BIGINT, None)},
+                  jnp.ones((16,), bool))
+    build = Column(jnp.asarray([1, 2, 3, 5, 8]), None, T.BIGINT, None)
+    return scan, batch, K.rf_build(build, jnp.ones((5,), bool))
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["compiled", "mesh_shard"])
+def test_injected_summaries_apply_in_a_static_trace(tpch_catalog_tiny,
+                                                    sharded):
+    """_rf_apply is not gated by _rf_mask_pays: a summary that arrives
+    from outside (rf_inject, the cluster's side channel) masks the scan
+    in a static executor too.  Only a single-device compiled program
+    declines to PRODUCE one; a mesh shard answers the predicate True."""
+    from presto_tpu.exec.executor import Executor
+    from presto_tpu.parallel.dist_executor import DistExecutor
+
+    session = presto_tpu.connect(tpch_catalog_tiny)
+    if sharded:
+        ex = DistExecutor(session, 2, scan_inputs={})
+    else:
+        ex = Executor(session, static=True)
+    assert ex.static
+    assert ex._rf_mask_pays() is sharded
+    assert Executor(session)._rf_mask_pays()    # dynamic mode: it pays
+    scan, batch, summary = _probe_scan_and_batch()
+    assert ex._rf_apply(scan, batch) is batch   # nothing registered
+    ex.rf_inject({"df0": summary})
+    out = ex._rf_apply(scan, batch)
+    assert (np.asarray(out.sel)
+            == np.isin(np.arange(16), [1, 2, 3, 5, 8])).all()
+    assert ex.sort_stats["df_filters_applied"] == 1
+    assert "df_filters_declined" not in ex.sort_stats
 
 
 # ---------------------------------------------------------------------------
@@ -288,24 +406,53 @@ def test_chunked_runtime_domain_prunes_chunks(tpch_catalog_tiny):
         assert norm(r_on.rows) == norm(r_off.rows) == norm(r_whole.rows)
         assert r_on.stats.execution_mode == "chunked"
         assert r_on.stats.df_chunks_pruned > 0
-        assert r_on.stats.df_filters_applied >= 1
+        # the per-chunk programs are compiled: they decline the mask
+        assert r_on.stats.df_filters_declined >= 1
+        assert r_on.stats.df_filters_applied == 0
         assert r_off.stats.df_chunks_pruned == 0
+        assert r_off.stats.df_filters_declined == 0
     finally:
         whole.sql("DROP TABLE ok_list")
 
 
+def test_chunked_all_chunks_pruned_keeps_one_and_stays_empty(
+        tpch_catalog_tiny):
+    """A build whose keys miss EVERY chunk: the grid keeps one chunk so
+    downstream shapes stay well-formed, and with the in-trace mask
+    declined it is the join itself that drops that chunk's rows."""
+    ddl = ("CREATE TABLE far_list AS SELECT o_orderkey + 100000000 AS k "
+           "FROM orders WHERE o_orderkey < 2000")
+    q = ("SELECT count(*) c, sum(l_quantity) q FROM lineitem, far_list "
+         "WHERE l_orderkey = k")
+    s_on = _chunked_session(tpch_catalog_tiny, True)
+    s_off = _chunked_session(tpch_catalog_tiny, False)
+    s_on.sql(ddl)  # the catalog is shared: create once
+    try:
+        r_on = s_on.sql(q)
+        r_off = s_off.sql(q)
+        assert norm(r_on.rows) == norm(r_off.rows)
+        assert r_on.rows[0][0] == 0
+        assert r_on.stats.execution_mode == "chunked"
+        assert r_on.stats.df_chunks_pruned > 0
+        assert r_on.stats.df_filters_applied == 0
+    finally:
+        s_on.sql("DROP TABLE far_list")
+
+
 @pytest.mark.slow
 def test_chunked_q17_on_off_identical(tpch_catalog_tiny):
-    """q17 chunked: the in-trace filter applies (trace counter), results
-    identical.  Chunk pruning is honestly 0 here — l_partkey does not
-    correlate with the orderkey-range chunk grid (docs/PERF.md r10)."""
+    """q17 chunked: the per-chunk programs decline the in-trace filter
+    (trace counter), results identical.  Chunk pruning is honestly 0
+    here — l_partkey does not correlate with the orderkey-range chunk
+    grid (docs/PERF.md r10)."""
     s_on = _chunked_session(tpch_catalog_tiny, True)
     s_off = _chunked_session(tpch_catalog_tiny, False)
     r_on = s_on.sql(QUERIES[17])
     r_off = s_off.sql(QUERIES[17])
     assert r_on.stats.execution_mode == "chunked"
     assert norm(r_on.rows) == norm(r_off.rows)
-    assert r_on.stats.df_filters_applied >= 1
+    assert r_on.stats.df_filters_declined >= 1
+    assert r_on.stats.df_filters_applied == 0
 
 
 # ---------------------------------------------------------------------------
@@ -341,7 +488,8 @@ def df_cluster(tpch_catalog_tiny):
 
 
 def _df_delta(workers, before):
-    keys = ("df_filters_produced", "df_filters_applied", "df_rows_pruned")
+    keys = ("df_filters_produced", "df_filters_applied",
+            "df_filters_declined", "df_rows_pruned")
     after = [_worker_counters(w.url) for w in workers]
     return {k: sum(a[k] - b[k] for a, b in zip(after, before))
             for k in keys}
@@ -360,6 +508,40 @@ def test_cluster_broadcast_filters_in_fragment(df_cluster):
     d = _df_delta(workers, before)
     assert d["df_filters_applied"] >= 1, d
     assert d["df_rows_pruned"] > 0, d
+
+
+def test_cluster_tasks_keep_their_filters(df_cluster, monkeypatch):
+    """Tier-1 twin of the side-channel test below: a cluster task's
+    FragmentExecutor answers _rf_mask_pays True (a pruned row is a row
+    no exchange ships), so the partitioned join's summaries still
+    travel, apply and prune on the workers, and nothing is declined."""
+    from presto_tpu.exec.executor import Executor
+
+    session, cs, workers = df_cluster
+    pays = []
+    real = Executor._exec_join
+
+    def spy(self, node):
+        if type(self).__name__ == "FragmentExecutor":
+            pays.append(self._rf_mask_pays())
+        return real(self, node)
+
+    monkeypatch.setattr(Executor, "_exec_join", spy)
+    want = norm(session.sql(CLUSTER_Q).rows)
+    session.set("broadcast_join_threshold_rows", 0)
+    session.set("dynamic_filtering_wait_ms", 8000)
+    before = [_worker_counters(w.url) for w in workers]
+    try:
+        got = cs.sql(CLUSTER_Q)
+    finally:
+        session.set("broadcast_join_threshold_rows", 1_000_000)
+        session.set("dynamic_filtering_wait_ms", 0)
+    assert norm(got.rows) == want
+    assert pays and all(pays)
+    d = _df_delta(workers, before)
+    assert d["df_filters_applied"] >= 1, d
+    assert d["df_rows_pruned"] > 0, d
+    assert d["df_filters_declined"] == 0, d
 
 
 @pytest.mark.slow
@@ -401,4 +583,4 @@ def test_cluster_kill_switch_no_activity(df_cluster):
     assert norm(got.rows) == want
     d = _df_delta(workers, before)
     assert d == {"df_filters_produced": 0, "df_filters_applied": 0,
-                 "df_rows_pruned": 0}, d
+                 "df_filters_declined": 0, "df_rows_pruned": 0}, d

@@ -244,6 +244,9 @@ def test_query_detail_plan_and_timeline(server):
     assert set(ff) >= {"fragmentsFused", "edgesFused", "edgesCut",
                        "edgesMispredicted", "costMillis", "skips"}
     assert isinstance(ff["skips"], dict)
+    # dynamic-filter economics, the trace-time decline count included
+    assert set(detail["dynamicFilters"]) >= {
+        "produced", "applied", "declined", "rowsPruned", "chunksPruned"}
 
 
 def test_query_detail_node_stats_dynamic(server):

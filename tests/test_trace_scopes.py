@@ -37,22 +37,6 @@ def read(name):
         return f.read().strip()
 
 
-@pytest.fixture
-def lowered_texts(monkeypatch):
-    """Every program the engine AOT-compiles, as lowered text with debug
-    info, in build order."""
-    texts = []
-    real = CC.Executable.aot_compile
-
-    def spy(self, example_args):
-        shapes = jax.tree_util.tree_map(CC._shape_struct, example_args)
-        texts.append(self._jitted.lower(*shapes).as_text(debug_info=True))
-        return real(self, example_args)
-
-    monkeypatch.setattr(CC.Executable, "aot_compile", spy)
-    return texts
-
-
 @pytest.mark.parametrize("query", sorted(EXPECTED))
 def test_lowered_text_holds_the_scopes(tpch_catalog_tiny, lowered_texts,
                                        query):

@@ -12,7 +12,9 @@ time, so every measurement runs K iterations INSIDE one jitted program
 returns a scalar, and subtracts the measured empty-program round trip;
 per-iteration time = (t - t_rtt) / K.
 
-Prints ONE JSON line; run `python tools/roofline.py` on the chip.
+Prints ONE JSON line; run `python tools/roofline.py` on the chip
+(`python tools/roofline.py dynfilter`: the dynamic-filter sweep alone, at
+the shapes of the benchmark's sf1_join).
 The numbers land in docs/PERF.md.
 """
 
@@ -498,12 +500,111 @@ def sketch_sweep(per_iter, rng, nexps=(20, 22, 23)):
     return sout
 
 
-def sketch_anchor(nexps):
-    """Standalone `--sketch` entry: run ONLY the sketch sweep and print
-    one JSON line.  main() includes the sweep in the full roofline; this
-    entry exists so the docs/PERF.md anchor can be re-measured on a CPU
-    host without paying for the whole sweep (ROOFLINE_K overrides the
-    iteration count the way the committed agg anchor used K=5)."""
+def dynfilter_sweep(per_iter, rng, shapes=((1 << 22, 1 << 14),),
+                    pcts=(1, 10, 50, 90)):
+    """Dynamic filtering: what a runtime filter costs and what a
+    compaction fed by it would give back.
+
+    Per (probe rows, build rows) shape and probe selectivity: the
+    build-side summary (rf_build) and the probe-side mask (rf_probe)
+    per membership structure — the routing constants in exec/kernels.py
+    (RF_EXACT_MAX, bloom sizing) — and build_probe at the probe's full
+    capacity against the probe compacted to the survivors' power-of-two
+    bound, its compaction included.  On this engine a static join's
+    cost scales with capacity, so compaction is the only place pruned
+    rows turn into wall-clock: a compiled program that only ANDs the
+    mask into sel pays *_probe_ms and gets nothing (PERF.md section 6,
+    PR 28).  The build is the whole key range [0, nbuild) and the probe
+    is uniform over nbuild * 100 / pct values, so pct % of the probe
+    rows survive."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from presto_tpu import types as PT
+    from presto_tpu.batch import Column as PCol
+    from presto_tpu.exec import kernels as KK
+
+    def col(v):
+        return PCol(v, None, PT.BIGINT, None)
+
+    out = {}
+    for nprobe, nbuild in shapes:
+        live = jnp.ones((nbuild,), bool)
+        bvals = jnp.asarray(rng.permutation(nbuild).astype(np.int64))
+        sb = jnp.sort(bvals)
+        shape_out = {}
+        for pct in pcts:
+            pvals = jnp.asarray(rng.integers(0, nbuild * 100 // pct, nprobe))
+            cell = {}
+            for structure in ("exact", "bloom"):
+                @jax.jit
+                def build_loop(bv):
+                    def body(i, s):
+                        summ = KK.rf_build(col(bv ^ s), live,
+                                           structure=structure)
+                        arr = summ["keys" if structure == "exact"
+                                   else "bits"]
+                        return arr[-1].astype(jnp.int64) & 1
+
+                    return lax.fori_loop(0, K, body, jnp.int64(0))
+
+                cell[f"{structure}_build_ms"] = round(
+                    per_iter(timed(build_loop, bvals)) * 1000, 2)
+                summary = KK.rf_build(col(bvals), live, structure=structure)
+                kind = summary.pop("kind")
+
+                # the summary rides as an argument: a 4-64 MB bitset
+                # would otherwise be baked into the program as a constant
+                @jax.jit
+                def probe_loop(summ, pv):
+                    def body(i, s):
+                        m = KK.rf_probe({"kind": kind, **summ},
+                                        col(pv ^ (s & 1)))
+                        return jnp.sum(m).astype(jnp.int64)
+
+                    return lax.fori_loop(0, K, body, jnp.int64(0))
+
+                cell[f"{structure}_probe_ms"] = round(
+                    per_iter(timed(probe_loop, summary, pvals)) * 1000, 2)
+            # downstream: full-capacity join (off) vs compact-then-join
+            ncap = 1 << max(int(np.ceil(np.log2(nprobe * pct / 100 * 1.25))),
+                            12)
+            mask = pvals < nbuild   # the exact mask, made for free here
+
+            @jax.jit
+            def join_full(pv):
+                def body(i, s):
+                    _o, lb, ub = KK.build_probe(sb, pv ^ s)
+                    return (ub[0] - lb[0]).astype(jnp.int32)
+
+                return lax.fori_loop(0, K, body, jnp.int32(0))
+
+            @jax.jit
+            def join_compacted(pv, m):
+                def body(i, s):
+                    # (s >= 0 always: keeps the compaction in the loop)
+                    idx = KK.nonzero_i32(m & (s >= 0), ncap, 0)
+                    _o, lb, ub = KK.build_probe(sb, pv[idx] ^ s)
+                    return (ub[0] - lb[0]).astype(jnp.int32)
+
+                return lax.fori_loop(0, K, body, jnp.int32(0))
+
+            cell["join_off_ms"] = round(
+                per_iter(timed(join_full, pvals)) * 1000, 2)
+            cell["join_compacted_rows"] = ncap
+            cell["join_compacted_ms"] = round(
+                per_iter(timed(join_compacted, pvals, mask)) * 1000, 2)
+            shape_out[f"sel{pct}"] = cell
+        out[f"probe{nprobe}_build{nbuild}"] = shape_out
+    return out
+
+
+def _anchor(name, sweep):
+    """Run ONE sweep of main() alone and print one JSON line, so that an
+    anchor can be re-measured without paying for the whole roofline
+    (ROOFLINE_K overrides the iteration count)."""
     global K
     K = int(os.environ.get("ROOFLINE_K", K))
     import jax
@@ -520,8 +621,27 @@ def sketch_anchor(nexps):
         return max(t - rtt, 1e-9) / K
 
     out = {"device": str(dev), "platform": dev.platform, "iters": K,
-           "sketch": sketch_sweep(per_iter, rng, nexps)}
+           "rtt_ms": round(rtt * 1000, 1), name: sweep(per_iter, rng)}
     print(json.dumps(out), flush=True)
+
+
+def sketch_anchor(nexps):
+    """Standalone `--sketch` entry: ONLY the sketch sweep, so the
+    docs/PERF.md anchor can be re-measured on a CPU host (the committed
+    agg anchor used ROOFLINE_K=5)."""
+    _anchor("sketch", lambda per_iter, rng: sketch_sweep(per_iter, rng, nexps))
+
+
+#: (probe rows, build rows) of sf1_join's filters: orders probed with
+#: customer's keys (Q3 df0, Q18 df1), lineitem with orders' (the rest)
+DYNFILTER_SF1 = ((1_500_000, 150_000), (6_000_000, 1_500_000))
+
+
+def dynfilter_anchor():
+    """Standalone `dynfilter` entry: ONLY the dynamic-filter sweep, at
+    the shapes of sf1_join's filters and the selectivities Q3 sees."""
+    _anchor("dynfilter", lambda per_iter, rng: dynfilter_sweep(
+        per_iter, rng, DYNFILTER_SF1, pcts=(10, 50)))
 
 
 def main():
@@ -841,67 +961,8 @@ def main():
     out["compile"] = cout
 
     # --- dynamic filtering: probe selectivity x membership structure --
-    # Pins the routing constants in exec/kernels.py (RF_EXACT_MAX, bloom
-    # sizing): what the probe-side mask costs per structure at q17-like
-    # shapes (6M-row probe, 16k-key build), and what the downstream join
-    # gets back when the mask's selectivity lets the probe COMPACT to a
-    # fraction of its capacity before build_probe (on this engine the
-    # static join cost scales with capacity, so compaction is where
-    # pruned rows turn into wall-clock).  Swept at 1/10/50/90% probe
-    # selectivity; "off" is the unfiltered join baseline.
-    from presto_tpu import types as PT
-    from presto_tpu.batch import Column as PCol
-
-    dout = {}
-    nprobe_df = 1 << 22
-    nbuild_df = 1 << 14
-    dsel = jnp.ones((nbuild_df,), bool)
-    for pct in (1, 10, 50, 90):
-        # build keys live in the first pct% of the probe key domain, so
-        # P(probe row survives) == pct/100 exactly
-        dom = 1 << 20
-        cut = max(dom * pct // 100, 1)
-        bvals = jnp.asarray(rng.integers(0, cut, nbuild_df))
-        pvals = jnp.asarray(rng.integers(0, dom, nprobe_df))
-        bcol = PCol(bvals, None, PT.BIGINT, None)
-        pcol = PCol(pvals, None, PT.BIGINT, None)
-        cell = {}
-        for structure in ("exact", "bloom"):
-            summary = KK.rf_build(bcol, dsel, structure=structure)
-
-            @jax.jit
-            def probe_loop(pv):
-                def body(i, s):
-                    m = KK.rf_probe(summary,
-                                    PCol(pv ^ s, None, PT.BIGINT, None))
-                    return jnp.sum(m).astype(jnp.int64)
-
-                return lax.fori_loop(0, K, body, jnp.int64(0))
-
-            cell[f"{structure}_probe_ms"] = round(
-                per_iter(timed(probe_loop, pvals)) * 1000, 2)
-        # downstream: full-capacity join (off) vs masked+compacted join
-        mask = KK.rf_probe(KK.rf_build(bcol, dsel, structure="exact"),
-                           pcol)
-        ncap = 1 << max(int(np.ceil(np.log2(nprobe_df * pct / 100))), 12)
-        idx = KK.nonzero_i32(mask, ncap, 0)
-        pkept = pvals[idx]
-        sb = jnp.sort(bvals)
-
-        @jax.jit
-        def join_full(pv):
-            def body(i, s):
-                _o, lb, ub = KK.build_probe(sb, pv ^ s)
-                return (ub[0] - lb[0]).astype(jnp.int32)
-
-            return lax.fori_loop(0, K, body, jnp.int32(0))
-
-        cell["join_off_ms"] = round(
-            per_iter(timed(join_full, pvals)) * 1000, 2)
-        cell["join_filtered_ms"] = round(
-            per_iter(timed(join_full, pkept)) * 1000, 2)
-        dout[f"sel{pct}"] = cell
-    out["dynfilter"] = dout
+    # (dynfilter_sweep above; `dynfilter` runs it alone at Q3/Q18 shapes)
+    out["dynfilter"] = dynfilter_sweep(per_iter, rng)
 
     # --- exchange economics: host HTTP shuffle vs in-trace all_to_all --
     # (exchange_sweep above; `--calibrate` fits it into the fusion-cost
@@ -1004,6 +1065,8 @@ if __name__ == "__main__":
     elif "--fleet" in sys.argv:
         args = [a for a in sys.argv[1:] if not a.startswith("--")]
         fleet_sweep(int(args[0]) if args else 4)
+    elif "dynfilter" in sys.argv:
+        dynfilter_anchor()
     elif "--sketch" in sys.argv:
         args = [a for a in sys.argv[1:] if not a.startswith("--")]
         sketch_anchor(tuple(int(a) for a in args) or (20, 22, 23))
