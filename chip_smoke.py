@@ -34,7 +34,9 @@ SERVED = (1, 6, 3, 18)      # TPC-H queries of the serve phase
 MESH = (1, 3, 18)           # ... of the --mesh phase
 ORDER_SLICE = 500_000       # orders per slice of the streamed reference
 REL = 1e-4                  # float sums under float32_compute
-MESH_REL = 1e-6             # --mesh runs DOUBLE as f64 (emulated on a TPU)
+MESH_REL = 1e-6             # --mesh sets no float32_compute: this script's
+                            # choice, so DOUBLE is f64 there (emulated on a
+                            # TPU); with the property the mesh computes in f32
 
 POINT_SQL = ("SELECT count(*) c, sum(l_extendedprice) s "
              "FROM lineitem WHERE l_orderkey = ?")

@@ -253,7 +253,7 @@ class TpchTable(ConnectorTable):
         caller falls back to read().  See connectors/tpch_device.py."""
         from presto_tpu.connectors import tpch_device as D
 
-        if not all(D.is_device_generable(self.name, c) for c in columns):
+        if not all(self.device_generable(c) for c in columns):
             return None
         from presto_tpu.exec import compile_cache as CC
 
@@ -272,6 +272,23 @@ class TpchTable(ConnectorTable):
             # cold cost and belongs in its compile-economics counters
             fn = cache[key] = CC.build_jit(gen, example=())
         return fn()
+
+    def device_generable(self, column: str) -> bool:
+        from presto_tpu.connectors import tpch_device as D
+
+        return D.is_device_generable(self.name, column)
+
+    def shard_grid(self, ndev: int):
+        """`ndev` contiguous primary-key ranges of this table, one a
+        mesh shard, with the in-trace generator for a range (`build_scan`;
+        connectors/tpch_device.shard_grid).  The layout is the table's,
+        whatever the columns: host-read columns are laid out by it too."""
+        grids = self.__dict__.setdefault("_shard_grids", {})
+        if ndev not in grids:
+            from presto_tpu.connectors import tpch_device as D
+
+            grids[ndev] = D.shard_grid(self.name, self.sf, ndev)
+        return grids[ndev]
 
     def _full_table(self):
         # per-table lock: streaming cluster tasks run concurrently and

@@ -573,6 +573,10 @@ class TpchChunkGrid:
     def capacity(self, table: str) -> int:
         return self.cap_lines if table == "lineitem" else self.cap_orders
 
+    def row_edges(self, table: str):
+        """The table's own row range of every chunk, as edges."""
+        return self.line_offsets if table == "lineitem" else self.order_edges
+
     def exchange_bound(self) -> int:
         """Default per-chunk compact bound for exchange outputs (chunk
         outputs are reductions of the chunk — aggregates on the bucket
@@ -624,13 +628,31 @@ class TpchChunkGrid:
                 pad=self.cap_lines, n_orders=self.cap_orders,
                 line_row0=line0)
             sel = jnp.arange(self.cap_lines) < n_line
-        elif table == "orders":
-            raw = generate_device("orders", self.sf, cols, row0=o0,
+        else:   # orders, or a table that shard_grid cut by its own rows
+            raw = generate_device(table, self.sf, cols, row0=o0,
                                   f32=f32, pad=self.cap_orders)
             sel = jnp.arange(self.cap_orders) < n_ord
-        else:
-            raise KeyError(f"{table} is not in the tpch chunk family")
         return raw, sel
+
+
+def shard_grid(table: str, sf: float, ndev: int) -> TpchChunkGrid:
+    """The chunk grid cut for a mesh: `ndev` contiguous primary-key
+    ranges of `table`, one a shard (parallel/dist_executor.sharded_scan
+    generates each on the chip that holds it).  lineitem and orders are
+    cut by the same order rows, so an order's lines lie on the shard
+    that holds the order; any other table by its own rows, which then
+    stand where the grid says orders.  Trailing ranges may be empty."""
+    by_orders = table in TpchChunkFamily.BUCKET_COLUMNS
+    total = int(H._TABLE_ROWS["orders"] * sf) if by_orders \
+        else H.row_count(table, sf)
+    per = max(-(-total // ndev), 1)
+    edges = [min(i * per, total) for i in range(ndev + 1)]
+    lines = edges
+    if table == "lineitem":
+        before = np.concatenate([[0], np.cumsum(H._lines_per_order(
+            np.arange(total, dtype=np.int64)))])
+        lines = [int(before[e]) for e in edges]
+    return TpchChunkGrid(sf, edges, lines)
 
 
 class TpchChunkFamily:

@@ -333,7 +333,7 @@ def _dispatch_statement(session, text: str, stmt, mon) -> QueryResult:
         try:
             with mon.phase("execute"):
                 mon.stats.execution_mode = "distributed"
-                return run_distributed(session, text, stmt)
+                return run_distributed(session, text, stmt, mon=mon)
         except (Undistributable, StaticFallback,
                 jax.errors.ConcretizationTypeError,
                 jax.errors.TracerArrayConversionError) as e:

@@ -65,12 +65,17 @@ SPANS: Dict[str, tuple] = {
     "plan": ("planner", "QueryMonitor.phase"),
     "execute": ("executor", "QueryMonitor.phase"),
     "exec.dispatch": ("executor",
-                      "run_compiled[_batched]: scan batches, parameter stacking, "
-                      "the jitted call until it returns"),
+                      "run_compiled[_batched], run_distributed: scan batches, parameter "
+                      "stacking, the jitted call until it returns"),
     "exec.wait_fetch": ("executor",
-                        "run_compiled[_batched]: jax.device_get of the packed result"),
+                        "run_compiled[_batched]: jax.device_get of the packed result; "
+                        "run_distributed: the fetch of the mesh program's guard"),
     "exec.materialize": ("executor",
-                         "run_compiled[_batched]: unpack_fetch and materialize_host"),
+                         "run_compiled[_batched]: unpack_fetch and materialize_host; "
+                         "run_distributed: the result's fetch and its rows"),
+    "mesh.feed": ("mesh",
+                  "run_distributed: sharded_scan of every scan, cached shards when warm, "
+                  "generated on their chips or put there when cold"),
     "xla_compile": ("executor", "compile_cache.Executable.aot_compile: lower + compile"),
 }
 

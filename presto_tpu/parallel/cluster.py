@@ -1091,7 +1091,7 @@ class _ClusterExecutor:
             data = np.asarray(data)[live]
             if c.dictionary is not None:
                 data = c.dictionary.values[
-                    np.clip(data, 0, max(len(c.dictionary.values) - 1, 0))]
+                    np.clip(data, 0, max(len(c.dictionary) - 1, 0))]
             valid = None if valid is None else np.asarray(valid)[live]
             cols[sym] = (data, valid)
         return cols
@@ -1213,7 +1213,7 @@ class _ClusterExecutor:
             data = host(c.data)[0][live]
             if c.dictionary is not None:
                 data = c.dictionary.values[
-                    np.clip(data, 0, max(len(c.dictionary.values) - 1, 0))]
+                    np.clip(data, 0, max(len(c.dictionary) - 1, 0))]
             valid = None if c.valid is None else host(c.valid)[0][live]
             if sel_repl and rank != 0:
                 data = data[:0]

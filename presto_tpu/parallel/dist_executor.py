@@ -17,7 +17,7 @@ guard re-runs the query on the single-device dynamic path.
 
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import List
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 from presto_tpu.batch import Batch, Column
 from presto_tpu.exec import compile_cache as CC
 from presto_tpu.exec.executor import Executor
+from presto_tpu.observe import trace as TR
 from presto_tpu.parallel import exchange as EX
 from presto_tpu.parallel import mesh as MH
 from presto_tpu.parallel.mesh import AXIS, make_mesh
@@ -96,6 +97,16 @@ class DistExecutor(Executor):
             return bool(srcs) and all(complete(s) for s in srcs)
 
         return complete(node.right)
+
+    def _build_presorted(self, node, right: Batch, rkeys) -> bool:
+        """The planner made its claim (a build sorted on the key, masked
+        rows in a suffix) on the single-node plan.  An exchange under the
+        build lays the shards' buffers end to end, each with its dead
+        rows after its live ones, so the claim ends there
+        (plan/properties.derive: an Exchange keeps no order); a build
+        that stays on its shard keeps it."""
+        return not isinstance(node.right, P.Exchange) \
+            and super()._build_presorted(node, right, rkeys)
 
     def _rf_mask_pays(self) -> bool:
         # kept as on the parent until sf1_mesh4_join can price it, not
@@ -181,9 +192,19 @@ def _shard_mapped(fn, mesh, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 
 
-def run_distributed(session, text: str, stmt):
+def run_distributed(session, text: str, stmt, mon=None):
     """Plan, distribute, and execute a query over the mesh; results are
-    gathered/replicated, so materialization reads shard 0's copy."""
+    gathered/replicated, so materialization reads shard 0's copy.
+
+    Scans are fed by `sharded_scan` (span `mesh.feed`): born on their
+    chips where the table generates on the device, in f32 under
+    `float32_compute`.  The launch, the wait for the guard and the
+    result's fetch lie under `exec.dispatch`, `exec.wait_fetch` and
+    `exec.materialize`, as in run_compiled; `mon` (the query's
+    QueryMonitor) gets the program's trace-time counters — exchange
+    bytes, declined runtime filters — with every run, cold or warm.
+    Compiles reach it through compile_cache.recording, like any
+    program's."""
     from presto_tpu.exec import executor as X
 
     ndev = int(session.properties.get("mesh_devices", 0)) or len(jax.devices())
@@ -201,21 +222,40 @@ def run_distributed(session, text: str, stmt):
 
     if entry is None:
         try:
-            return _build_and_run(session, stmt, cache, key, ndev)
+            entry = _build(session, stmt, ndev)
         except Exception as e:
             # memoize undistributable/untraceable shapes so re-executions
             # skip the failed plan+distribute+trace (the runtime-guard path
             # below already memoizes via "DYNAMIC")
-            from presto_tpu.exec.executor import StaticFallback
-
-            if isinstance(e, (Undistributable, StaticFallback,
+            if isinstance(e, (Undistributable, X.StaticFallback,
                               jax.errors.ConcretizationTypeError)):
                 cache[key] = "DYNAMIC"
             raise
-    return _run_entry(session, cache, key, entry, ndev)
+        cache[key] = entry  # built (traced, compiled) without a failure
+    dplan, jitted, scan_nodes, mesh, counters = entry
+    with TR.span("exec.dispatch"):
+        out_batch, guard = jitted(_feed(session, scan_nodes, mesh, ndev))
+    if mon is not None:
+        X._merge_sort_stats(mon.stats, counters)
+    with TR.span("exec.wait_fetch"):
+        tripped = bool(guard)  # the program's end: every shard's guards
+    if tripped:
+        cache[key] = "DYNAMIC"
+        raise Undistributable("static assumption violated at runtime")
+    with TR.span("exec.materialize"):  # its own fetches are inside
+        return X.Executor(session).materialize(dplan, out_batch)
 
 
-def _build_and_run(session, stmt, cache, key, ndev):
+def _feed(session, scan_nodes, mesh, ndev):
+    f32 = bool(session.properties.get("float32_compute", False))
+    with TR.span("mesh.feed"):
+        return [sharded_scan(session.catalog.get(n.table), n, mesh, ndev,
+                             f32) for n in scan_nodes]
+
+
+def _build(session, stmt, ndev):
+    """-> (distributed plan, the whole-mesh program, its scans, the mesh,
+    the program's trace-time counters)."""
     from presto_tpu.exec import executor as X
 
     mesh = make_mesh(ndev)
@@ -229,10 +269,13 @@ def _build_and_run(session, stmt, cache, key, ndev):
     X._collect_tablescans(dplan.root, scan_nodes)
     for sub in sorted(dplan.subplans):
         X._collect_tablescans(dplan.subplans[sub], scan_nodes)
+    counters: dict = {}
 
     def fn(batches):
+        stats: dict = {}
         ex = DistExecutor(session, ndev,
-                          {id(n): b for n, b in zip(scan_nodes, batches)})
+                          {id(n): b for n, b in zip(scan_nodes, batches)},
+                          sort_stats=stats)
         # scalar subqueries evaluated inside the same trace so float
         # reduction order matches the main plan bit-for-bit
         for pid in sorted(dplan.subplans):
@@ -245,85 +288,134 @@ def _build_and_run(session, stmt, cache, key, ndev):
             g = jnp.zeros((), bool)
         # any shard's violation aborts the whole query
         g = jax.lax.psum(g.astype(jnp.int32), AXIS) > 0
+        # trace-time counters: filled anew by every (re)trace, replayed
+        # into the query's stats by every run
+        counters.clear()
+        counters.update(stats)
         return out, g
 
     sharded = _shard_mapped(fn, mesh, (PS(AXIS),), PS())
-    # counted build (exec/compile_cache.py): the whole-mesh program's
-    # compile lands in this query's compile-economics counters; the
-    # live jit (no AOT pin) keeps input resharding automatic
-    jitted = CC.build_jit(sharded)
-    entry = (dplan, jitted, scan_nodes, mesh)
-    # trace/compile before caching so failures propagate to the caller
-    out_batch, guard = jitted(
-        [sharded_scan(session.catalog.get(n.table), n, mesh, ndev)
-         for n in scan_nodes])
-    cache[key] = entry
-    return _finish(session, cache, key, dplan, out_batch, guard)
+    # counted AOT build (exec/compile_cache.py): the whole-mesh program's
+    # compile lands in this query's compile-economics counters, and its
+    # HLO text is what lets a profile name its device time by the
+    # engine's scopes (scope_tables); every scan is fed on the mesh under
+    # one sharding, so the pinned input shardings are the feed's own.
+    # The tag tells this query's module from another's in a profile.
+    plan_fp = CC.plan_fingerprint(
+        (dplan.root, sorted(dplan.subplans.items())))
+    jitted = CC.build_jit(sharded,
+                          example=(_feed(session, scan_nodes, mesh, ndev),),
+                          tag=X._program_tag(plan_fp))
+    return dplan, jitted, scan_nodes, mesh, counters
 
 
-def _run_entry(session, cache, key, entry, ndev):
-    from presto_tpu.exec import executor as X  # noqa: F401
-
-    dplan, jitted, scan_nodes, mesh = entry
-    batches = [sharded_scan(session.catalog.get(n.table), n, mesh, ndev)
-               for n in scan_nodes]
-    out_batch, guard = jitted(batches)
-    return _finish(session, cache, key, dplan, out_batch, guard)
-
-
-def _finish(session, cache, key, dplan, out_batch, guard):
-    from presto_tpu.exec import executor as X
-
-    if bool(guard):
-        cache[key] = "DYNAMIC"
-        raise Undistributable("static assumption violated at runtime")
-    ex = X.Executor(session)
-    return ex.materialize(dplan, out_batch)
+def _shard_rows(table, ndev: int):
+    """-> (row edges [ndev+1], rows of the fullest shard): shard i holds
+    the table's rows [edges[i], edges[i+1]), a contiguous primary-key
+    range.  A table that generates on the device says where it cuts
+    (`shard_grid`: order-row ranges for lineitem); any other is cut
+    evenly."""
+    if hasattr(table, "shard_grid"):
+        grid = table.shard_grid(ndev)
+        return grid.row_edges(table.name), grid.capacity(table.name)
+    n = table.row_count()
+    per = max(-(-n // ndev), 1)
+    return [min(i * per, n) for i in range(ndev + 1)], per
 
 
-def sharded_scan(table, node: P.TableScan, mesh, ndev: int) -> Batch:
-    """Host columns -> row-sharded device arrays over the mesh (P3 source
-    distribution: the split-assignment role of SourcePartitionedScheduler,
-    done by sharding annotation instead of split queues).  Rows are padded
-    to a multiple of ndev with dead (sel=False) rows."""
-    cache_attr = f"_dist_cols_{ndev}"
-    cache: Dict[str, Column] = getattr(table, cache_attr, None)
-    if cache is None:
-        cache = {}
-        setattr(table, cache_attr, cache)
+def shard_generator(table, cols, mesh, ndev: int, f32: bool):
+    """-> (fn, host args): `fn(*args)` is ONE shard_map program in which
+    every chip generates its own range of `cols` — ({col: Column}, sel),
+    row-sharded, a shard's live rows first and dead rows under `sel`
+    after them up to the static per-shard length.  The generator is the
+    one-chip one in its static-shape form (the chunk grid's
+    `build_scan`), the ranges arrive as traced per-shard scalars."""
+    grid = table.shard_grid(ndev)
+    orders, lines = np.asarray(grid.order_edges), np.asarray(grid.line_offsets)
+    args = (orders[:-1].astype(np.int64), lines[:-1].astype(np.int64),
+            np.diff(orders).astype(np.int32), np.diff(lines).astype(np.int32))
+
+    def shard(o0, line0, n_ord, n_line):
+        return grid.build_scan(table.name, cols,
+                               (o0[0], line0[0], n_ord[0], n_line[0]), f32)
+
+    return _shard_mapped(shard, mesh, (PS(AXIS),) * 4, PS(AXIS)), args
+
+
+def sharded_scan(table, node: P.TableScan, mesh, ndev: int,
+                 f32: bool = False) -> Batch:
+    """A scan's columns as row-sharded device arrays over the mesh (P3
+    source distribution: the split-assignment role of
+    SourcePartitionedScheduler, done by sharding annotation instead of
+    split queues).  A shard is a contiguous primary-key range
+    (`_shard_rows`), padded to one static length with dead (sel=False)
+    rows.
+
+    Columns the table can generate on the device (`device_generable`)
+    are born on the chip that holds them (`shard_generator`): no host
+    copy exists.  The rest are read on the host, laid out by the same
+    ranges and put on the mesh.  f32=True (the `float32_compute` session
+    property) stores DOUBLE columns as float32, as scan_batch does on
+    one chip.  Every column is cached on the table (`_dist_cols_<ndev>`,
+    DOUBLE under f32 in `_dist_cols_<ndev>_f32`), so a warm query
+    touches no generator and no host array."""
+    base = vars(table).setdefault(f"_dist_cols_{ndev}", {})
+    f32cache = vars(table).setdefault(f"_dist_cols_{ndev}_f32", {}) \
+        if f32 else None
+
+    def cache_for(colname):
+        # virtual pushdown predicate columns are schema-less BOOLEANs
+        t = table.schema.get(colname)
+        return f32cache if f32 and t is not None and t.name == "DOUBLE" \
+            else base
+
     spec = NamedSharding(mesh, PS(AXIS))
     needed = list(dict.fromkeys(node.assignments.values()))
-    missing = [c for c in needed if c not in cache]
-    n_rows = table.row_count()
-    npad = max(int(np.ceil(n_rows / ndev)) * ndev, ndev)
-    if missing:
-        from presto_tpu.batch import column_from_numpy
-
-        data = table.read(missing)
-        for c in missing:
-            from presto_tpu import types as T
-
-            # virtual pushdown predicate columns are schema-less BOOLEANs
-            col = column_from_numpy(data[c], table.schema.get(c, T.BOOLEAN))
-            arr = np.asarray(col.data)
-            pad = np.zeros((npad - n_rows,), dtype=arr.dtype)
-            arr = np.concatenate([arr, pad])
-            valid = col.valid
-            if valid is not None:
-                valid = np.concatenate([np.asarray(valid),
-                                        np.zeros((npad - n_rows,), bool)])
-                valid = _put(valid, spec)
-            cache[c] = Column(_put(arr, spec), valid, col.type,
-                              col.dictionary)
+    missing = [c for c in needed if c not in cache_for(c)]
+    born = [c for c in missing if hasattr(table, "device_generable")
+            and table.device_generable(c)]
     sel_key = "__sel__"
-    if sel_key not in cache:
-        sel = np.arange(npad) < n_rows
-        cache[sel_key] = _put(sel, spec)
+    if born:
+        fn, args = shard_generator(table, born, mesh, ndev, f32)
+        args = tuple(_put(a, spec) for a in args)
+        # AOT, counted: the generator's compile is part of a query's cold
+        # cost, as TpchTable.device_columns' is on one chip
+        cols, sel = CC.build_jit(fn, example=args)(*args)
+        for c in born:
+            cache_for(c)[c] = cols[c]
+        base.setdefault(sel_key, sel)
+    read = [c for c in missing if c not in born]
+    if read or sel_key not in base:
+        edges, per = _shard_rows(table, ndev)
+
+        def laid_out(arr):
+            out = np.zeros((ndev * per,) + arr.shape[1:], dtype=arr.dtype)
+            for i, (a, b) in enumerate(zip(edges[:-1], edges[1:])):
+                out[i * per:i * per + b - a] = arr[a:b]
+            return _put(out, spec)
+
+        if read:
+            from presto_tpu import types as T
+            from presto_tpu.batch import column_from_numpy
+
+            data = table.read(read)
+            for c in read:
+                t = table.schema.get(c, T.BOOLEAN)
+                col = column_from_numpy(data[c], t)
+                arr = np.asarray(col.data)
+                if f32 and t.name == "DOUBLE":
+                    arr = arr.astype(np.float32)
+                valid = None if col.valid is None \
+                    else laid_out(np.asarray(col.valid))
+                cache_for(c)[c] = Column(laid_out(arr), valid, col.type,
+                                         col.dictionary)
+        if sel_key not in base:
+            base[sel_key] = laid_out(np.ones((edges[-1],), bool))
     cols = {}
     for sym, colname in node.assignments.items():
-        c = cache[colname]
+        c = cache_for(colname)[colname]
         cols[sym] = Column(c.data, c.valid, node.types[sym], c.dictionary)
-    return Batch(cols, cache[sel_key])
+    return Batch(cols, base[sel_key])
 
 
 # ---------------------------------------------------------------------------

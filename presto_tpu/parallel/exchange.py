@@ -78,12 +78,18 @@ def _dict_value_hashes(dictionary) -> np.ndarray:
     cached = getattr(dictionary, "_value_hashes", None)
     if cached is not None:
         return cached
-    out = np.empty(len(dictionary), dtype=np.uint64)
-    for i, v in enumerate(dictionary.values):
-        hv = 0xCBF29CE484222325
-        for byte in str(v).encode("utf-8"):
-            hv = ((hv ^ byte) * 0x100000001B3) & 0xFFFFFFFFFFFFFFFF
-        out[i] = hv
+    # by code, not by iteration: a functional dictionary (the device
+    # generator's numbered names) renders values from codes and has no end
+    values = np.asarray(dictionary.values[np.arange(len(dictionary))])
+    raw = np.char.encode(values.astype(str), "utf-8")
+    width = max(raw.dtype.itemsize, 1)
+    octets = np.frombuffer(raw.astype(f"S{width}").tobytes(), np.uint8) \
+        .reshape(len(raw), width).astype(np.uint64)
+    lengths = np.char.str_len(raw)
+    out = np.full(len(raw), 0xCBF29CE484222325, dtype=np.uint64)
+    for j in range(width):  # one pass a byte position, all values at once
+        out = np.where(j < lengths,
+                       (out ^ octets[:, j]) * np.uint64(0x100000001B3), out)
     dictionary._value_hashes = out
     return out
 

@@ -25,7 +25,7 @@ N_ROWS = 6_000_000  # one SF1 lineitem
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -40,9 +40,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
 
 
 @pytest.fixture
@@ -167,3 +172,49 @@ def test_scoped_kernels_lower_and_keep_their_names(one_chip, as_tpu, kernel):
     text = _compile(fn, one_chip, *shapes).as_text()
     for scope in scopes:
         assert scope in text, scope
+
+
+#: table, scale, columns: what the cell sf1_mesh4_join scans, at SF1 — but
+#: lineitem's columns that repeat an order's value over its lines at
+#: SF0.01: `jnp.repeat` over a shard's 1.5 M lines takes the v5e's compiler
+#: ~110 s (5 s at SF0.01; on the chip it is part of a cold set-up)
+SHARDED_SCANS = {
+    "lineitem_sf1": ("lineitem", 1.0, ["l_quantity", "l_extendedprice",
+                                       "l_discount", "l_tax"]),
+    "lineitem_repeats": ("lineitem", 0.01, [
+        "l_orderkey", "l_quantity", "l_extendedprice", "l_discount", "l_tax",
+        "l_returnflag", "l_linestatus", "l_shipdate"]),
+    "orders_sf1": ("orders", 1.0, ["o_orderkey", "o_custkey", "o_orderdate",
+                                   "o_shippriority"]),
+    "customer_sf1": ("customer", 1.0, ["c_custkey", "c_mktsegment"]),
+}
+
+
+@pytest.mark.parametrize("scan", sorted(SHARDED_SCANS))
+def test_sharded_generation_program_compiles_for_four_chips(topology, scan):
+    """The program in which every chip of the mesh generates its own
+    range of a table (parallel/dist_executor.shard_generator): one
+    shard_map over the described 2x2, the ranges as traced per-shard
+    scalars, DOUBLE born f32, and no collective in it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from presto_tpu.catalog import TpchTable
+    from presto_tpu.parallel import dist_executor as DX
+    from presto_tpu.parallel.mesh import AXIS
+
+    name, sf, cols = SHARDED_SCANS[scan]
+    mesh = Mesh(np.asarray(topology.devices[:4]), (AXIS,))
+    spec = NamedSharding(mesh, PartitionSpec(AXIS))
+    table = TpchTable(name, sf)
+    fn, args = DX.shard_generator(table, cols, mesh, 4, True)
+    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=spec)
+              for a in args]
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "all-to-all" not in text and "all-gather" not in text
+    out, sel = jax.eval_shape(fn, *shapes)
+    assert sel.shape == (4 * table.shard_grid(4).capacity(name),)
+    for c in cols:
+        want = jnp.float32 if table.schema[c].name == "DOUBLE" \
+            else out[c].data.dtype
+        assert out[c].data.dtype == want and out[c].data.shape == sel.shape
