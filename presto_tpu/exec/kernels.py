@@ -1406,15 +1406,42 @@ def _sort_operand_native(col: Column) -> jnp.ndarray:
 @NM.scoped("k:sort")
 def argsort_stable(key: jnp.ndarray) -> jnp.ndarray:
     """Stable argsort (equal keys keep input order) — routed entry point
-    for the exchange layer's destination-bucket ordering."""
+    for the write path's page order (write_sort_perm)."""
     return jnp.argsort(key, stable=True)
 
 
-@NM.scoped("k:sort")
-def lexsort_pair(minor: jnp.ndarray, major: jnp.ndarray) -> jnp.ndarray:
-    """Permutation sorting by (major, then minor) — routed entry point
-    (jnp.lexsort order convention: last key is primary)."""
-    return jnp.lexsort((minor, major))
+#: 1-D operands one carrying sort takes (a 64-bit column is two on the
+#: TPU); a wider batch sorts in groups under the same keys.  The v5e
+#: compiler's time grows faster than the width: 151 s with 8 operands,
+#: 345 s with 16, over 3400 s with 40 (PERF.md section 7, PR 30)
+SORT_CARRY_WIDTH = 16
+
+
+def sort_carrying(keys, payloads):
+    """Stable sort by `keys` (keys[0] primary; equal keys keep input
+    order) that carries `payloads` along as operands: payload p, of any
+    trailing shape, comes back as `p[order]` without a full-size gather
+    per array — routed entry point for the exchange layer's send layout.
+    More than SORT_CARRY_WIDTH operands sort in groups under the same
+    keys (a stable sort by the same keys is the same permutation every
+    time).  Returns (sorted keys, sorted payloads).  Like `unpermute` it
+    opens no scope: its time is the caller's (`x:repartition`,
+    `x:range_partition`)."""
+    keys, nk = tuple(keys), len(keys)
+    # 1-D sort operands: a long decimal's limbs ride one each
+    flat = [p.reshape(p.shape[0], -1) for p in payloads]
+    operands = [f[:, j] for f in flat for j in range(f.shape[1])]
+    skeys, carried = None, []
+    for i in range(0, max(len(operands), 1), SORT_CARRY_WIDTH):
+        res = jax.lax.sort(keys + tuple(operands[i:i + SORT_CARRY_WIDTH]),
+                           num_keys=nk, is_stable=True)
+        skeys = res[:nk]
+        carried.extend(res[nk:])
+    carried = iter(carried)
+    return skeys, [
+        jnp.stack([next(carried) for _ in range(f.shape[1])],
+                  axis=1).reshape(p.shape)
+        for p, f in zip(payloads, flat)]
 
 
 @NM.scoped("k:sort")

@@ -87,7 +87,7 @@ DYNAMIC_SPANS: Dict[str, tuple] = {
 
 #: device scope -> what runs under it.  `k:` kernels, `x:` exchanges.
 KERNEL_SCOPES: Dict[str, str] = {
-    "k:sort": "kernels.sort_pair / sort_perm / sort_values / sort_order_plan / argsort_stable / lexsort_pair",
+    "k:sort": "kernels.sort_pair / sort_perm / sort_values / sort_order_plan / argsort_stable",
     "k:build_probe": "kernels.build_probe: sorted build side, searchsorted probe",
     "k:take_rows.staged": "kernels.take_rows, staged route: sorted indices through exec/gather, one co-sort home",
     "k:take_rows.flat": "kernels.take_rows, flat route: XLA's gather over the packed words (or a column)",
@@ -99,9 +99,9 @@ KERNEL_SCOPES: Dict[str, str] = {
     "k:fused_group_sums.operand": "the stack and pad that build fused_group_sums' operand",
     "k:compact": "executor._compact_batch and kernels.compact: live rows to the front",
     "k:scan_filter": "executor._exec_filter: the predicate over a scan's rows, into the selection mask",
-    "x:repartition": "parallel/exchange.repartition_batch: all_to_all by key hash",
+    "x:repartition": "parallel/exchange.repartition_batch: one sort by destination (key hash) that carries the columns, the send buffer as contiguous slices, all_to_all",
     "x:all_gather": "parallel/exchange.all_gather_batch: a shard's rows on every shard",
-    "x:range_partition": "parallel/exchange.range_partition_batch: sample sort's split and all_to_all",
+    "x:range_partition": "parallel/exchange.range_partition_batch: sample sort's split, the same send layout by (destination, key), all_to_all",
 }
 
 

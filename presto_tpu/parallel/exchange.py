@@ -4,9 +4,13 @@ Reference parity: the HTTP shuffle (SURVEY.md §2.6 — PartitionedOutputOperato
 -> PagesSerde -> OutputBuffer -> HttpPageBufferClient -> ExchangeClient)
 re-based on XLA collectives over the ICI mesh.  Where the reference
 serializes pages and pulls them over HTTP with ack tokens, here a whole
-repartition is ONE `lax.all_to_all` inside the jitted superstep: rows are
-bucketed by key hash into a fixed (ndev, C) send layout, exchanged, and
-received as a fixed (ndev*C,) batch with a validity mask.  Backpressure,
+repartition is ONE `lax.all_to_all` (per array) inside the jitted
+superstep: one stable sort by destination (the key's hash) carries every
+column along, so each destination's rows lie contiguous; ndev slices of
+length C out of the sorted arrays are the fixed (ndev, C) send layout,
+exchanged and received as a fixed (ndev*C,) batch with a validity mask —
+no gather and no scatter per column (random access costs 25-28 ns a row
+on the v5e, a carried sort operand next to nothing).  Backpressure,
 framing, compression, and retry disappear — XLA schedules the transfer and
 overlap; capacity overflow is a traced guard that falls back to dynamic
 execution (the analog of the reference's spill-on-buffer-full, but chosen
@@ -100,49 +104,58 @@ def _exchange_by_dest(b: Batch, dest: jnp.ndarray, ndev: int, axis: str,
     """Shared all_to_all machinery: move every live row to shard
     `dest[row]` (dest in [0, ndev); dead rows may carry any value).
 
-    Static send layout: per-destination capacity C = ceil(slack * n/ndev);
-    rows are stably sorted by (dest, order_key) — order_key preserves a
-    within-destination order for the range exchange — positioned within
-    their bucket, and scattered into a (ndev*C,) send buffer.  Bucket
-    overflow (skew beyond `slack`) sets the returned guard — the caller
-    falls back, the distributed analog of the reference's skew pathology
-    (SURVEY.md §7 hard-part 5).
+    Static send layout: per-destination capacity C = ceil(slack * n/ndev).
+    ONE stable sort keyed on (dest, order_key) — order_key gives the range
+    exchange its within-destination order; without it rows keep their input
+    order — carries every column's data and validity array as payload
+    (kernels.sort_carrying), so the rows of destination d lie contiguous
+    at first[d] .. first[d+1].  The (ndev*C,) send buffer is ndev
+    contiguous slices of length C out of each sorted array, with the slots
+    past a bucket's count zeroed: no gather and no scatter per column.
+    Bucket overflow (count[d] > C: skew beyond `slack`) sets the returned
+    guard and the rows beyond C are not sent — the caller falls back, the
+    distributed analog of the reference's skew pathology (SURVEY.md §7
+    hard-part 5).
 
     Returns (received batch with capacity ndev*C, overflow guard)."""
     n = b.capacity
     c_cap = max(int(np.ceil(slack * n / ndev)), 1)
     dest = jnp.where(b.sel, dest, ndev)  # dead rows sort last
-    if order_key is None:
-        order = K.argsort_stable(dest)
-    else:
-        order = K.lexsort_pair(order_key, dest)
-    sdest = dest[order]
-    # position of each row within its destination bucket
+    arrays = [x for c in b.columns.values() for x in (c.data, c.valid)
+              if x is not None]
+    keys = (dest,) if order_key is None else (dest, order_key)
+    (sdest, *_), carried = K.sort_carrying(keys, arrays)
     first = jnp.searchsorted(sdest, jnp.arange(ndev + 1, dtype=sdest.dtype))
-    within = jnp.arange(n) - first[jnp.clip(sdest, 0, ndev)]
-    live = sdest < ndev
-    ok = live & (within < c_cap)
-    overflow = jnp.any(live & (within >= c_cap))
-    # send slot; dropped rows (overflow/dead) go to scratch slot ndev*c_cap
-    slot = jnp.where(ok, sdest * c_cap + within, ndev * c_cap)
+    count = first[1:] - first[:-1]  # live rows per destination
+    overflow = jnp.any(count > c_cap)
+    # a slot is sent iff its sender has a row for it, and a received slot
+    # is live iff it was sent
+    sent = (jnp.arange(c_cap)[None, :]
+            < jnp.minimum(count, c_cap)[:, None]).reshape(-1)
 
-    def exchange(x, fill=0):
-        buf = jnp.full((ndev * c_cap + 1,) + x.shape[1:], fill, dtype=x.dtype)
-        buf = buf.at[slot].set(x[order])
-        send = buf[: ndev * c_cap]
+    def all_to_all(send):
         return jax.lax.all_to_all(send, axis, split_axis=0, concat_axis=0,
                                   tiled=True)
 
-    # a received slot is live iff the sender placed a live row in it
-    sent_live = jnp.zeros((ndev * c_cap + 1,), dtype=bool).at[slot].set(ok)
-    sel_out = jax.lax.all_to_all(sent_live[: ndev * c_cap], axis,
-                                 split_axis=0, concat_axis=0, tiled=True)
+    def exchange(x):
+        # padded by C so that no slice runs off the end (dynamic_slice
+        # would move its start); past its bucket's count a slice holds the
+        # next buckets' rows: zeroed, as a slot nothing was sent in reads
+        trail = x.shape[1:]
+        x = jnp.concatenate([x, jnp.zeros((c_cap,) + trail, x.dtype)])
+        send = jnp.concatenate([
+            jax.lax.dynamic_slice_in_dim(x, first[d], c_cap)
+            for d in range(ndev)])
+        keep = sent.reshape((-1,) + (1,) * len(trail))
+        return all_to_all(jnp.where(keep, send, jnp.zeros((), x.dtype)))
+
+    carried = iter(carried)
     cols = {}
     for name, c in b.columns.items():
-        data = exchange(c.data)
-        valid = None if c.valid is None else exchange(c.valid)
+        data = exchange(next(carried))
+        valid = None if c.valid is None else exchange(next(carried))
         cols[name] = Column(data, valid, c.type, c.dictionary)
-    return Batch(cols, sel_out), overflow
+    return Batch(cols, all_to_all(sent)), overflow
 
 
 @NM.scoped("x:repartition")
