@@ -66,6 +66,12 @@ class _QueryJob:
     stats: Optional[dict] = None
     done: threading.Event = dataclasses.field(default_factory=threading.Event)
     cancel: threading.Event = dataclasses.field(default_factory=threading.Event)
+    finished_at: float = 0.0  # time.monotonic() at finish(): _prune_done's age
+    delivered: bool = False  # last page or terminal error served at least once
+
+    def finish(self) -> None:
+        self.finished_at = time.monotonic()
+        self.done.set()
 
 
 class PrestoTpuServer:
@@ -190,14 +196,14 @@ class PrestoTpuServer:
                 job.error = str(e)
                 job.error_code = e.code
                 job.state = "FAILED"
-            job.done.set()
+            job.finish()
             with self.jobs_lock:
                 self.active_queries -= 1
             return
         except Exception as e:  # noqa: BLE001 — rejection is a query error
             job.error = f"{type(e).__name__}: {e}"
             job.state = "FAILED"
-            job.done.set()
+            job.finish()
             with self.jobs_lock:
                 self.active_queries -= 1
             return
@@ -300,7 +306,7 @@ class PrestoTpuServer:
                     # alive to observe the outcome => clean up; only a
                     # coordinator that DIED leaves entries for adoption
                     self._journal.remove(job.query_id)
-                job.done.set()
+                job.finish()
                 with self.jobs_lock:
                     self.active_queries -= 1
 
@@ -500,7 +506,7 @@ class PrestoTpuServer:
             job.state = "FAILED"
         finally:
             self._journal.remove(job.query_id)
-            job.done.set()
+            job.finish()
             with self.jobs_lock:
                 self.active_queries -= 1
 
@@ -550,12 +556,14 @@ class PrestoTpuServer:
             out["error"] = {"message": job.error,
                             "errorCode": job.error_code or "QUERY_FAILED"}
             out["stats"] = {"state": "FAILED"}
+            job.delivered = True
             return out
         if job.state == "CANCELED":
             out["stats"] = {"state": "CANCELED"}
             if job.error:  # drained by graceful shutdown: say why
                 out["error"] = {"message": job.error,
                                 "errorCode": job.error_code or "USER_CANCELED"}
+            job.delivered = True
             return out
         start = token * PAGE_ROWS
         page = job.rows[start:start + PAGE_ROWS]
@@ -566,19 +574,35 @@ class PrestoTpuServer:
         if start + PAGE_ROWS < len(job.rows):
             out["nextUri"] = f"{base}/{token + 1}"
         else:
+            job.delivered = True
             self._prune_done()
         return out
 
     MAX_DONE_JOBS = 64
+    CLIENT_TIMEOUT_S = 300.0  # the reference's query.client.timeout
 
     def _prune_done(self) -> None:
-        """Bound retained results: keep the newest MAX_DONE_JOBS finished
-        jobs so recent pages stay refetchable (at-least-once) while the
-        server never accumulates every result ever produced (reference:
-        QueryTracker expiry, execution/QueryTracker.java)."""
+        """The one retention rule for finished jobs (reference:
+        QueryTracker, execution/QueryTracker.java).  DELIVERED ones (last
+        page or terminal error served) are kept to the newest
+        MAX_DONE_JOBS, so a recent page stays refetchable
+        (at-least-once); UNDELIVERED ones are kept until
+        CLIENT_TIMEOUT_S after they finished and then dropped as
+        abandoned, so a slow reader never loses rows the server computed.
+        The bound: MAX_DONE_JOBS delivered results plus the undelivered
+        results of the last CLIENT_TIMEOUT_S, which admission bounds
+        (hardConcurrencyLimit + maxQueued; one per closed-loop client)."""
+        now = time.monotonic()
+        delivered, abandoned = [], []
         with self.jobs_lock:
-            done = [qid for qid, j in self.jobs.items() if j.done.is_set()]
-            for qid in done[:-self.MAX_DONE_JOBS]:
+            for qid, j in self.jobs.items():
+                if not j.done.is_set():
+                    continue
+                if j.delivered:
+                    delivered.append(qid)
+                elif now - j.finished_at > self.CLIENT_TIMEOUT_S:
+                    abandoned.append(qid)
+            for qid in delivered[:-self.MAX_DONE_JOBS] + abandoned:
                 del self.jobs[qid]
 
     def query_list_payload(self) -> list:

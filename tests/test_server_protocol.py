@@ -5,6 +5,7 @@ presto-tests)."""
 
 import json
 import time
+import urllib.error
 import urllib.request
 
 import pytest
@@ -87,6 +88,11 @@ def test_page_refetch_is_idempotent(server, monkeypatch):
     with urllib.request.urlopen(url) as r:
         page2 = json.loads(r.read())
     assert page1["data"] == page2["data"]
+    # read to the end: a half-read result is kept for its client
+    # (CLIENT_TIMEOUT_S) and would count among the shared server's jobs
+    assert page1["nextUri"].endswith(f"{qid}/2")
+    with urllib.request.urlopen(page1["nextUri"]) as r:
+        assert "nextUri" not in json.loads(r.read())
 
 
 def test_cancel(server):
@@ -98,6 +104,9 @@ def test_cancel(server):
     job = server.jobs[client.query_id]
     job.done.wait(timeout=30)
     assert job.state in ("FINISHED", "CANCELED")
+    with urllib.request.urlopen(
+            f"{server.uri}/v1/statement/{client.query_id}/0") as r:
+        assert json.loads(r.read())["stats"]["state"] == job.state
 
 
 def test_concurrent_queries(server):
@@ -127,6 +136,135 @@ def test_done_jobs_bounded(server):
     with server.jobs_lock:
         done = [j for j in server.jobs.values() if j.done.is_set()]
     assert len(done) <= server.MAX_DONE_JOBS + 1
+
+
+# ---------------------------------------------------------------------------
+# Retention of finished jobs (PrestoTpuServer._prune_done): a result stays
+# until its client has read it, or CLIENT_TIMEOUT_S after it finished
+# ---------------------------------------------------------------------------
+
+KEYS_SQL = "SELECT n_nationkey FROM nation ORDER BY 1"  # 25 rows: 3 pages of 10
+
+
+@pytest.fixture
+def own_server(tpch_catalog_tiny, monkeypatch):
+    """A server of the case's own (its `jobs` and `CLIENT_TIMEOUT_S` are the
+    case's to set), one worker slot, ten rows a page."""
+    import presto_tpu.server.protocol as proto
+
+    monkeypatch.setattr(proto, "PAGE_ROWS", 10)
+    srv = PrestoTpuServer(presto_tpu.connect(tpch_catalog_tiny),
+                          max_concurrent=1).start()
+    yield srv
+    srv.stop()
+
+
+def finish_unpolled(srv, sql):
+    """A query that ran to its end and whose client has not asked yet."""
+    job = srv.submit(sql)
+    assert job.done.wait(timeout=30)
+    return job.query_id
+
+
+def get_page(srv, qid, token):
+    """-> (HTTP status, payload) of one poll."""
+    try:
+        with urllib.request.urlopen(
+                f"{srv.uri}/v1/statement/{qid}/{token}", timeout=30) as r:
+            return r.status, json.loads(r.read())
+    except urllib.error.HTTPError as e:
+        return e.code, {}
+
+
+def read_from(srv, qid, token):
+    """The rows from page `token` to the last page."""
+    rows = []
+    while token is not None:
+        status, page = get_page(srv, qid, token)
+        assert status == 200, (qid, token, status)
+        rows += [r[0] for r in page.get("data", [])]
+        nxt = page.get("nextUri")
+        token = int(nxt.rsplit("/", 1)[1]) if nxt else None
+    return rows
+
+
+def deliver(srv, n):
+    """n later queries, each read to its last page."""
+    for _ in range(n):
+        connect_http(srv.uri).execute("SELECT 1")
+
+
+def retention_unpolled(srv):
+    qid = finish_unpolled(srv, KEYS_SQL)
+    deliver(srv, srv.MAX_DONE_JOBS + 10)
+    assert read_from(srv, qid, 0) == list(range(25))
+
+
+def retention_half_read(srv):
+    qid = finish_unpolled(srv, KEYS_SQL)
+    status, page = get_page(srv, qid, 0)
+    assert status == 200 and page["nextUri"].endswith(f"{qid}/1")
+    deliver(srv, srv.MAX_DONE_JOBS + 10)
+    assert read_from(srv, qid, 1) == list(range(10, 25))
+
+
+def retention_abandoned(srv):
+    srv.CLIENT_TIMEOUT_S = 0.0
+    qid = finish_unpolled(srv, KEYS_SQL)
+    deliver(srv, 1)  # the next last page served runs the rule
+    assert get_page(srv, qid, 0)[0] == 404
+
+
+def retention_refetch(srv):
+    qid = finish_unpolled(srv, KEYS_SQL)
+    assert read_from(srv, qid, 0) == list(range(25))
+    deliver(srv, srv.MAX_DONE_JOBS - 1)  # still among the newest 64
+    assert read_from(srv, qid, 2) == list(range(20, 25))
+    deliver(srv, 1)
+    assert get_page(srv, qid, 2)[0] == 404
+
+
+def _delivered_once_served(srv, qid, state):
+    deliver(srv, srv.MAX_DONE_JOBS + 10)
+    job = srv.jobs[qid]  # nobody asked: kept
+    assert job.state == state and not job.delivered
+    status, page = get_page(srv, qid, 0)
+    assert status == 200 and page["stats"]["state"] == state
+    assert job.delivered
+    deliver(srv, srv.MAX_DONE_JOBS)  # now one of the delivered, and old
+    assert get_page(srv, qid, 0)[0] == 404
+
+
+def retention_failed(srv):
+    qid = finish_unpolled(srv, "SELECT nocol FROM nation")
+    _delivered_once_served(srv, qid, "FAILED")
+
+
+def retention_canceled(srv):
+    assert srv._sema.acquire(timeout=30)  # the one slot: the job waits for it
+    try:
+        job = srv.submit(KEYS_SQL)
+        req = urllib.request.Request(
+            f"{srv.uri}/v1/statement/{job.query_id}/0", method="DELETE")
+        with urllib.request.urlopen(req, timeout=30) as r:
+            assert json.loads(r.read())["canceled"]
+    finally:
+        srv._sema.release()
+    assert job.done.wait(timeout=30)
+    _delivered_once_served(srv, job.query_id, "CANCELED")
+
+
+def retention_thousand(srv):
+    deliver(srv, 1000)
+    assert len(srv.jobs) <= srv.MAX_DONE_JOBS + 1
+
+
+@pytest.mark.parametrize("case", [
+    retention_unpolled, retention_half_read, retention_abandoned,
+    retention_refetch, retention_failed, retention_canceled,
+    retention_thousand], ids=lambda f: f.__name__[len("retention_"):])
+def test_finished_job_retention(own_server, case):
+    case(own_server)
 
 
 def test_heartbeat_failure_detection(server):

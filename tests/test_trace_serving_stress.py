@@ -133,6 +133,66 @@ def test_dashboard_shape_answers_as_solo(served, profiled, tmp_path):
             assert "presto:" + want in names, want
 
 
+def test_a_stalled_reader_keeps_its_answer(served, monkeypatch):
+    """One of the eight clients sleeps before its GET (on the chip: a
+    retransmitted SYN, PERF.md section 7) while the other seven keep the
+    rate up, so more than MAX_DONE_JOBS lookups finish and are read in
+    between: its rows are still there and equal the solo answer."""
+    import presto_tpu.server.protocol as proto
+
+    session, srv, pool, solo = served
+    # no grace: every answer is fetched by a GET, as a lookup over the
+    # grace is on the chip
+    monkeypatch.setattr(proto, "FIRST_RESPONSE_GRACE_S", 0.0)
+    stall_s, stalls_wanted = 0.5, 4
+    read = [0]                  # lookups the seven have read to the end
+    read_lock = threading.Lock()
+    stalls, wrong, errors = [], [], []
+    enough = threading.Event()
+    deadline = time.monotonic() + TIME_LIMIT_S
+
+    def lookup(k, stalled):
+        c = StatementClient(srv.uri, f"EXECUTE pt USING {k}")
+        c.advance()             # the POST
+        got = [list(r) for r in c._current_data]
+        if stalled:
+            seen = read[0]
+            time.sleep(stall_s)
+            while (read[0] - seen <= srv.MAX_DONE_JOBS
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+            stalls.append(read[0] - seen)
+        got += [list(r) for r in c.rows()]
+        if got != solo[k]:
+            wrong.append((k, got, solo[k]))
+
+    def loop(cid):
+        rng = np.random.default_rng([32, cid])
+        while not enough.is_set() and time.monotonic() < deadline:
+            try:
+                lookup(pool[int(rng.integers(len(pool)))], cid == 0)
+            except Exception as e:  # noqa: BLE001 — counted, asserted on
+                errors.append(f"client {cid}: {type(e).__name__}: {e}")
+            if cid == 0:
+                if len(stalls) >= stalls_wanted:
+                    enough.set()
+            else:
+                with read_lock:
+                    read[0] += 1
+
+    threads = [threading.Thread(target=loop, args=(i,), daemon=True)
+               for i in range(CLIENTS)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=TIME_LIMIT_S + 30)
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    assert wrong == []
+    assert len(stalls) == stalls_wanted
+    assert min(stalls) > srv.MAX_DONE_JOBS
+
+
 def profile_span_names(trace_dir):
     import glob
     import os
