@@ -161,13 +161,9 @@ def phase_kernels(on_tpu):
         same = jax.jit(lambda g, s, i: jnp.array_equal(g, s[i])
                        & jnp.array_equal(g, rows_of(i)))(got, src, idx)
         check(bool(same), "staged_gather != src[idx]")
-        # on the TPU backend the Pallas block-gather is switched off in
-        # the routing (gather._block_gather_enabled): XLA gather expected
-        check(is_kernel == (on_tpu and G._block_gather_enabled()))
+        check(not is_kernel, "staged_gather is XLA's gather: no kernel")
         emit("kernels", kernel="staged_gather", n=n, m=m, w=w,
-             tpu_custom_call=is_kernel,
-             block_gather_enabled=G._block_gather_enabled(),
-             ms=ms_since(t0))
+             tpu_custom_call=is_kernel, ms=ms_since(t0))
 
     # the route the engine takes by itself: a request-order gather of a
     # mixed-width row through take_rows (staged on the chip)
@@ -260,8 +256,8 @@ def phase_serve(sf):
     from tests.tpch_queries import QUERIES
 
     session = presto_tpu.connect(tpch_catalog(sf, cache_dir=None))
-    # DOUBLE math in f32 on the device, as bench.py runs it: puts the
-    # fused-aggregate kernel on the path
+    # DOUBLE math in f32 on the device, as the benchmark's cells run it:
+    # puts the fused-aggregate kernel on the path
     session.set("float32_compute", True)
     # a second door over the same session whose tier has no result
     # cache, so a repeated text really executes (built first: the

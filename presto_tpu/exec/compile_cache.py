@@ -38,9 +38,9 @@ Three layers, all fronted by this module:
 
 Telemetry: every build routes through `build_jit`, so QueryStats gains
 exact `compiles` / `compile_ms` / `compile_cache_hits` /
-`compile_ahead_hits` per query (bench.py emits them as
-`compile_economics`).  Persistent-cache disk hits are observed through
-jax.monitoring's `/jax/compilation_cache/cache_hits` event.
+`compile_ahead_hits` per query.  Persistent-cache disk hits are
+observed through jax.monitoring's `/jax/compilation_cache/cache_hits`
+event.
 """
 
 from __future__ import annotations
@@ -66,7 +66,7 @@ DEFAULT_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 #: QueryStats counter names this module maintains (observe/stats.py
-#: declares the same fields; bench.py emits them as compile_economics)
+#: declares the same fields)
 COUNTERS = ("compiles", "compile_ms", "compile_cache_hits",
             "compile_ahead_hits")
 
@@ -432,16 +432,6 @@ def build_jit(fn: Callable, *, example=None, tag: Optional[str] = None,
     else:
         _note("compiles")
     return ex
-
-
-def static_jit(fn=None, **jit_kwargs):
-    """Plain jax.jit passthrough for KERNEL helpers that are invoked
-    inside other traced programs (e.g. the Pallas block-gather): nested
-    jits inline into the enclosing trace, so counting them would
-    double-book the enclosing program's compile."""
-    if fn is None:
-        return lambda f: jax.jit(f, **jit_kwargs)
-    return jax.jit(fn, **jit_kwargs)
 
 
 # ---------------------------------------------------------------------------

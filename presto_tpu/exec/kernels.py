@@ -839,11 +839,11 @@ def take_rows(arrays: List[jnp.ndarray], idx: jnp.ndarray,
     X64 rewriter cannot lower f64 bitcasts).
 
     Large gathers route through the gather-aware tier (exec/gather.py):
-    indices are sorted, rows are staged through VMEM-windowed
-    sequential reads (Pallas block-gather), and results ride ONE
-    co-sort back to request order.  `presorted=True` asserts idx is
-    already nondecreasing (ascending expansions, sort_order_plan
-    output): the staging then skips both the sort and the way home."""
+    indices are sorted, rows are gathered in ascending order, and
+    results ride ONE co-sort back to request order.  `presorted=True`
+    asserts idx is already nondecreasing (ascending expansions,
+    sort_order_plan output): the staging then skips both the sort and
+    the way home."""
     if arrays and arrays[0].shape[0] == 0 and idx.shape[0] > 0:
         # gathering from an EMPTY source (e.g. a zero-row exchange
         # buffer): every index is dead and the caller masks the result —
@@ -899,8 +899,8 @@ def take_rows(arrays: List[jnp.ndarray], idx: jnp.ndarray,
 
 @NM.scoped("k:take_rows.staged")
 def _take_rows_staged(arrays, idx, words, spec, presorted):
-    """Sorted-index staging: ascending gather through exec/gather's
-    VMEM-windowed kernel, then (for request-order callers) ONE co-sort
+    """Sorted-index staging: the ascending gather (exec/gather.
+    staged_gather), then (for request-order callers) ONE co-sort
     keyed on the saved positions carries every word — and the f64
     side columns — home together.  Payload operands ride a lax.sort
     nearly free; the inverse-permutation GATHER this replaces paid the

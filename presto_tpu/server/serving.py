@@ -50,8 +50,7 @@ Three pieces, composable and individually optional:
   and oversized results are never cached.
 
 `ServingTier` composes the three for the protocol server
-(server/protocol.py) and `bench.py --serve` (the closed-loop QPS
-benchmark with the SERVE_r01.json record).
+(server/protocol.py).
 """
 
 from __future__ import annotations

@@ -231,8 +231,7 @@ DEFAULT_SESSION_PROPERTIES: Dict[str, Any] = {
     "admission_queue_timeout_s": 60.0,
     # coordinator fleet (server/fleet.py; docs/SERVING.md "Multi-
     # coordinator topology"): coordinator_count is the serving-fleet
-    # size (1 = classic single coordinator; bench.py --serve
-    # --coordinators N overrides per run); fleet_affinity is the front
+    # size (1 = classic single coordinator); fleet_affinity is the front
     # door's routing mode for statements owned by a ring peer — proxy
     # (default: forward and re-home URIs, dumb clients keep one
     # endpoint) | redirect (307 to the owner; clients that follow it

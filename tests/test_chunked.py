@@ -260,22 +260,20 @@ def test_order_insensitive_walk():
 
 def test_chunked_sort_order_materialization(sessions, monkeypatch):
     """Force the gather-staging tier on at test sizes: the chunked
-    join-under-partial-agg programs then run the Pallas block-gather /
-    sort-order materialization paths (interpret mode on CPU) and must
-    still match whole-table results exactly."""
+    join-under-partial-agg programs then run the sort-order
+    materialization paths and must still match whole-table results
+    exactly."""
     from presto_tpu.exec import gather as G
 
     monkeypatch.setenv("PRESTO_TPU_GATHER", "force")
     monkeypatch.setattr(G, "_STAGED_MIN_INDICES", 1)
-    monkeypatch.setattr(G, "_IB", 64)
-    monkeypatch.setattr(G, "_MAX_WINDOW", 512)
     staged = presto_tpu.connect(
         tpch_catalog(SF, cache_dir="/tmp/presto_tpu_cache"))
     staged.properties["chunked_rows_threshold"] = 50_000
     staged.properties["chunk_orders"] = 20_000
     _, whole = sessions
     # Q18: expanding join under a partial aggregate — the exact shape
-    # the sort-order/blocked tier targets (Q3 rides the same kernels
+    # the sort-order tier targets (Q3 rides the same kernels
     # via test_chunked_matches_whole)
     got = staged.sql(QUERIES[18])
     want = whole.sql(QUERIES[18])

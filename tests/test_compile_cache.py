@@ -227,7 +227,7 @@ def test_concurrent_chunked_queries_with_compile_ahead(tpch_catalog_tiny,
 # ---------------------------------------------------------------------------
 
 _SUBPROC = r"""
-import json, os, sys, time
+import json, sys
 sys.path.insert(0, {root!r})
 import presto_tpu
 from presto_tpu.catalog import tpch_catalog
@@ -235,14 +235,10 @@ from tests.tpch_queries import QUERIES
 
 s = presto_tpu.connect(tpch_catalog(0.005, cache_dir=None),
                        execution_mode="compiled")
-t0 = time.perf_counter()
 r = s.sql(QUERIES[3])
-wall = time.perf_counter() - t0
 print(json.dumps({{"compiles": r.stats.compiles,
-                  "compile_ms": r.stats.compile_ms,
                   "cache_hits": r.stats.compile_cache_hits,
-                  "wall_ms": wall * 1000,
-                  "rows": len(r.rows)}}))
+                  "rows": repr(r.rows)}}))
 """
 
 
@@ -279,9 +275,11 @@ def test_cache_dir_placed_from_outside_is_left_alone(monkeypatch, tmp_path):
 
 def test_persistent_cache_across_processes(tmp_path):
     """Two fresh subprocesses over one persistent cache dir: the first
-    compiles cold into it; the second reports compile_cache_hits > 0
-    and a lower cold wall-clock — the compile bill is per MACHINE, not
-    per process."""
+    compiles cold into it; the second builds the same programs
+    (`compiles` counts builds, loaded or not) with compile_cache_hits
+    > 0 and answers the same rows — the compile bill is per MACHINE,
+    not per process.  No wall time is compared: under load the second
+    process can be the slower one."""
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
                PRESTO_TPU_COMPILE_CACHE=str(tmp_path / "cc"),
@@ -300,10 +298,8 @@ def test_persistent_cache_across_processes(tmp_path):
 
     r1 = run()
     r2 = run()
-    assert r1["compiles"] > 0 and r1["rows"] > 0
-    assert r2["rows"] == r1["rows"]
+    assert r1["compiles"] > 0 and r1["cache_hits"] == 0
+    assert r2["rows"] == r1["rows"] != "[]"
+    assert r2["compiles"] == r1["compiles"]
     assert r2["cache_hits"] > 0, \
         f"warmed dir served no executables: {r2}"
-    assert r2["wall_ms"] < r1["wall_ms"], \
-        f"warmed cold start not faster: {r1['wall_ms']:.0f}ms -> " \
-        f"{r2['wall_ms']:.0f}ms"

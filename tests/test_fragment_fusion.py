@@ -133,8 +133,7 @@ def test_fused_vs_cut_checksum_equivalence(qid, fusion_cluster,
     fragment-cut path AND the sqlite oracle.  q18/q21's cut legs are
     tier-2 (the cut path's cold per-fragment execution costs tens of
     seconds on the 1-core CI tier); tier-1 covers q18 fused via
-    test_q18_single_fused_program and the committed MULTICHIP_r07
-    record carries the measured q18 fused-vs-cut-vs-auto equality."""
+    test_q18_single_fused_program."""
     session, cs, w = fusion_cluster
     session.set("fragment_fusion", True)
     fused = cs.sql(QUERIES[qid])
@@ -156,7 +155,7 @@ def test_fused_vs_cut_checksum_equivalence(qid, fusion_cluster,
 def test_q18_single_fused_program(fusion_cluster, tpch_sqlite_tiny):
     """q18 (the deep join+agg gate query) fuses into ONE program with
     zero host exchange bytes and matches the sqlite oracle; its full
-    fused-vs-cut leg is tier-2 + the committed MULTICHIP_r07 record;
+    fused-vs-cut leg is tier-2;
     the round-18 AUTO leg (cost model picks cut here) lives in
     tests/test_fusion_cost.py."""
     session, cs, _w = fusion_cluster

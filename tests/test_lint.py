@@ -65,7 +65,7 @@ def test_no_raw_device_sorts_outside_kernels():
 
 def test_no_raw_jax_jit_outside_compile_economics():
     """Compile-economics gate (ISSUE 4): every engine-level jax.jit
-    must route through exec/compile_cache.py (build_jit / static_jit)
+    must route through exec/compile_cache.py (build_jit)
     so XLA compiles are counted, memoized process-wide, and eligible
     for compile-ahead — the two executors (exec/chunked.py,
     exec/executor.py) are the only other modules allowed to spell

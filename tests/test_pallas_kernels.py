@@ -142,20 +142,17 @@ def test_fused_agg_in_query(tpch_catalog_tiny):
 
 
 # ---------------------------------------------------------------------------
-# gather-aware tier (exec/gather.py): blocked Pallas gather + sort-order
-# staging must be BYTE-IDENTICAL to the flat packed gather
+# gather-aware tier (exec/gather.py): sort-order staging must be
+# BYTE-IDENTICAL to the flat packed gather
 # ---------------------------------------------------------------------------
 
 
 @pytest.fixture
 def tiny_gather(monkeypatch):
-    """Shrink the routing/window constants so the staged tier (and the
-    Pallas block-gather inside it) engages at test sizes; 'force'
-    opts in to staging off-TPU (auto mode is TPU-only)."""
+    """Shrink the routing constant so the staged tier engages at test
+    sizes; 'force' opts in to staging off-TPU (auto mode is TPU-only)."""
     monkeypatch.setenv("PRESTO_TPU_GATHER", "force")
     monkeypatch.setattr(G, "_STAGED_MIN_INDICES", 1)
-    monkeypatch.setattr(G, "_IB", 64)
-    monkeypatch.setattr(G, "_MAX_WINDOW", 512)
     yield
 
 
@@ -171,11 +168,24 @@ def _dtype_arrays(n, rng):
     ]
 
 
-def test_staged_take_rows_matches_flat(tiny_gather, monkeypatch):
+def _request_indices(dist, n, m, rng):
+    """Request-order indices into n rows: spread evenly, piled on the
+    two ends of the source, or hitting nearly every row."""
+    if dist == "skewed":
+        idx = np.where(rng.integers(0, 2, m) == 0, 0, n - 1)
+    elif dist == "dense":
+        idx = rng.permutation(np.arange(m) % n)
+    else:
+        idx = rng.integers(0, n, m)
+    return jnp.asarray(idx.astype(np.int32))
+
+
+@pytest.mark.parametrize("dist", ["uniform", "skewed", "dense"])
+def test_staged_take_rows_matches_flat(tiny_gather, monkeypatch, dist):
     rng = np.random.default_rng(7)
     n, m = 5000, 4096
     arrays = _dtype_arrays(n, rng)
-    idx = jnp.asarray(rng.integers(0, n, m).astype(np.int32))
+    idx = _request_indices(dist, n, m, rng)
     assert G.gather_route(n, m, 8) == "staged"
     staged = K.take_rows(arrays, idx)
     monkeypatch.setenv("PRESTO_TPU_GATHER", "flat")
@@ -195,35 +205,6 @@ def test_staged_take_rows_presorted(tiny_gather, monkeypatch):
     flat = K.take_rows(arrays, sidx)
     for a, b in zip(flat, staged):
         assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_staged_gather_skew_falls_back_covered(tiny_gather):
-    """Index blocks whose span exceeds the window must take the
-    lax.cond fallback and still return exact rows."""
-    rng = np.random.default_rng(9)
-    n, m = 8192, 1024
-    src = jnp.asarray(rng.integers(0, 1 << 32, (n, 3)).astype(np.uint32))
-    # maximally skewed: indices alternate across the whole range
-    skew = np.sort(np.concatenate([
-        np.zeros(m // 2, np.int32), np.full(m - m // 2, n - 1, np.int32)]))
-    # interleave so single blocks span the full source
-    skew[::2], skew[1::2] = 0, n - 1
-    skew = np.sort(skew)  # staged_gather requires ascending
-    out = G.staged_gather(src, jnp.asarray(skew))
-    assert np.array_equal(np.asarray(out), np.asarray(src)[skew])
-
-
-def test_staged_gather_dense_uses_windows(tiny_gather):
-    """Dense ascending indices satisfy coverage (windows engage) and
-    the result is exact."""
-    rng = np.random.default_rng(10)
-    n, m = 4096, 4096
-    src = jnp.asarray(rng.integers(0, 1 << 32, (n, 2)).astype(np.uint32))
-    sidx = jnp.asarray(np.sort(rng.integers(0, n, m)).astype(np.int32))
-    W = G.window_rows(n, m)
-    assert W is not None
-    out = G.staged_gather(src, sidx)
-    assert np.array_equal(np.asarray(out), np.asarray(src)[np.asarray(sidx)])
 
 
 def test_gather_batch_staged_oob_and_validity(tiny_gather, monkeypatch):
@@ -308,26 +289,13 @@ def test_gather_route_env_off(monkeypatch):
 
 
 def test_gather_route_auto_is_tpu_only(monkeypatch):
-    """Auto mode must NOT stage off-TPU: the interpret-mode Pallas
-    grid at production index counts unrolls into an XLA CPU program
-    that effectively never finishes compiling (tpcds q37 regression)."""
+    """Auto mode must NOT stage off-TPU: the routing constants are the
+    TPU's."""
     monkeypatch.delenv("PRESTO_TPU_GATHER", raising=False)
     assert jax.default_backend() != "tpu"
     assert G.gather_route(1 << 23, 1 << 22, 8) == "flat"
     assert G.gather_route(1 << 23, 1 << 22, 8, presorted=True) == "flat"
     assert not G.sort_order_worthwhile(1 << 22, 4)
-
-
-def test_window_rows_density():
-    IB = G._IB
-    # dense (m == n): the 2x slack window
-    assert G.window_rows(1 << 23, 1 << 23) == 2 * IB
-    # 2:1 density doubles the window (2x slack x 2 rows/index)
-    assert G.window_rows(1 << 23, 1 << 22) == 4 * IB
-    # too sparse for any window: staging falls back to the plain
-    # ascending gather
-    assert G.window_rows(1 << 23, 1 << 18) is None
-    assert G.window_rows(0, 1 << 20) is None
 
 
 def test_sort_order_worthwhile_gate(monkeypatch):

@@ -5,8 +5,7 @@ Absolute wall-clock is too noisy on shared CI hosts, so the default
 suite gates RELATIVE plan quality: the cost-based optimizer may never
 make a query meaningfully slower than the greedy order it replaces —
 the exact failure mode that shipped `vs_baseline 0.98` in round 3.
-bench.py separately gates absolute warm times on the real chip against
-tests/perf_reference.json and reports `perf_gate` in its JSON line.
+Absolute times on the chip are the benchmark's (`BENCHMARK.json`).
 """
 
 import time

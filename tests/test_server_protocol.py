@@ -224,7 +224,7 @@ def retention_refetch(srv):
     assert get_page(srv, qid, 2)[0] == 404
 
 
-def _delivered_once_served(srv, qid, state):
+def delivered_once_served(srv, qid, state):
     deliver(srv, srv.MAX_DONE_JOBS + 10)
     job = srv.jobs[qid]  # nobody asked: kept
     assert job.state == state and not job.delivered
@@ -237,7 +237,7 @@ def _delivered_once_served(srv, qid, state):
 
 def retention_failed(srv):
     qid = finish_unpolled(srv, "SELECT nocol FROM nation")
-    _delivered_once_served(srv, qid, "FAILED")
+    delivered_once_served(srv, qid, "FAILED")
 
 
 def retention_canceled(srv):
@@ -251,7 +251,7 @@ def retention_canceled(srv):
     finally:
         srv._sema.release()
     assert job.done.wait(timeout=30)
-    _delivered_once_served(srv, job.query_id, "CANCELED")
+    delivered_once_served(srv, job.query_id, "CANCELED")
 
 
 def retention_thousand(srv):

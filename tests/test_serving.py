@@ -529,94 +529,6 @@ def test_cluster_coordinator_admission(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# the serving QPS gate (bench.py --serve artifact)
-# ---------------------------------------------------------------------------
-
-
-def test_serve_gate_units():
-    import bench
-
-    rec = {"platform": "cpu", "sf": 0.01, "failures": 0,
-           "qps_per_chip": 100.0, "p99_ms": 200.0,
-           "box_sort_ms": 100.0}
-    assert bench._serve_gate(dict(rec), None).startswith("pass")
-    committed = {"platform": "cpu", "sf": 0.01,
-                 "qps_per_chip": 100.0, "p99_ms": 200.0,
-                 "box_sort_ms": 100.0}
-    assert bench._serve_gate(dict(rec), committed) == "pass"
-    slow = dict(rec, qps_per_chip=10.0)
-    assert bench._serve_gate(slow, committed).startswith("FAIL")
-    spiky = dict(rec, p99_ms=900.0)
-    assert bench._serve_gate(spiky, committed).startswith("FAIL")
-    # box-fingerprint scaling: a box 2x slower than the committed one
-    # halves the qps bar (70 qps passes where an equal box would FAIL)
-    # and doubles the p99 bar
-    slow_box = dict(rec, qps_per_chip=70.0, p99_ms=500.0,
-                    box_sort_ms=200.0)
-    assert bench._serve_gate(slow_box, committed) == "pass"
-    # no fingerprint on the committed record -> absolute legs skipped
-    assert bench._serve_gate(
-        dict(rec, qps_per_chip=10.0),
-        {k: v for k, v in committed.items() if k != "box_sort_ms"},
-    ).startswith("pass (committed record has no box fingerprint")
-    other = dict(committed, platform="tpu")
-    assert bench._serve_gate(dict(rec), other).startswith("pass (no")
-    failed = dict(rec, failures=3)
-    assert bench._serve_gate(failed, committed).startswith("FAIL")
-
-
-def test_mv_serve_gate_units():
-    """SERVE_r04's gate (bench.py --serve --mv): correctness legs are
-    absolute; the p99-flatness leg and the committed-record absolute
-    leg are core-aware (a 1-core box cannot hide co-located refresh
-    compute — the FLEET_GATE enforcement precedent)."""
-    import bench
-
-    rec = {"platform": "cpu", "cores": 4, "failures": 0,
-           "wrong_results": 0, "unrouted": 0,
-           "p99_steady_ms": 10.0, "p99_churn_ms": 12.0,
-           "p99_flat_ratio": 1.2, "routed_ms": 1.0,
-           "recompute_ms": 500.0, "routed_speedup": 500.0,
-           "box_sort_ms": 100.0}
-    committed = dict(rec)
-    assert bench._mv_serve_gate(dict(rec), None).startswith("pass")
-    assert bench._mv_serve_gate(dict(rec), committed) == "pass"
-    for bad in ({"failures": 2}, {"wrong_results": 1}, {"unrouted": 1},
-                {"routed_speedup": 3.0},
-                {"p99_flat_ratio": 2.0, "p99_churn_ms": 20.0}):
-        assert bench._mv_serve_gate(dict(rec, **bad),
-                                    committed).startswith("FAIL"), bad
-    # 1-core box: flatness measured, not enforced — but the
-    # correctness legs stay absolute
-    one_core = dict(rec, cores=1, p99_flat_ratio=2.0,
-                    p99_churn_ms=20.0)
-    out = bench._mv_serve_gate(one_core, committed)
-    assert out.startswith("pass") and "not enforced" in out
-    assert bench._mv_serve_gate(dict(one_core, wrong_results=1),
-                                committed).startswith("FAIL")
-    # absolute churn-p99 leg vs the committed record, box-scaled,
-    # >=2 cores only
-    spiky = dict(rec, p99_churn_ms=40.0, p99_flat_ratio=1.2)
-    assert bench._mv_serve_gate(spiky, committed).startswith("FAIL")
-    assert bench._mv_serve_gate(dict(spiky, cores=1),
-                                committed).startswith("pass")
-
-
-def test_serve_gate_registered_in_bench_artifact():
-    """The committed SERVE record rides the default bench artifact (the
-    gate exits 0 on committed records — re-measuring is --serve)."""
-    import bench
-
-    rec = bench.load_serve_record()
-    assert rec is not None, "SERVE_r01.json must be committed"
-    summary = bench.serve_gate_summary()
-    assert summary["qps_per_chip"] > 0
-    assert summary["p99_ms"] > 0
-    assert str(summary["gate"]).startswith("pass")
-    assert bench._percentile([1, 2, 3, 4], 0.5) == 3
-
-
-# ---------------------------------------------------------------------------
 # query coalescing (ISSUE 12): vmap-batched prepared execution
 # ---------------------------------------------------------------------------
 
@@ -834,7 +746,7 @@ def test_result_cache_hit_accounting_unchanged_under_coalescing():
 
 def test_serving_tier_embedded_admission():
     """ServingTier.admit/release work embedded (no HTTP): the surface
-    bench.py --serve and the protocol server share."""
+    an embedded caller and the protocol server share."""
     s = _session()
     rgm = ResourceGroupManager()
     rgm.add_group("global.e", hard_concurrency_limit=1, max_queued=5)

@@ -55,7 +55,6 @@ def as_tpu(monkeypatch):
     """jax.default_backend() still says cpu here: steer the engine's
     TPU branches from the test, not through an option of the program."""
     monkeypatch.setattr(K, "_pallas_interpret", lambda: False)
-    monkeypatch.setattr(G, "_interpret", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
@@ -69,21 +68,6 @@ def test_fused_group_sums_tpu_body(one_chip, as_tpu, n_groups):
     c = _compile(lambda v, g: K.fused_group_sums(v, g, n_groups), one_chip,
                  ((8, N_ROWS), jnp.float32), ((N_ROWS,), jnp.int32))
     assert "tpu_custom_call" in c.as_text()
-
-
-@pytest.mark.parametrize("W,w", [(8192, 16), (1024, 2)])
-def test_blocked_gather_refused_by_mosaic(one_chip, as_tpu, W, w):
-    """Why gather._block_gather_enabled() is off on the TPU backend:
-    the compiler's own message.  When the kernel is rebuilt so that
-    this compiles, flip the assertion and the routing together."""
-    IB, m = 1024, 2_000_000
-    m_pad = -(-m // IB) * IB
-    n_pad = -(-N_ROWS // W) * W
-    with pytest.raises(Exception, match="Shape mismatch in input, "
-                                        "indices and output"):
-        _compile(lambda b, i, s: G._blocked_gather_call(b, i, s, W=W, IB=IB),
-                 one_chip, ((m_pad // IB,), jnp.int32),
-                 ((1, m_pad), jnp.int32), ((n_pad, w), jnp.uint32))
 
 
 def test_staged_gather_runs_as_xla_on_tpu(one_chip, as_tpu):

@@ -1,5 +1,4 @@
-"""Verifier + benchmark suite tests (reference analogs: the
-presto-verifier unit tests and BenchmarkSuite smoke runs)."""
+"""Verifier tests (reference analog: the presto-verifier unit tests)."""
 
 import presto_tpu
 from presto_tpu.verifier import (Verifier, report, row_checksum,
@@ -43,14 +42,3 @@ def test_verifier_detects_difference(tpch_catalog_tiny):
     v = Verifier(control, session_runner(s))
     r = v.verify_one("x", "SELECT 2")
     assert r.state == "MISMATCH"
-
-
-def test_benchmark_suite_runs(tpch_catalog_tiny):
-    from presto_tpu.benchmarks import build_default_suite
-
-    s = presto_tpu.connect(tpch_catalog_tiny)
-    suite = build_default_suite(s, 0.01)
-    suite.runs = 1
-    results = suite.run("sql_tpch_q6")
-    assert len(results) == 1
-    assert results[0].median_ms > 0 and results[0].rows_per_sec > 0

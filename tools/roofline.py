@@ -316,8 +316,7 @@ def fleet_sweep(max_coord=4):
     and one FleetDirectory, signature-affinity proxying on — and reports
     where the marginal door stops paying (<10% QPS gain), i.e. where
     dispatch has saturated the machine rather than the admission gate.
-    Prints ONE JSON line; the committed scaling record is SERVE_r03.json
-    (bench.py --serve --coordinators N)."""
+    Prints ONE JSON line."""
     import threading
 
     import numpy as np
@@ -699,15 +698,12 @@ def main():
     t = per_iter(timed(sort_loop64, base64_))
     out["sort_i64_mrows_s"] = round(n / t / 1e6, 1)
 
-    # --- gather family: random vs blocked vs sort-order ---------------
+    # --- gather family: random vs sort-order --------------------------
     # Pins the routing constants in exec/gather.py (the crossover where
-    # sorted staging beats the flat packed gather, and where the Pallas
-    # VMEM-window kernel beats the plain ascending gather).  Swept over
+    # sorted staging beats the flat packed gather).  Swept over
     # index count x row width; each cell is ns/index so the table reads
     # directly against the ~45ns/random-index constant from the round-5
     # profile.
-    from presto_tpu.exec import gather as GG
-
     nsrc = 1 << 23  # 8M source rows, the SF100 chunk shape
     gout = {}
     for width in (1, 2, 4, 8):
@@ -731,21 +727,11 @@ def main():
                         .astype(jnp.int32)
                 return lax.fori_loop(0, K, body, jnp.int32(0))
 
-            @jax.jit
-            def blocked_loop(src, sidx):
-                def body(i, s):
-                    out = GG.staged_gather(
-                        src, jnp.clip(sidx + s, 0, nsrc - 1))
-                    return out[0, 0].astype(jnp.int32)
-                return lax.fori_loop(0, K, body, jnp.int32(0))
-
             cell = {}
             cell["random_ns_per_idx"] = round(
                 per_iter(timed(rand_loop, src, ridx)) / m * 1e9, 2)
             cell["sorted_ns_per_idx"] = round(
                 per_iter(timed(sorted_loop, src, sidx)) / m * 1e9, 2)
-            cell["blocked_ns_per_idx"] = round(
-                per_iter(timed(blocked_loop, src, sidx)) / m * 1e9, 2)
             gout[f"w{width}_m{m >> 20}M"] = cell
     out["gather"] = gout
 
