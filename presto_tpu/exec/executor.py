@@ -60,7 +60,8 @@ def _merge_sort_stats(stats, counts: dict) -> None:
               "approx_rewrites",
               "spill_partitions", "spill_bytes", "spill_restores",
               "spill_recursions",
-              "partial_aggs_bypassed", "partial_aggs_reenabled"):
+              "partial_aggs_bypassed", "partial_aggs_reenabled",
+              "aggs_fused", "aggs_unfused"):
         setattr(stats, k, getattr(stats, k, 0) + int(counts.get(k, 0)))
     if counts.get("partial_agg_ratio"):
         # a gauge, not a sum: the last ratio a partial stage observed
@@ -2102,9 +2103,7 @@ class Executor:
         for k, (data, valid) in raw.items():
             c = b.columns[k]
             out_cols[k] = Column(data, valid, c.type, c.dictionary)
-        fused = self._fused_sum_aggs(b, aggs, gid, n_groups)
-        for sym, a in aggs.items():
-            out_cols[sym] = fused.get(sym) or self._agg_column(b, a, gid, n_groups)
+        out_cols.update(self._agg_columns(b, aggs, gid, n_groups))
         sel = jnp.ones((max(n_groups, 0),), dtype=bool)
         if n_groups == 0:
             out_cols = {k: Column(c.data[:0], None if c.valid is None else c.valid[:0],
@@ -2184,9 +2183,7 @@ class Executor:
             out_cols[k] = Column(
                 data, None if valid is None else (valid & exists),
                 c.type, c.dictionary)
-        fused = self._fused_sum_aggs(b, aggs, gid, cap)
-        for sym, a in aggs.items():
-            out_cols[sym] = fused.get(sym) or self._agg_column(b, a, gid, cap)
+        out_cols.update(self._agg_columns(b, aggs, gid, cap))
         out = Batch(out_cols, exists)
         if layout is not None:
             # live prefix ascending on the packed key; dead slots carry
@@ -2217,9 +2214,7 @@ class Executor:
             data = (code - 1 + lo).astype(c.data.dtype)
             valid = None if c.valid is None else ((code != 0) & exists)
             out_cols[k] = Column(data, valid, c.type, c.dictionary)
-        fused = self._fused_sum_aggs(b, aggs, gid, cap)
-        for sym, a in aggs.items():
-            out_cols[sym] = fused.get(sym) or self._agg_column(b, a, gid, cap)
+        out_cols.update(self._agg_columns(b, aggs, gid, cap))
         out = Batch(out_cols, exists)
         # slot order IS packed-key order (live slots ascending), but
         # EMPTY slots sit interspersed: not tail-masked
@@ -2227,10 +2222,23 @@ class Executor:
                          tail_ok=False)
         return out
 
+    def _agg_columns(self, b: Batch, aggs: Dict[str, ir.AggCall],
+                     gid, n_groups: int) -> Dict[str, Column]:
+        """A grouped node's aggregate columns: the fused pass answers
+        what it can, _agg_column the rest.  aggs_fused / aggs_unfused
+        count each side (at trace time, like the sort economics), so a
+        plan whose aggregates fall off the fused kernel says so in
+        QueryStats."""
+        fused = self._fused_sum_aggs(b, aggs, gid, n_groups)
+        self._count("aggs_fused", len(fused))
+        self._count("aggs_unfused", len(aggs) - len(fused))
+        return {sym: fused.get(sym) or self._agg_column(b, a, gid, n_groups)
+                for sym, a in aggs.items()}
+
     def _fused_sum_aggs(self, b: Batch, aggs: Dict[str, ir.AggCall],
                         gid, n_groups: int) -> Dict[str, Column]:
-        """Prepass: compute all sum-shaped aggregates (count/count_if/
-        sum/avg over DOUBLE) in ONE Pallas pass over the rows
+        """Prepass: compute all sum-shaped aggregates (count(*)/count(x)/
+        count_if, sum/avg over DOUBLE) in ONE Pallas pass over the rows
         (kernels.fused_group_sums) instead of one scatter-add per
         aggregate.  Returns {} when not worthwhile; callers fall through
         to _agg_column per aggregate."""
@@ -2244,7 +2252,7 @@ class Executor:
         # threshold set bails out before any expression is evaluated
         # (otherwise _agg_column would redo each eval)
         def fusable(a):
-            if a.fn == "count" and not a.args:
+            if a.fn == "count" and len(a.args) <= 1:
                 return True
             if a.fn == "count_if":
                 return True
@@ -2268,13 +2276,29 @@ class Executor:
         rows: List[jnp.ndarray] = []
         plan: Dict[str, tuple] = {}
         any_f32 = False
-        for sym, a in chosen.items():
+        # count(x) waits for the sums: where sum/avg/partial_sum_double
+        # of the same (argument, filter) stacks `valid` as its count row
+        # (avg's PARTIAL decomposition, plan/distribute.py), the count
+        # is that row and adds none
+        count_rows: Dict[tuple, int] = {}
+        for sym, a in sorted(chosen.items(), key=lambda kv: (
+                kv[1].fn == "count" and bool(kv[1].args))):
+            of_arg = (a.args[0], a.filter) if a.args else None
+            if a.fn == "count" and of_arg in count_rows:
+                plan[sym] = ("count", count_rows[of_arg])
+                continue
             mask = b.sel
             if a.filter is not None:
                 mask = mask & eval_predicate(a.filter, b, self.ctx)
             if a.fn == "count" and not a.args:
                 plan[sym] = ("count", len(rows))
                 rows.append(mask)
+            elif a.fn == "count":
+                # only x's validity is read: any argument type
+                v = eval_expr(a.args[0], b, self.ctx)
+                plan[sym] = ("count", len(rows))
+                count_rows[of_arg] = len(rows)
+                rows.append(mask if v.valid is None else (mask & v.valid))
             elif a.fn == "count_if":
                 v = eval_expr(a.args[0], b, self.ctx)
                 m = mask & jnp.asarray(v.data)
@@ -2295,6 +2319,7 @@ class Executor:
                 ci = len(rows)
                 rows.append(valid)
                 plan[sym] = (a.fn, vi, ci, a.type)
+                count_rows.setdefault(of_arg, ci)
         if len(plan) < (1 if any_f32 else 2):
             return {}
         # on the TPU path the kernel uses f32 block partials with an f64

@@ -203,6 +203,15 @@ class QueryStats:
     partial_aggs_bypassed: int = 0
     partial_aggs_reenabled: int = 0
     agg_strategy: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # fused aggregation engagement (Executor._agg_columns): over the
+    # grouped aggregate nodes a program traced, how many aggregates the
+    # one fused_group_sums pass answered and how many fell to a
+    # per-aggregate segment reduction (counted at trace time, replayed
+    # with every run of a cached program).  A node under the kernel's
+    # gate (rows < 32,768, groups > 4096, no float32_compute on a TPU)
+    # reads all of its aggregates unfused.
+    aggs_fused: int = 0
+    aggs_unfused: int = 0
     result_cache_hit: int = 0
     resource_group: str = ""
     admission_wait_ms: float = 0.0

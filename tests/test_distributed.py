@@ -4,6 +4,7 @@ DistributedQueryRunner — a fake multi-node cluster in one process,
 presto-tests/.../DistributedQueryRunner.java:78)."""
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 import presto_tpu
@@ -158,3 +159,53 @@ def test_all_22_tpch_queries_distribute(dsession):
     dynamic = [k for k, v in cache.items() if v == "DYNAMIC"]
     assert not dynamic, f"queries fell back to single-device: {dynamic}"
     assert len(cache) >= 22
+
+
+def test_q1_partial_counts_ride_the_fused_pass(monkeypatch):
+    """On the mesh avg(x) is partial_sum_double(x) + count(x): the three
+    counts are the sums' own count rows of the one fused pass, and no
+    integer segment_sum reads a shard's rows.  SF0.05 on four devices:
+    a shard (75 k rows) is over the fused kernel's 32,768-row gate."""
+    from presto_tpu.catalog import tpch_catalog
+    from presto_tpu.exec import kernels as K
+
+    cat = tpch_catalog(0.05, cache_dir=None)
+    one = presto_tpu.connect(cat)
+    one.set("float32_compute", True)
+    expected = one.sql(QUERIES[1]).rows
+
+    segment, fused = [], []
+    seg, fus = K.segment_sum, K.fused_group_sums
+
+    def segment_spy(x, gid, n_groups):
+        segment.append((x.dtype, x.shape[0]))
+        return seg(x, gid, n_groups)
+
+    def fused_spy(vals, gid, n_groups):
+        fused.append(vals.shape)
+        return fus(vals, gid, n_groups)
+
+    monkeypatch.setattr(K, "segment_sum", segment_spy)
+    monkeypatch.setattr(K, "fused_group_sums", fused_spy)
+    s = presto_tpu.connect(cat)
+    s.set("float32_compute", True)
+    s.set("distributed", True)
+    s.set("mesh_devices", 4)
+    for _ in range(2):  # traced, then replayed from the cached program
+        got = s.sql(QUERIES[1]).rows
+        st = s.history_snapshot()[-1]
+        assert (st.execution_mode, st.fallback_reason) == ("distributed", "")
+        # PARTIAL: sum x 4, partial_sum_double x 3, count(x) x 3, count(*),
+        # all fused; what is unfused is the FINAL node's eight merges
+        # over 4 x 16 gathered rows, under the kernel's gate
+        assert (st.aggs_fused, st.aggs_unfused) == (11, 8)
+        assert len(got) == len(expected) == 4
+        for g, e in zip(got, expected):
+            assert g[:2] == e[:2] and g[-1] == e[-1]
+            assert g[2:-1] == pytest.approx(e[2:-1], rel=1e-6)
+    assert len(fused) == 1 and fused[0][0] == 15
+    shard_rows = fused[0][1]
+    assert shard_rows >= 32_768
+    assert [d for d, n in segment if n == shard_rows
+            and jnp.issubdtype(d, jnp.integer)] == []
+    assert any(n == shard_rows for _, n in segment)  # the `counts` row

@@ -141,6 +141,113 @@ def test_fused_agg_in_query(tpch_catalog_tiny):
         assert abs(a[3] - b[3]) < 1e-9 * abs(b[3])
 
 
+def _spy_fused(monkeypatch):
+    """Record every operand K.fused_group_sums is called with."""
+    operands = []
+    orig = K.fused_group_sums
+
+    def spy(vals, gid, n_groups):
+        operands.append(vals)
+        return orig(vals, gid, n_groups)
+
+    monkeypatch.setattr(K, "fused_group_sums", spy)
+    return operands
+
+
+#: case -> (aggregates beside the group key, how many they are, rows the
+#: fused operand holds).
+#: count(x) reads only x's validity, so it rides the fused pass whatever
+#: x's type; beside sum/avg of the same (x, filter) it IS their count row.
+COUNT_X_CASES = {
+    "double": ("count(l_quantity), count(*)", 2, 2),
+    "bigint": ("count(l_orderkey), count(*)", 2, 2),
+    "null_arg": ("count(CASE WHEN l_discount > 0.05 THEN l_quantity END), "
+                 "count(*)", 2, 2),
+    "filter": ("count(l_quantity) FILTER (WHERE l_shipdate > "
+               "DATE '1995-01-01'), count(*)", 2, 2),
+    # avg's rows, sum's rows; the count reuses avg's count row
+    "beside_avg_sum": ("count(l_quantity), avg(l_quantity), "
+                       "sum(l_quantity)", 3, 4),
+    # the filter is part of the row's identity: no reuse across filters
+    "other_filter": ("count(l_quantity) FILTER (WHERE l_tax > 0.02), "
+                     "avg(l_quantity)", 2, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COUNT_X_CASES))
+def test_count_of_argument_rides_the_fused_pass(tpch_catalog_tiny,
+                                                monkeypatch, case):
+    import presto_tpu
+
+    aggs, n_aggs, operand_rows = COUNT_X_CASES[case]
+    sql = (f"SELECT l_returnflag, {aggs} FROM lineitem "
+           "GROUP BY l_returnflag ORDER BY 1")
+    off = presto_tpu.connect(tpch_catalog_tiny)
+    off.set("float32_compute", True)
+    off.set("pallas_fused_agg", False)
+    expected = off.sql(sql).rows
+    assert off.history_snapshot()[-1].aggs_fused == 0
+    on = presto_tpu.connect(tpch_catalog_tiny)
+    on.set("float32_compute", True)
+    operands = _spy_fused(monkeypatch)
+    got = on.sql(sql).rows
+    assert len(got) == len(expected) == 3
+    for g, e in zip(got, expected):
+        for x, y in zip(g, e):
+            if isinstance(y, float):
+                assert x == pytest.approx(y, rel=1e-9)
+            else:
+                assert x == y and type(x) is type(y)
+    assert [o.shape[0] for o in operands] == [operand_rows]
+    st = on.history_snapshot()[-1]
+    assert (st.aggs_fused, st.aggs_unfused) == (n_aggs, 0)
+
+
+def test_single_step_q1_operand_did_not_move(tpch_catalog_tiny, monkeypatch):
+    """One chip: Q1 is a SINGLE aggregate, avg is fused whole and no
+    count(x) exists — one fused call over (15, rows), as before
+    count(x) became fusable."""
+    import presto_tpu
+    from presto_tpu.exec import compile_cache as CC
+    from tests.tpch_queries import QUERIES
+
+    s = presto_tpu.connect(tpch_catalog_tiny, execution_mode="compiled")
+    s.set("float32_compute", True)
+    CC.clear()  # traced here, not taken from the process-wide memo
+    operands = _spy_fused(monkeypatch)
+    s.sql(QUERIES[1])
+    st = s.history_snapshot()[-1]
+    assert st.execution_mode == "compiled"
+    assert [o.shape[0] for o in operands] == [15]
+    assert operands[0].shape[1] >= 32_768
+    assert (st.aggs_fused, st.aggs_unfused) == (8, 0)
+
+
+def test_operand_rows_without_count_of_argument_keep_their_order(
+        tpch_catalog_tiny, monkeypatch):
+    """Without a count(x) the operand is what it was: one row a
+    count(*)/count_if, a value row and a count row a sum/avg, in the
+    aggregates' order.  Each row's total names it."""
+    import presto_tpu
+
+    aggs = ("count(*), sum(l_extendedprice), count_if(l_discount > 0.05), "
+            "avg(l_quantity), sum(l_tax) FILTER (WHERE l_quantity < 10)")
+    s = presto_tpu.connect(tpch_catalog_tiny, execution_mode="dynamic")
+    s.set("float32_compute", True)
+    operands = _spy_fused(monkeypatch)
+    s.sql(f"SELECT l_returnflag, {aggs} FROM lineitem GROUP BY l_returnflag")
+    assert len(operands) == 1 and operands[0].shape[0] == 8
+    totals = np.asarray(operands[0], dtype=np.float64).sum(axis=1)
+    off = presto_tpu.connect(tpch_catalog_tiny)
+    off.set("pallas_fused_agg", False)
+    n, ext, n_disc, qty, tax, n_small = off.sql(
+        "SELECT count(*), sum(l_extendedprice), count_if(l_discount > 0.05), "
+        "sum(l_quantity), sum(l_tax) FILTER (WHERE l_quantity < 10), "
+        "count(*) FILTER (WHERE l_quantity < 10) FROM lineitem").rows[0]
+    np.testing.assert_allclose(
+        totals, [n, ext, n, n_disc, qty, n, tax, n_small], rtol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # gather-aware tier (exec/gather.py): sort-order staging must be
 # BYTE-IDENTICAL to the flat packed gather
