@@ -255,23 +255,9 @@ class TpchTable(ConnectorTable):
 
         if not all(self.device_generable(c) for c in columns):
             return None
-        from presto_tpu.exec import compile_cache as CC
-
-        key = (tuple(sorted(columns)), f32)
-        cache = getattr(self, "_device_gen_jit", None)
-        if cache is None:
-            cache = self._device_gen_jit = {}
-        fn = cache.get(key)
-        if fn is None:
-            cols = list(key[0])
-
-            def gen():
-                return D.generate_device(self.name, self.sf, cols, f32=f32)
-
-            # zero-arg AOT: the generator compile is part of a query's
-            # cold cost and belongs in its compile-economics counters
-            fn = cache[key] = CC.build_jit(gen, example=())
-        return fn()
+        return _generated_on_device(
+            self, columns, f32,
+            lambda cols: D.generate_device(self.name, self.sf, cols, f32=f32))
 
     def device_generable(self, column: str) -> bool:
         from presto_tpu.connectors import tpch_device as D
@@ -312,6 +298,25 @@ class TpchTable(ConnectorTable):
                     with open(path, "wb") as f:
                         pickle.dump(self._data, f, protocol=4)
         return self._data
+
+
+def _generated_on_device(table, columns, f32, generate):
+    """`generate(sorted columns)` -> {column: Column} as one program per
+    (column set, lane), kept on the table so that a scan after
+    release_device_caches() builds nothing again.  Zero-argument AOT:
+    the generator's compile is part of a query's cold cost and belongs
+    in its compile-economics counters."""
+    from presto_tpu.exec import compile_cache as CC
+
+    key = (tuple(sorted(columns)), f32)
+    cache = table.__dict__.setdefault("_device_gen_jit", {})
+    fn = cache.get(key)
+    if fn is None:
+        def gen():
+            return generate(list(key[0]))
+
+        fn = cache[key] = CC.build_jit(gen, example=())
+    return fn()
 
 
 #: every live catalog, for bulk cache release (the test suite frees
@@ -509,6 +514,26 @@ class TpcdsTable(ConnectorTable):
             return {c: data[c][a:b] for c in cols}
         return {c: data[c] for c in cols}
 
+    def device_columns(self, columns, f32=False):
+        """The whole table's `columns` generated on the device, as
+        TpchTable.device_columns does: no host array of the table's
+        length.  None where the device generator lacks a column (the
+        dimensions: strings), and the caller falls back to read().  See
+        connectors/tpcds_device.py."""
+        from presto_tpu.connectors import tpcds_device as D
+
+        if not all(self.device_generable(c) for c in columns):
+            return None
+        return _generated_on_device(
+            self, columns, f32,
+            lambda cols: D.generate_device(self.name, self.sf, cols, 0,
+                                           self.row_count(), f32=f32))
+
+    def device_generable(self, column: str) -> bool:
+        from presto_tpu.connectors import tpcds_device as D
+
+        return D.is_device_generable(self.name, column)
+
     def _full_table(self):
         lock = self.__dict__.setdefault("_mat_lock", threading.Lock())
         with lock:
@@ -541,3 +566,14 @@ def tpcds_catalog(sf: float = 0.01, cache_dir: Optional[str] = None) -> Catalog:
     for name in tpcds_gen.SCHEMAS:
         cat.register(TpcdsTable(name, sf, cache_dir))
     return cat
+
+
+def tpcds_device_catalog(sf: float = 0.01,
+                         cache_dir: Optional[str] = None) -> Catalog:
+    """`tpcds_catalog`, under the name a deployment asks for whose fact
+    tables must be born on the device (benchmarks/configs/
+    tpcds_store.json).  A program from before TpcdsTable.device_columns
+    lacks the name and stops there, where under the old name it would
+    generate all 23 columns of store_sales on the host (5 GB at sf10)
+    and then meet programs it cannot compile."""
+    return tpcds_catalog(sf, cache_dir)

@@ -1398,6 +1398,32 @@ MAX_ROWS_PER_KEY = {
 }
 
 
+#: VARCHAR columns the generators above draw from a fixed vocabulary ->
+#: its size: the column's ndv at every scale (column_stats)
+_VOCABULARY = {
+    "cd_gender": len(GENDERS), "cd_marital_status": len(MARITAL),
+    "cd_education_status": len(EDUCATION), "cd_credit_rating": len(CREDIT),
+    "hd_buy_potential": len(BUY_POTENTIAL),
+    "i_category": len(CATEGORIES), "i_class": len(CLASSES),
+    "i_size": 7, "i_units": len(UNITS), "i_container": 1,
+    "i_color": len(COLORS), "i_item_desc": len(COLORS),
+    "s_store_name": 9, "s_hours": 3, "s_city": 6, "s_county": 1,
+    "s_state": 9, "s_country": 1, "s_company_name": 1,
+    "s_division_name": 1, "s_geography_class": 1,
+    "s_manager": len(FIRST_NAMES), "s_market_manager": len(FIRST_NAMES),
+    "s_street_name": len(STREET_NAMES), "s_street_type": len(STREET_TYPES),
+    "d_day_name": 7, "d_holiday": 2, "d_weekend": 2,
+    "d_following_holiday": 2, "d_current_day": 1, "d_current_week": 1,
+    "d_current_month": 1, "d_current_quarter": 1, "d_current_year": 1,
+    "c_salutation": len(SALUTATIONS), "c_first_name": len(FIRST_NAMES),
+    "c_last_name": len(LAST_NAMES), "c_preferred_cust_flag": 2,
+    "c_birth_country": 1, "c_login": 1,
+    "ca_street_name": len(STREET_NAMES), "ca_street_type": len(STREET_TYPES),
+    "ca_city": len(CITIES), "ca_county": 15, "ca_state": len(STATES),
+    "ca_country": 1, "ca_location_type": 3,
+}
+
+
 def _fk_targets(sf: float):
     """FK column suffix -> (lo, hi) of the referenced key range."""
     return {
@@ -1517,8 +1543,12 @@ def column_stats(table: str, column: str, sf: float, ColStats):
         return ColStats(min=0.0, max=200.0, ndv=20001)
     typ = SCHEMAS[table].get(column)
     if typ is not None and typ.name == "VARCHAR":
-        # string ndvs: enum picks are tiny, ids/names scale with rows
-        return ColStats(ndv=min(rows, 100_000))
+        # string ndvs: a pick from a vocabulary has at most its size (a
+        # filter on a dimension's flag or class keeps 1 row in 2..15, not
+        # 1 in 100,000: a star join's survivors are sized from this);
+        # ids and names have at most one value a row.  Never an
+        # undershoot: group capacities are sized from ndv
+        return ColStats(ndv=min(rows, _VOCABULARY.get(column, rows)))
     return ColStats()
 
 

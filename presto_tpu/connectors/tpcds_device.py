@@ -438,6 +438,10 @@ _GENERATORS = {
 DEVICE_COLUMNS = {t: set(DS.SCHEMAS[t]) for t in _GENERATORS}
 
 
+def is_device_generable(table: str, col: str) -> bool:
+    return col in DEVICE_COLUMNS.get(table, ())
+
+
 def generate_device(table: str, sf: float, cols: List[str], row0,
                     pad: int, f32: bool = False) -> Dict[str, Column]:
     """Generate `cols` of `table` rows [row0, row0+pad) on device.

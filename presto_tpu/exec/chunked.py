@@ -219,6 +219,7 @@ def run_chunked(session, stmt, text: str, mon=None):
 
     runner = _FragmentRunner(session, f32, table_family, grids, {},
                              bucketed=bucketed)
+    runner.sort_stats["grouping_set_branches"] = plan.grouping_set_branches
     consumer_eid = {}  # producer fid -> eid of the exchange it feeds
     for f in frags:
         for inp in f.inputs:

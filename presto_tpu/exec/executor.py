@@ -61,7 +61,8 @@ def _merge_sort_stats(stats, counts: dict) -> None:
               "spill_partitions", "spill_bytes", "spill_restores",
               "spill_recursions",
               "partial_aggs_bypassed", "partial_aggs_reenabled",
-              "aggs_fused", "aggs_unfused"):
+              "aggs_fused", "aggs_unfused",
+              "window_functions", "grouping_set_branches"):
         setattr(stats, k, getattr(stats, k, 0) + int(counts.get(k, 0)))
     if counts.get("partial_agg_ratio"):
         # a gauge, not a sum: the last ratio a partial stage observed
@@ -599,13 +600,18 @@ def query_cache_key(session, text: str) -> tuple:
             _volatile_nonce(text))
 
 
-def _program_tag(plan_fp: Optional[str], batch: int = 0) -> Optional[str]:
+def _program_tag(plan_fp: Optional[str], plan: P.QueryPlan,
+                 batch: int = 0) -> Optional[str]:
     """What tells this program's HLO module from another query's in a
     profile (compile_cache.build_jit): the head of the plan's fingerprint,
-    the same in every process, and the batch width of a coalesced one."""
+    the same in every process, the marks of the scopes younger than
+    SCOPE_VERSION that the plan opens (observe/names.LATE_SCOPES), and
+    the batch width of a coalesced one."""
     if plan_fp is None:
         return None
-    return plan_fp[:8] + (f"b{batch}" if batch else "")
+    marks = "".join(NM.late_scope_marks(n)
+                    for n in [plan.root, *plan.subplans.values()])
+    return plan_fp[:8] + marks + (f"b{batch}" if batch else "")
 
 
 def bind_param_values(session, params):
@@ -657,7 +663,8 @@ def run_compiled(session, text: str, stmt, mon=None, params=None) -> QueryResult
             return Executor(session, monitor=mon, params=host_params).run(plan)
         # uncorrelated scalar subqueries: evaluate eagerly (tiny), bake in;
         # populate ctx as we go — later subplans may reference earlier ones
-        sort_counts = {}  # trace-time sort routing decisions
+        # trace-time sort routing decisions, and what the planner counted
+        sort_counts = {"grouping_set_branches": plan.grouping_set_branches}
         ex0 = Executor(session, sort_stats=sort_counts)
         scalar_results = ex0.ctx.scalar_results
         for pid, sub in sorted(plan.subplans.items()):
@@ -730,13 +737,13 @@ def run_compiled(session, text: str, stmt, mon=None, params=None) -> QueryResult
                     return trace(batches, None)
 
                 jitted = CC.build_jit(fn, example=(batches,),
-                                       tag=_program_tag(plan_fp))
+                                       tag=_program_tag(plan_fp, plan))
             else:
                 def fn(batches, pvals):
                     return trace(batches, pvals)
 
                 jitted = CC.build_jit(fn, example=(batches, pvals),
-                                       tag=_program_tag(plan_fp))
+                                       tag=_program_tag(plan_fp, plan))
             return (plan, jitted, scan_nodes, meta_box[0],
                     dict(sort_counts))
 
@@ -899,7 +906,7 @@ def run_compiled_batched(session, text: str, stmt, params_list,
 
             try:
                 jitted = CC.build_jit(fn, example=(batches, stacked),
-                                       tag=_program_tag(plan_fp, bpad))
+                                       tag=_program_tag(plan_fp, plan, bpad))
             except Unbatchable:
                 raise
             except (StaticFallback, jax.errors.ConcretizationTypeError,
@@ -1580,6 +1587,7 @@ class Executor:
     def run(self, plan: P.QueryPlan) -> QueryResult:
         if self.monitor is not None:
             self.monitor.plan = plan  # rendered at finish (UI plan pane)
+        self._count("grouping_set_branches", plan.grouping_set_branches)
         try:
             batch = self.evaluate(plan)
             return self.materialize(plan, batch)
@@ -3350,7 +3358,11 @@ class Executor:
         if not self.static or est is None or b.capacity < (1 << 19):
             return b
         bound = 1 << max(int(np.ceil(np.log2(max(est, 1) * 2))), 14)
-        if bound > min(b.capacity // 4, 1 << 20):
+        # the top_k costs one sort of the capacity whatever the bound
+        # (77.5 ms at 28.8 M slots for 2^20 and for 2^21: PERF.md section
+        # 6, PR 34), the gather grows with the bound: up to 2^20 rows a
+        # quarter of the capacity is worth it, beyond that an eighth
+        if bound > min(b.capacity // 4, max(1 << 20, b.capacity // 8)):
             return b
         self.guards.append(jnp.sum(b.sel.astype(jnp.int32)) > bound)
         out = _compact_batch(b, bound)
@@ -3517,7 +3529,7 @@ class Executor:
             # chunks with a highly selective semi-join upstream lose
             # ~7%).  Gate to probes not much wider than the build.
             use_index = lkeys[0].data.shape[0] <= 2 * right.capacity
-        index_ridx = None
+        index_ridx = index_rows = None
         if il is not None and os.environ.get("PRESTO_TPU_DEBUG_INDEX"):
             import sys as _sys
 
@@ -3555,8 +3567,23 @@ class Executor:
                 in_slot = jnp.ones_like(off, bool)
             pos = jnp.clip(pos_raw, 0, nrows - 1).astype(jnp.int32)
             in_range = (off >= 0) & (pos_raw < nrows) & in_slot
-            rkd = jnp.asarray(rkeys[0].data)[pos].astype(jnp.int64)
-            found_idx = lsel & in_range & rsel[pos] & (rkd == lk)
+            if full_build and not strided and rkeys[0].valid is None \
+                    and GA.small_source(nrows, pos.shape[0]):
+                # a star join's probe: the fact table's rows against a
+                # dimension many times smaller.  The layout guard above
+                # has every live build row hold the key of its position,
+                # so a live row at `pos` IS the match: no gather of the
+                # build key, and the build's sel comes in the one packed
+                # gather that brings the row's columns (three one-word
+                # gathers and a staged one cost 1.2 s a join at 28.8 M
+                # rows, this one 0.2: PERF.md section 6, PR 34)
+                index_rows = K.gather_batch(
+                    right if jt in ("INNER", "LEFT")
+                    else Batch({}, right.sel), pos)
+                found_idx = lsel & in_range & index_rows.sel
+            else:
+                rkd = jnp.asarray(rkeys[0].data)[pos].astype(jnp.int64)
+                found_idx = lsel & in_range & rsel[pos] & (rkd == lk)
             counts = found_idx.astype(jnp.int32)
             index_ridx = pos
         elif self.static:
@@ -3676,7 +3703,15 @@ class Executor:
             else:
                 match_pos = jnp.clip(lb, 0, max(order.shape[0] - 1, 0))
                 ridx = order[match_pos]
-            rbatch = K.gather_batch(right, ridx, idx_valid=found)
+            if index_rows is not None:
+                # gathered with the probe: only the validity is left
+                rbatch = Batch(
+                    {n: Column(c.data, found if c.valid is None
+                               else (c.valid & found), c.type, c.dictionary)
+                     for n, c in index_rows.columns.items()},
+                    index_rows.sel & found)
+            else:
+                rbatch = K.gather_batch(right, ridx, idx_valid=found)
             merged = dict(left.columns)
             merged.update(rbatch.columns)
             if node.filter is not None:

@@ -61,6 +61,27 @@ _STAGED_MIN_INDICES = 1 << 20
 _STAGED_MIN_WORDS = 2
 
 
+# A fact table's rows gathering from a dimension many times smaller (a
+# star join's probe): sorting the indices buys nothing, the source being
+# small wherever the index points, and past this many indices the packed
+# result cannot even be laid out — a (m, w) u32 matrix takes m x 512
+# bytes in the TPU's 128-lane tiles whatever w is (13.7 GB at 28.8 M rows:
+# the compiler refused TPC-DS q36).  Measured on a v5e at 28.8 M indices
+# into 180,000 x 5 words (PERF.md section 6, PR 34): staged 495 ms, the
+# packed gather in blocks 198 ms, ascending indices 198 ms as well, five
+# one-word gathers 1838 ms.  No program of fewer indices changes.
+_SMALL_SOURCE_MIN_INDICES = 1 << 23
+_SMALL_SOURCE_RATIO = 8
+#: indices a block of the packed gather holds (2 GB of padded rows)
+PACKED_BLOCK = 1 << 22
+
+
+def small_source(n: int, m: int) -> bool:
+    """An m-index gather from n rows is a star join's: many indices, a
+    source at least _SMALL_SOURCE_RATIO times smaller."""
+    return m > _SMALL_SOURCE_MIN_INDICES and m >= _SMALL_SOURCE_RATIO * n
+
+
 def _staging_enabled() -> bool:
     """PRESTO_TPU_GATHER: '' (auto: staged on TPU, the platform the
     routing constants were modelled for, flat elsewhere) | 'flat'
@@ -83,8 +104,9 @@ def gather_route(n: int, m: int, words: int,
 
     presorted indices skip the sort AND the unpermute, so staging wins
     at any width; request-order gathers must clear _STAGED_MIN_WORDS to
-    amortize the co-sort home."""
-    if not _staging_enabled():
+    amortize the co-sort home.  A small source under many indices
+    (`small_source`) is gathered flat, in blocks."""
+    if not _staging_enabled() or small_source(n, m):
         return "flat"
     if m < _STAGED_MIN_INDICES or n <= 0 or words <= 0:
         return "flat"

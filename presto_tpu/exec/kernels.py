@@ -888,13 +888,31 @@ def take_rows(arrays: List[jnp.ndarray], idx: jnp.ndarray,
     # vs one (8M,2) packed gather 35-50ms on chip), so a single i64
     # column (= 2 u32 words) already wins
     with NM.kernel_scope("k:take_rows.flat"):
-        if len(words) >= 2 and idx.shape[0] >= 65536:
+        if len(words) >= 2 and idx.shape[0] > 2 * G.PACKED_BLOCK:
+            taken = _packed_gather_in_blocks(words, idx)
+            col = lambda k: taken[k]
+        elif len(words) >= 2 and idx.shape[0] >= 65536:
             packed = jnp.stack(words, axis=1)[idx]
             col = lambda k: packed[:, k]
         else:
             taken = [w[idx] for w in words]
             col = lambda k: taken[k]
         return _rebuild_taken(arrays, idx, spec, col, out)
+
+
+def _packed_gather_in_blocks(words: List[jnp.ndarray],
+                             idx: jnp.ndarray) -> List[jnp.ndarray]:
+    """`jnp.stack(words, 1)[idx]`, column by column, G.PACKED_BLOCK
+    indices at a time: the gathered (m, w) rows exist one block at a
+    time (the TPU pads each to 128 lanes: exec/gather.py), the columns
+    come out one-dimensional."""
+    mat = jnp.stack(words, axis=1)
+    m, block = idx.shape[0], G.PACKED_BLOCK
+    nblocks = -(-m // block)
+    blocks = jnp.pad(idx, (0, nblocks * block - m)).reshape(nblocks, block)
+    cols = jax.lax.map(
+        lambda b: tuple(mat[b][:, k] for k in range(len(words))), blocks)
+    return [c.reshape(-1)[:m] for c in cols]
 
 
 @NM.scoped("k:take_rows.staged")

@@ -35,6 +35,7 @@ import numpy as np
 from presto_tpu import types as T
 from presto_tpu.batch import Batch, Column
 from presto_tpu.exec import kernels as K
+from presto_tpu.observe import names as NM
 from presto_tpu.plan import ir
 from presto_tpu.plan import nodes as P
 
@@ -45,6 +46,12 @@ class WindowError(Exception):
 
 def execute_window(ex, node: P.Window) -> Batch:
     b = ex.exec_node(node.source)
+    ex._count("window_functions", len(node.functions))
+    with NM.kernel_scope("k:window"):
+        return _windowed(ex, node, b)
+
+
+def _windowed(ex, node: P.Window, b: Batch) -> Batch:
     if not ex.static:
         b = K.compact(b)
     n = b.capacity

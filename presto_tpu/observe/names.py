@@ -33,6 +33,27 @@ SPAN_PREFIX = "presto:"
 #: is opened: one vocabulary, one cache key.
 SCOPE_VERSION = 2
 
+#: Scopes that arrived after SCOPE_VERSION's last bump, by the plan node
+#: under which alone they open -> the mark that the name of a program
+#: holding such a node carries behind the plan's fingerprint
+#: (`late_scope_marks`).  A bump would change every program's cache key and
+#: make every deployment compile everything anew; a mark changes the keys
+#: of the programs that open the new scope and of no other.  The next bump
+#: of SCOPE_VERSION empties this table.
+LATE_SCOPES: Dict[str, str] = {"Window": "w1"}     # k:window
+
+
+def late_scope_marks(node) -> str:
+    """The marks of LATE_SCOPES for the plan under `node`, in the table's
+    order; "" for a plan that holds none of its nodes."""
+    seen, stack = set(), [node]
+    while stack:
+        n = stack.pop()
+        seen.add(type(n).__name__)
+        stack.extend(n.sources)
+    return "".join(m for name, m in LATE_SCOPES.items() if name in seen)
+
+
 #: span name -> (layer, site).  Layers are BENCHMARK.json's.
 SPANS: Dict[str, tuple] = {
     "client.post": ("client and protocol",
@@ -99,6 +120,7 @@ KERNEL_SCOPES: Dict[str, str] = {
     "k:fused_group_sums.operand": "the stack and pad that build fused_group_sums' operand",
     "k:compact": "executor._compact_batch and kernels.compact: live rows to the front",
     "k:scan_filter": "executor._exec_filter: the predicate over a scan's rows, into the selection mask",
+    "k:window": "window.execute_window: partition and peer boundaries, frame bounds, the functions' prefix and segmented scans, the gather into sorted order (its sort stays k:sort's)",
     "x:repartition": "parallel/exchange.repartition_batch: one sort by destination (key hash) that carries the columns, the send buffer as contiguous slices, all_to_all",
     "x:all_gather": "parallel/exchange.all_gather_batch: a shard's rows on every shard",
     "x:range_partition": "parallel/exchange.range_partition_batch: sample sort's split, the same send layout by (destination, key), all_to_all",

@@ -212,6 +212,13 @@ class QueryStats:
     # reads all of its aggregates unfused.
     aggs_fused: int = 0
     aggs_unfused: int = 0
+    # window_functions: window function calls the program's Window nodes
+    # computed (exec/window.execute_window; trace time, replayed like
+    # aggs_fused).  grouping_set_branches: sub-queries the planner made of
+    # GROUPING SETS / ROLLUP / CUBE, one a grouping set, each with its own
+    # copy of the FROM clause's joins (QueryPlan.grouping_set_branches).
+    window_functions: int = 0
+    grouping_set_branches: int = 0
     result_cache_hit: int = 0
     resource_group: str = ""
     admission_wait_ms: float = 0.0

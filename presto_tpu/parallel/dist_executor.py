@@ -292,6 +292,7 @@ def _build(session, stmt, ndev):
         # into the query's stats by every run
         counters.clear()
         counters.update(stats)
+        counters["grouping_set_branches"] = plan.grouping_set_branches
         return out, g
 
     sharded = _shard_mapped(fn, mesh, (PS(AXIS),), PS())
@@ -305,7 +306,7 @@ def _build(session, stmt, ndev):
         (dplan.root, sorted(dplan.subplans.items())))
     jitted = CC.build_jit(sharded,
                           example=(_feed(session, scan_nodes, mesh, ndev),),
-                          tag=X._program_tag(plan_fp))
+                          tag=X._program_tag(plan_fp, dplan))
     return dplan, jitted, scan_nodes, mesh, counters
 
 
@@ -372,7 +373,9 @@ def sharded_scan(table, node: P.TableScan, mesh, ndev: int,
     spec = NamedSharding(mesh, PS(AXIS))
     needed = list(dict.fromkeys(node.assignments.values()))
     missing = [c for c in needed if c not in cache_for(c)]
-    born = [c for c in missing if hasattr(table, "device_generable")
+    # born sharded needs the table's own cut (`shard_grid`); a table that
+    # generates whole columns only (TPC-DS) is host-read and laid out
+    born = [c for c in missing if hasattr(table, "shard_grid")
             and table.device_generable(c)]
     sel_key = "__sel__"
     if born:

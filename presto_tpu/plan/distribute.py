@@ -78,7 +78,7 @@ def distribute(plan: P.QueryPlan, session, ndev: int,
     root = IterativeOptimizer(
         [PushPartialAggregationThroughExchange(session)]).optimize(root)
     out = P.Output(root, plan.root.names, plan.root.symbols)
-    dplan = P.QueryPlan(out, subplans)
+    dplan = P.QueryPlan(out, subplans, plan.grouping_set_branches)
     # fragment-fusion economics (plan/fusion_cost.py): stamp every
     # Exchange node with stats-derived est_rows/est_bytes hints so the
     # coordinator's per-edge fuse-vs-cut pricing (and anything reading

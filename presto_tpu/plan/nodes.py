@@ -357,6 +357,10 @@ class QueryPlan:
 
     root: Output
     subplans: Dict[int, PlanNode] = field(default_factory=dict)
+    #: sub-queries the planner made of GROUPING SETS / ROLLUP / CUBE (one
+    #: per grouping set, UNION ALLed: Planner._expand_grouping_sets);
+    #: QueryStats.grouping_set_branches
+    grouping_set_branches: int = 0
 
 
 def plan_tree_str(node: PlanNode, indent: int = 0, annotate=None) -> str:

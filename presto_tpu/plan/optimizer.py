@@ -41,7 +41,7 @@ def optimize(plan: P.QueryPlan, session) -> P.QueryPlan:
             CC._note("approx_rewrites", n)
     subplans = {k: _optimize_node(v, session) for k, v in plan.subplans.items()}
     new_root = _optimize_node(root, session)
-    out = P.QueryPlan(new_root, subplans)
+    out = P.QueryPlan(new_root, subplans, plan.grouping_set_branches)
     annotate_static_hints(out, session)
     if session.properties.get("prune_fd_group_keys", False):
         # OFF by default: measured on chip (SF1 Q3 517->607ms, Q18

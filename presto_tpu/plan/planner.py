@@ -90,13 +90,15 @@ class Planner:
         # _try_subquery_conjunct's general correlated form)
         self._scalar_sub_overrides: Dict[int, ir.RowExpr] = {}
         self._mark_overrides: Dict[int, str] = {}  # Exists/In -> mark sym
+        self.grouping_set_branches = 0  # -> QueryPlan, QueryStats
 
     # ------------------------------------------------------------------
     def plan_statement(self, stmt: ast.Statement) -> P.QueryPlan:
         if isinstance(stmt, ast.QueryStatement):
             node, scope, names = self.plan_query(stmt.query)
             out = P.Output(node, names, [f.symbol for f in scope.fields])
-            return P.QueryPlan(out, self.subplans)
+            return P.QueryPlan(out, self.subplans,
+                               self.grouping_set_branches)
         raise SemanticError(f"unsupported statement: {type(stmt).__name__}")
 
     # ------------------------------------------------------------------
@@ -114,7 +116,7 @@ class Planner:
         tf = P.TableFinish(source=tw)
         out = P.Output(source=tf, names=["rows"],
                        symbols=[tw.rows_symbol])
-        return P.QueryPlan(root=out, subplans=inner.subplans)
+        return P.QueryPlan(out, inner.subplans, inner.grouping_set_branches)
 
     # ------------------------------------------------------------------
     def plan_query(self, q: ast.Query, outer: Optional[Scope] = None):
@@ -243,6 +245,7 @@ class Planner:
             branches.append(ast.QuerySpec(
                 items, spec.distinct, spec.from_, spec.where, list(s),
                 spec.having))
+        self.grouping_set_branches += len(branches)
         body = branches[0]
         for b in branches[1:]:
             body = ast.SetOp("UNION", True, body, b)
