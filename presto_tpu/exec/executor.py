@@ -62,7 +62,8 @@ def _merge_sort_stats(stats, counts: dict) -> None:
               "spill_recursions",
               "partial_aggs_bypassed", "partial_aggs_reenabled",
               "aggs_fused", "aggs_unfused",
-              "window_functions", "grouping_set_branches"):
+              "window_functions", "grouping_set_branches",
+              "grouping_set_sources"):
         setattr(stats, k, getattr(stats, k, 0) + int(counts.get(k, 0)))
     if counts.get("partial_agg_ratio"):
         # a gauge, not a sum: the last ratio a partial stage observed
@@ -1309,15 +1310,16 @@ class Executor:
             prev = flags.get(id(node))
             flags[id(node)] = flag if prev is None else (prev and flag)
             t = type(node).__name__
-            if t == "Aggregate":
+            if t in ("Aggregate", "GroupingSets"):
                 # an ordering-exploiting aggregate (presorted grouping
                 # hint) WANTS its input order: sort-order-materializing
                 # joins below it would scramble the claimed ordering and
                 # trade the elided grouping sort for a guard trip
+                hints = node.hints if t == "GroupingSets" else [vars(node)]
                 walk(node.source, not any(
                     a.fn in self._ORDER_SENSITIVE_AGGS
                     for a in node.aggs.values())
-                    and getattr(node, "ordering_hint", None) is None)
+                    and all(h.get("ordering_hint") is None for h in hints))
             elif t in ("Filter", "Project", "Output"):
                 # row-wise: input permutation = same output permutation
                 walk(node.source, flag)
@@ -1800,9 +1802,41 @@ class Executor:
 
     # ---- aggregation -------------------------------------------------
     def _exec_aggregate(self, node: P.Aggregate) -> Batch:
+        return self._aggregate_batch(node, self.exec_node(node.source))
+
+    def _exec_groupingsets(self, node: P.GroupingSets) -> Batch:
+        """The source ONCE; then each grouping set is the Aggregate of its
+        keys over that one batch, with NULL columns for the keys the set
+        leaves out and its index as the group id; the sets' rows are
+        concatenated as a UNION ALL's are."""
+        b = self.exec_node(node.source)
+        self._count("grouping_set_sources")
+        if node.hints:
+            # every set starts from the same input estimate: compact once
+            b = self._maybe_compact_static(
+                b, node.hints[0].get("input_est_hint"))
+        outs = []
+        for i, keys in enumerate(node.sets):
+            with jax.named_scope("Aggregate"):
+                out = self._aggregate_batch(node.set_aggregate(i), b)
+            n = out.capacity
+            cols = {}
+            for k in node.group_keys:
+                c = b.columns[k]    # left out: NULLs of the key's own kind
+                cols[k] = out.columns[k] if k in keys else Column(
+                    jnp.zeros((n,) + c.data.shape[1:], c.data.dtype),
+                    jnp.zeros((n,), bool), c.type, c.dictionary)
+            for s in node.aggs:
+                cols[s] = out.columns[s]
+            cols[node.group_id] = Column(jnp.full((n,), i, jnp.int32), None,
+                                         T.INTEGER)
+            outs.append(Batch(cols, out.sel))
+        return K.concat_batches(outs)
+
+    def _aggregate_batch(self, node: P.Aggregate, b: Batch) -> Batch:
+        """`node` over `b`, its source's rows."""
         from presto_tpu.memory.context import batch_bytes
 
-        b = self.exec_node(node.source)
         strat = getattr(node, "agg_strategy", None)
         if strat and node.group_keys and node.step != "FINAL":
             # planner strategy counter (plan/agg_strategy.py) — counted

@@ -214,11 +214,16 @@ class QueryStats:
     aggs_unfused: int = 0
     # window_functions: window function calls the program's Window nodes
     # computed (exec/window.execute_window; trace time, replayed like
-    # aggs_fused).  grouping_set_branches: sub-queries the planner made of
-    # GROUPING SETS / ROLLUP / CUBE, one a grouping set, each with its own
-    # copy of the FROM clause's joins (QueryPlan.grouping_set_branches).
+    # aggs_fused).  grouping_set_branches: grouping sets the plan's
+    # GroupingSets nodes aggregate (QueryPlan.grouping_set_branches: 3 a
+    # ROLLUP (a, b)).  grouping_set_sources: how many times a program
+    # lowered such a node's source (Executor._exec_groupingsets; trace
+    # time, replayed): one a node, where the planner's former UNION ALL of
+    # sub-queries ran it once a set.  1 - sources / branches is the share
+    # of source runs saved.
     window_functions: int = 0
     grouping_set_branches: int = 0
+    grouping_set_sources: int = 0
     result_cache_hit: int = 0
     resource_group: str = ""
     admission_wait_ms: float = 0.0

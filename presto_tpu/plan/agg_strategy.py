@@ -155,8 +155,13 @@ def annotate(plan: P.QueryPlan, session) -> None:
         seen.add(id(node))
         for s in node.sources:
             walk(s)
-        if isinstance(node, P.Aggregate) and node.group_keys \
-                and node.step == "SINGLE":
+        if isinstance(node, P.GroupingSets):
+            node.annotate_sets(stamp)
+        elif isinstance(node, P.Aggregate):
+            stamp(node)
+
+    def stamp(node):
+        if node.group_keys and node.step == "SINGLE":
             node.agg_strategy = choose(node, session)
 
     walk(plan.root)

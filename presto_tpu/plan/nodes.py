@@ -99,6 +99,58 @@ class Aggregate(PlanNode):
 
 
 @dataclass
+class GroupingSets(PlanNode):
+    """GROUP BY GROUPING SETS / ROLLUP / CUBE: ONE source aggregated once
+    per grouping set, the sets' rows concatenated (reference: GroupIdNode
+    under an AggregationNode; here one node, because the executor
+    aggregates the one source batch per set and never multiplies its
+    rows).  Outputs every key (NULL in the rows of a set that leaves the
+    key out), every aggregate, and `group_id`, the index of the row's set
+    in `sets`: grouping(...) is an expression over it."""
+
+    source: PlanNode
+    group_keys: List[str] = field(default_factory=list)  # union over the sets
+    sets: List[List[str]] = field(default_factory=list)  # subsets of group_keys
+    aggs: Dict[str, AggCall] = field(default_factory=dict)  # same for every set
+    group_id: str = ""
+    #: per set, what the optimizer's annotators attach to an Aggregate
+    #: of that set's keys (capacity_hint, key_stats, input_est_hint,
+    #: ordering_hint..., agg_strategy): annotate_sets fills it
+    hints: List[dict] = field(default_factory=list)
+
+    def outputs(self):
+        from presto_tpu.types import INTEGER
+
+        src_types = self.source.output_types()
+        out = [(k, src_types[k]) for k in self.group_keys]
+        out += [(s, a.type) for s, a in self.aggs.items()]
+        return out + [(self.group_id, INTEGER)]
+
+    @property
+    def sources(self):
+        return [self.source]
+
+    def set_aggregate(self, i: int) -> Aggregate:
+        """Set i as the Aggregate it is lowered as: the node's source and
+        calls, the set's keys, the hints kept for the set."""
+        agg = Aggregate(self.source, list(self.sets[i]), self.aggs)
+        if i < len(self.hints):
+            vars(agg).update(self.hints[i])
+        return agg
+
+    def annotate_sets(self, annotate) -> None:
+        """Run an Aggregate annotator over every set and keep what it
+        attached, so each set gets the hints its own keys earn."""
+        kept = []
+        for i in range(len(self.sets)):
+            agg = self.set_aggregate(i)
+            annotate(agg)
+            kept.append({k: v for k, v in vars(agg).items()
+                         if k not in Aggregate.__dataclass_fields__})
+        self.hints = kept
+
+
+@dataclass
 class SpatialJoin(PlanNode):
     """Grid-indexed spatial inner join (reference: SpatialJoinOperator +
     PagesRTreeIndex).  TPU-native redesign: instead of a pointer-chasing
@@ -357,9 +409,8 @@ class QueryPlan:
 
     root: Output
     subplans: Dict[int, PlanNode] = field(default_factory=dict)
-    #: sub-queries the planner made of GROUPING SETS / ROLLUP / CUBE (one
-    #: per grouping set, UNION ALLed: Planner._expand_grouping_sets);
-    #: QueryStats.grouping_set_branches
+    #: grouping sets the plan's GroupingSets nodes aggregate (ROLLUP (a, b)
+    #: is 3); QueryStats.grouping_set_branches
     grouping_set_branches: int = 0
 
 
@@ -378,6 +429,9 @@ def plan_tree_str(node: PlanNode, indent: int = 0, annotate=None) -> str:
         detail = " {" + ", ".join(f"{s} := {e}" for s, e in node.assignments.items()) + "}"
     elif isinstance(node, Aggregate):
         detail = (f" {node.step} keys={node.group_keys} "
+                  + "{" + ", ".join(f"{s} := {a}" for s, a in node.aggs.items()) + "}")
+    elif isinstance(node, GroupingSets):
+        detail = (f" sets={node.sets} id={node.group_id} "
                   + "{" + ", ".join(f"{s} := {a}" for s, a in node.aggs.items()) + "}")
     elif isinstance(node, Join):
         detail = f" {node.join_type} {node.criteria}" + (

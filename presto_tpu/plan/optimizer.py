@@ -87,7 +87,7 @@ def _approx_distinct_rewrites(node: P.PlanNode) -> int:
     types rewrite (hll_hash64's domain); everything else keeps the
     exact dedup path."""
     n = 0
-    if isinstance(node, P.Aggregate):
+    if isinstance(node, (P.Aggregate, P.GroupingSets)):
         for s, a in list(node.aggs.items()):
             if a.fn == "count" and a.distinct and len(a.args) == 1:
                 t = a.args[0].type
@@ -174,18 +174,23 @@ def annotate_static_hints(plan: P.QueryPlan, session) -> None:
         return
     memo = {}
 
+    def annotate_aggregate(node):
+        src = S.derive(node.source, catalog, memo)
+        node.capacity_hint = S.capacity_for_groups(node, src)
+        node.key_stats = {k: src.cols.get(k) for k in node.group_keys}
+        # selectivity ESTIMATE of the input (not the sound upper
+        # bound): drives the guarded pre-aggregation compaction
+        # in the static executor
+        node.input_est_hint = int(src.est_rows)
+
     def annotate(node):
         for s in node.sources:
             annotate(s)
         try:
             if isinstance(node, P.Aggregate):
-                src = S.derive(node.source, catalog, memo)
-                node.capacity_hint = S.capacity_for_groups(node, src)
-                node.key_stats = {k: src.cols.get(k) for k in node.group_keys}
-                # selectivity ESTIMATE of the input (not the sound upper
-                # bound): drives the guarded pre-aggregation compaction
-                # in the static executor
-                node.input_est_hint = int(src.est_rows)
+                annotate_aggregate(node)
+            elif isinstance(node, P.GroupingSets):
+                node.annotate_sets(annotate_aggregate)
             elif isinstance(node, P.Join) and node.join_type not in ("CROSS",):
                 ls = S.derive(node.left, catalog, memo)
                 rs = S.derive(node.right, catalog, memo)
@@ -665,16 +670,19 @@ def prune_columns(node: P.PlanNode, required: Set[str]) -> P.PlanNode:
         for e in keep.values():
             need |= e.refs()
         return P.Project(prune_columns(node.source, need), keep)
-    if isinstance(node, P.Aggregate):
+    if isinstance(node, (P.Aggregate, P.GroupingSets)):
         keep_aggs = {s: a for s, a in node.aggs.items() if s in required}
-        need = set(node.group_keys)
+        need = set(node.group_keys)  # a GroupingSets': the union of its sets'
         for a in keep_aggs.values():
             for arg in a.args:
                 need |= arg.refs()
             if a.filter is not None:
                 need |= a.filter.refs()
-        return P.Aggregate(prune_columns(node.source, need), node.group_keys,
-                           keep_aggs, node.step)
+        src = prune_columns(node.source, need)
+        if isinstance(node, P.GroupingSets):
+            return P.GroupingSets(src, node.group_keys, node.sets, keep_aggs,
+                                  node.group_id)
+        return P.Aggregate(src, node.group_keys, keep_aggs, node.step)
     if isinstance(node, P.Join):
         need_l = set()
         need_r = set()

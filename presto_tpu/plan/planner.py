@@ -17,7 +17,6 @@ Subquery handling (reference: SubqueryPlanner + TransformCorrelated* rules):
 from __future__ import annotations
 
 import itertools
-import dataclasses
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -45,6 +44,9 @@ class Field_:
 class Scope:
     fields: List[Field_] = field(default_factory=list)
     parent: Optional["Scope"] = None  # outer query scope (correlation)
+    #: over a P.GroupingSets' outputs: (group-id symbol, per grouping set
+    #: the _ast_key of each key it holds): what grouping(...) reads
+    grouping: Optional[Tuple[str, List[frozenset]]] = None
 
     def resolve(self, parts: Tuple[str, ...]) -> Tuple[Field_, bool]:
         """Returns (field, is_outer)."""
@@ -172,88 +174,7 @@ class Planner:
         return node, lscope, lnames
 
     # ------------------------------------------------------------------
-    def _expand_grouping_sets(self, spec: ast.QuerySpec):
-        """GROUPING SETS/ROLLUP/CUBE -> UNION ALL of per-set aggregations
-        (reference: GroupIdNode + GroupIdOperator, expressed as a set
-        union instead of a group-id column).  Select items that are
-        grouping keys excluded from a set become typed NULLs (UNION
-        coercion settles the type)."""
-        all_keys = set()
-        for s in spec.grouping_sets:
-            for e in s:
-                all_keys.add(_ast_key(e))
-
-        def name_of(item):
-            if item.alias:
-                return item.alias
-            if isinstance(item.expr, ast.Identifier):
-                return item.expr.parts[-1]
-            return None
-
-        def null_out(expr, excluded):
-            """Replace references to rolled-up keys with NULL literals
-            inside arbitrary select expressions (e.g. the lochierarchy
-            CASE of TPC-DS q86 referencing a rolled-up column), and
-            resolve grouping(e1..en) to its per-branch literal bitmask
-            (reference: GroupingOperationRewriter — grouping() is a
-            constant once the grouping set is fixed)."""
-            if isinstance(expr, ast.FunctionCall) \
-                    and expr.name.lower() == "grouping":
-                bits = 0
-                for a in expr.args:
-                    bits = bits * 2 + (1 if _ast_key(a) in excluded else 0)
-                return ast.Literal(bits)
-            if isinstance(expr, ast.Expr) and _ast_key(expr) in excluded:
-                return ast.Literal(None)
-            if isinstance(expr, ast.FunctionCall) \
-                    and agg_fns.is_aggregate(expr.name):
-                return expr  # aggregate args see underlying rows, not NULLs
-            if not isinstance(expr, ast.Node):
-                return expr
-            def sub(v):
-                if isinstance(v, ast.Node):
-                    return null_out(v, excluded)
-                if isinstance(v, (list, tuple)):  # e.g. CASE whens pairs
-                    return type(v)(sub(x) for x in v)
-                return v
-
-            changed = {}
-            for f in dataclasses.fields(expr):
-                v = getattr(expr, f.name)
-                nv = sub(v)
-                if nv is not v and nv != v:
-                    changed[f.name] = nv
-            return dataclasses.replace(expr, **changed) if changed else expr
-
-        branches = []
-        for s in spec.grouping_sets:
-            in_set = {_ast_key(e) for e in s}
-            excluded = all_keys - in_set
-            items = []
-            for item in spec.select:
-                k = _ast_key(item.expr)
-                if k in all_keys and k not in in_set:
-                    items.append(ast.SelectItem(ast.Literal(None),
-                                                name_of(item)))
-                elif k not in all_keys:
-                    # null_out with an empty exclusion set still resolves
-                    # grouping() (all bits 0 in the finest branch)
-                    items.append(ast.SelectItem(
-                        null_out(item.expr, excluded), name_of(item)))
-                else:
-                    items.append(item)
-            branches.append(ast.QuerySpec(
-                items, spec.distinct, spec.from_, spec.where, list(s),
-                spec.having))
-        self.grouping_set_branches += len(branches)
-        body = branches[0]
-        for b in branches[1:]:
-            body = ast.SetOp("UNION", True, body, b)
-        return body
-
     def plan_query_spec(self, spec: ast.QuerySpec, outer):
-        if getattr(spec, "grouping_sets", None):
-            return self._plan_body(self._expand_grouping_sets(spec), outer)
         # FROM
         if spec.from_ is not None:
             node, scope = self.plan_relation(spec.from_, outer)
@@ -271,8 +192,7 @@ class Planner:
         agg_calls: List[Tuple[ast.FunctionCall, str]] = []  # (ast node, out symbol)
         # GROUP BY ordinals resolve to select-list expressions (reference:
         # StatementAnalyzer.analyzeGroupBy ordinal handling)
-        group_by = []
-        for ge in (spec.group_by or []):
+        def group_expr(ge):
             if isinstance(ge, ast.Literal) and isinstance(ge.value, int) \
                     and not isinstance(ge.value, bool):
                 k = ge.value
@@ -280,10 +200,21 @@ class Planner:
                         or isinstance(spec.select[k - 1].expr, ast.Star):
                     raise SemanticError(
                         f"GROUP BY position {k} is not in select list")
-                group_by.append(spec.select[k - 1].expr)
-            else:
-                group_by.append(ge)
-        has_group = bool(group_by)
+                return spec.select[k - 1].expr
+            return ge
+
+        # GROUPING SETS / ROLLUP / CUBE: FROM and WHERE above were planned
+        # once; the keys are the union over the sets and one
+        # P.GroupingSets aggregates that one source per set (reference:
+        # GroupIdNode; QueryPlanner.planGroupingSets)
+        sets = None
+        if getattr(spec, "grouping_sets", None):
+            sets = [[group_expr(ge) for ge in s] for s in spec.grouping_sets]
+            group_by = list({_ast_key(ge): ge
+                             for s in sets for ge in s}.values())
+        else:
+            group_by = [group_expr(ge) for ge in (spec.group_by or [])]
+        has_group = bool(group_by) or sets is not None
         exprs_to_scan = [it.expr for it in spec.select if not isinstance(it.expr, ast.Star)]
         if spec.having is not None:
             exprs_to_scan.append(spec.having)
@@ -294,7 +225,7 @@ class Planner:
         select_scope = scope
         if has_agg:
             node, select_scope, agg_map, group_map = self._plan_aggregation(
-                node, scope, group_by, agg_calls, outer)
+                node, scope, group_by, agg_calls, outer, sets)
         else:
             agg_map, group_map = {}, {}
 
@@ -373,7 +304,18 @@ class Planner:
             if sym is None:
                 if pre is not None:
                     sel_scope, agg_map, group_map = pre
-                    rex = self.analyze(e, sel_scope, agg_map=agg_map, group_map=group_map)
+                    try:
+                        rex = self.analyze(e, sel_scope, agg_map=agg_map,
+                                           group_map=group_map)
+                    except SemanticError:
+                        if not isinstance(node, P.Project):
+                            raise
+                        # a select alias inside a sort expression (TPC-DS
+                        # q36: CASE WHEN lochierarchy = 0 THEN ...): read
+                        # it over the select list's names and inline what
+                        # each stands for
+                        rex = ir.substitute(self.analyze(e, scope),
+                                            node.assignments)
                 else:
                     rex = self.analyze(e, scope)
                 s = self.symbols.new("sortkey")
@@ -385,9 +327,9 @@ class Planner:
                 node = P.Project(node.source,
                                  {**node.assignments, **extra_assignments})
             else:
-                # non-projection source (e.g. the UNION of grouping-set
-                # branches under a computed ORDER BY key, q36/q70):
-                # wrap in an identity projection carrying the sort keys
+                # non-projection source (a set operation under a computed
+                # ORDER BY key): wrap in an identity projection carrying
+                # the sort keys
                 assigns = {f.symbol: ir.Ref(f.symbol, f.type)
                            for f in scope.fields}
                 node = P.Project(node, {**assigns, **extra_assignments})
@@ -999,7 +941,8 @@ class Planner:
             node = P.Window(node, list(part), list(order), fns, frame)
         return node, win_map
 
-    def _plan_aggregation(self, node, scope, group_by, agg_calls, outer):
+    def _plan_aggregation(self, node, scope, group_by, agg_calls, outer,
+                          sets=None):
         pre_assigns = {s: ir.Ref(s, t) for s, t in node.outputs()}
         group_keys: List[str] = []
         group_map: Dict[str, str] = {}  # ast repr of group expr -> symbol
@@ -1095,9 +1038,20 @@ class Planner:
             aggs[s] = ir.AggCall(fc.name.lower(), tuple(arg_refs), rt, fc.distinct, filt)
             agg_map[id(fc)] = (s, rt)
         node = P.Project(node, pre_assigns)
-        node = P.Aggregate(node, group_keys, aggs, "SINGLE")
         post_fields = group_fields + [Field_(None, None, s, a.type) for s, a in aggs.items()]
         post_scope = Scope(post_fields, parent=outer)
+        if sets is None:
+            node = P.Aggregate(node, group_keys, aggs, "SINGLE")
+        else:
+            gid = self.symbols.new("groupid")
+            node = P.GroupingSets(
+                node, group_keys,
+                [list(dict.fromkeys(group_map[_ast_key(ge)] for ge in s))
+                 for s in sets], aggs, gid)
+            self.grouping_set_branches += len(sets)
+            post_fields.append(Field_(None, None, gid, T.INTEGER))
+            post_scope.grouping = (
+                gid, [frozenset(_ast_key(ge) for ge in s) for s in sets])
         return node, post_scope, agg_map, group_map
 
     # ------------------------------------------------------------------
@@ -1229,6 +1183,9 @@ class Planner:
             return ir.CastExpr(v, to, e.safe)
         if isinstance(e, ast.Extract):
             return self._call(f"extract_{e.fld.lower()}", [a(e.value)])
+        if isinstance(e, ast.FunctionCall) and e.name.lower() == "grouping" \
+                and scope.grouping is not None:
+            return self._grouping_call(e, scope)
         if isinstance(e, ast.FunctionCall):
             if agg_fns.is_aggregate(e.name) and e.window is None:
                 raise SemanticError(f"aggregate {e.name} not allowed here")
@@ -1280,6 +1237,26 @@ class Planner:
             raise SemanticError(
                 f"{type(e).__name__} only supported as a top-level WHERE/HAVING conjunct")
         raise SemanticError(f"unsupported expression {type(e).__name__}")
+
+    def _grouping_call(self, e: ast.FunctionCall, scope) -> ir.RowExpr:
+        """grouping(e1..en) over a P.GroupingSets: bit i is set in the
+        rows of a grouping set that leaves e_i out, so it is one literal
+        per set, chosen by the node's group-id column (reference:
+        GroupingOperationRewriter)."""
+        gid, key_sets = scope.grouping
+        keys = [_ast_key(a) for a in e.args]
+        if not keys or any(not any(k in ks for ks in key_sets) for k in keys):
+            raise SemanticError(
+                "grouping() takes one or more of the GROUP BY's keys")
+        bits = [sum((k not in ks) << (len(keys) - 1 - j)
+                    for j, k in enumerate(keys)) for ks in key_sets]
+        args: List[ir.RowExpr] = []
+        for i, b in enumerate(bits[:-1]):
+            args += [self._call("eq", [ir.Ref(gid, T.INTEGER),
+                                       ir.Lit(i, T.INTEGER)]),
+                     ir.Lit(b, T.INTEGER)]
+        args.append(ir.Lit(bits[-1], T.INTEGER))
+        return self._call("case", args) if len(args) > 1 else args[0]
 
     def _analyze_lambda_call(self, e: ast.FunctionCall, scope, agg_map,
                              group_map) -> ir.RowExpr:

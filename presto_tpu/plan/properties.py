@@ -319,6 +319,13 @@ def annotate(plan: P.QueryPlan, session) -> None:
         seen.add(id(node))
         for s in node.sources:
             walk(s)
+        if isinstance(node, P.GroupingSets):
+            # each set is hinted as the Aggregate of its own keys
+            node.annotate_sets(walk_own)
+        else:
+            walk_own(node)
+
+    def walk_own(node):
         if isinstance(node, P.Aggregate) and node.group_keys:
             src = derive(node.source, catalog, memo)
             lead = src.leading

@@ -94,7 +94,8 @@ def _derive(node, catalog, memo) -> NodeStats:
         src = node.source
         while isinstance(src, P.Project):
             src = src.source
-        if isinstance(src, P.Aggregate) and src.group_keys \
+        if isinstance(src, (P.Aggregate, P.GroupingSets)) \
+                and src.group_keys \
                 and _refs_agg_output(node.predicate, src):
             # HAVING-style comparison against an aggregate output:
             # range selectivity is unknowable from column stats, and
@@ -136,6 +137,18 @@ def _derive(node, catalog, memo) -> NodeStats:
         return NodeStats(cap, cols, [keyset] if node.group_keys else [],
                          {keyset: 1} if node.group_keys else {},
                          min(float(cap), s.est_rows))
+    if isinstance(node, P.GroupingSets):
+        # the sets' Aggregates, concatenated; (keys, group id) is unique
+        s = d(node.source)
+        caps = [capacity_for_groups(node.set_aggregate(i), s)
+                for i in range(len(node.sets))]
+        cols = {k: s.cols.get(k, ColStats()) for k in node.group_keys}
+        for sym in node.aggs:
+            cols[sym] = ColStats()
+        cols[node.group_id] = ColStats(0, len(caps) - 1, len(caps))
+        keyset = frozenset(node.group_keys + [node.group_id])
+        return NodeStats(sum(caps), cols, [keyset], {keyset: 1},
+                         sum(min(float(c), s.est_rows) for c in caps))
     if isinstance(node, P.Join):
         ls, rs = d(node.left), d(node.right)
         if node.join_type in ("SEMI", "ANTI"):

@@ -251,8 +251,8 @@ class QuerySpec(Node):
     group_by: List[Expr] = field(default_factory=list)
     having: Optional[Expr] = None
     # GROUPING SETS/ROLLUP/CUBE: list of grouping-key subsets; the
-    # planner expands to a UNION ALL of per-set aggregations
-    # (reference: GroupIdNode + GroupIdOperator)
+    # planner makes one P.GroupingSets node of them over the one FROM /
+    # WHERE (reference: GroupIdNode + GroupIdOperator)
     grouping_sets: Optional[List[List[Expr]]] = None
 
 
