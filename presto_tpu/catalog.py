@@ -559,13 +559,45 @@ class TpcdsTable(ConnectorTable):
         return self._data
 
 
-def tpcds_catalog(sf: float = 0.01, cache_dir: Optional[str] = None) -> Catalog:
+class TpcdsShardedTable(TpcdsTable):
+    """A sales or returns fact table that a mesh holds in contiguous
+    ticket / order ranges, each generated on the chip that holds it
+    (`shard_grid`, as TpchTable's): no host copy of the table exists on
+    a mesh either."""
+
+    def shard_grid(self, ndev: int):
+        """`ndev` contiguous row ranges of this table, cut on its
+        family's ticket / order boundaries (a return lies with its
+        sale), with the in-trace generator for a range (`build_scan`;
+        connectors/tpcds_device._SalesChunkFamily.shard_grid)."""
+        grids = self.__dict__.setdefault("_shard_grids", {})
+        if ndev not in grids:
+            grids[ndev] = self.bucketing().shard_grid(ndev)
+        return grids[ndev]
+
+
+def tpcds_catalog(sf: float = 0.01, cache_dir: Optional[str] = None,
+                  fact_table=TpcdsTable) -> Catalog:
     from presto_tpu.connectors import tpcds as tpcds_gen
+    from presto_tpu.connectors.tpcds_device import DEVICE_COLUMNS
 
     cat = Catalog()
     for name in tpcds_gen.SCHEMAS:
-        cat.register(TpcdsTable(name, sf, cache_dir))
+        cls = fact_table if name in DEVICE_COLUMNS else TpcdsTable
+        cat.register(cls(name, sf, cache_dir))
     return cat
+
+
+def tpcds_mesh_catalog(sf: float = 0.01,
+                       cache_dir: Optional[str] = None) -> Catalog:
+    """`tpcds_catalog` for a deployment on a mesh whose fact tables
+    outgrow a chip (benchmarks/configs/tpcds_store_sf100_mesh4.json):
+    the four sales / returns tables are `TpcdsShardedTable`s, born
+    sharded by `sharded_scan`.  Under `tpcds_catalog` a mesh reads them
+    on the host (all 23 columns of store_sales: 50 GB at sf100); a
+    program from before this entry point lacks the name and stops
+    there."""
+    return tpcds_catalog(sf, cache_dir, fact_table=TpcdsShardedTable)
 
 
 def tpcds_device_catalog(sf: float = 0.01,

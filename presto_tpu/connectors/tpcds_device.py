@@ -502,6 +502,10 @@ class _SalesChunkGrid:
         # holds at most cap_sales/unit distinct bucket values
         return max(self.cap_sales // max(self.unit, 1), 1)
 
+    def row_edges(self, table: str):
+        """The table's own row range of every chunk, as edges."""
+        return self.edges if table == self.sales else self.ret_edges
+
     def chunk_args(self, i: int):
         return (jnp.asarray(self.edges[i], jnp.int64),
                 jnp.asarray(self.edges[i + 1] - self.edges[i], jnp.int32),
@@ -549,10 +553,24 @@ class _SalesChunkFamily:
         # land in exactly one chunk (the bucketing colocation property)
         chunk_rows = max(self.unit, chunk_rows - chunk_rows % self.unit)
         total = DS.row_count(self.sales, self.sf)
-        total_ret = DS.row_count(self.returns, self.sf)
         edges = list(range(0, total, chunk_rows)) + [total]
         if len(edges) >= 2 and edges[-2] == edges[-1]:
             edges.pop()
+        return self._grid(edges)
+
+    def shard_grid(self, ndev: int) -> _SalesChunkGrid:
+        """The chunk grid cut for a mesh: `ndev` contiguous ranges of
+        the sales table's rows, interior edges on ticket / order
+        boundaries, and the returns of the same tickets beside them
+        (parallel/dist_executor.sharded_scan generates each range on the
+        chip that holds it).  Trailing ranges may be empty."""
+        total = DS.row_count(self.sales, self.sf)
+        per = max(-(-total // ndev), 1)
+        per += -per % self.unit
+        return self._grid([min(i * per, total) for i in range(ndev + 1)])
+
+    def _grid(self, edges) -> _SalesChunkGrid:
+        total_ret = DS.row_count(self.returns, self.sf)
         # return j's parent sale is row j*RETURN_EVERY: parents in
         # [a, b) <=> j in [ceil(a/E), ceil(b/E)) — an exact partition
         E = DS.RETURN_EVERY

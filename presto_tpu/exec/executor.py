@@ -13,6 +13,7 @@ reference's gather exchanges from pre-requisite stages.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Dict, List, Optional
 
@@ -63,7 +64,7 @@ def _merge_sort_stats(stats, counts: dict) -> None:
               "partial_aggs_bypassed", "partial_aggs_reenabled",
               "aggs_fused", "aggs_unfused",
               "window_functions", "grouping_set_branches",
-              "grouping_set_sources"):
+              "grouping_set_sources", "grouping_set_state_rows"):
         setattr(stats, k, getattr(stats, k, 0) + int(counts.get(k, 0)))
     if counts.get("partial_agg_ratio"):
         # a gauge, not a sum: the last ratio a partial stage observed
@@ -1373,7 +1374,7 @@ class Executor:
         rows that match on other shards."""
         return True
 
-    def _rf_mask_pays(self) -> bool:
+    def _rf_mask_pays(self, node=None) -> bool:
         """Does a consumer here do more with a filter's membership mask
         than AND it into sel?  Dynamic mode does: pruned rows are
         compacted away, counted, and turned into stripe/zone-map
@@ -1382,9 +1383,10 @@ class Executor:
         then drops it itself (an INNER/SEMI join IS the filter, without
         false positives) — so compiled programs decline the mask
         (df_filters_declined).  A cluster task overrides this: it does
-        not ship a pruned row.  The mesh executor overrides it too, but
-        only to stay as on the parent: its shapes are fixed like these,
-        and no cell has priced its mask yet (PERF.md section 7)."""
+        not ship a pruned row.  The mesh executor overrides it too: it
+        declines for a star join (`node.star_lookup`) and otherwise stays
+        as on the parent, though its shapes are fixed like these: no
+        cell has priced that mask yet (PERF.md section 7)."""
         return not self.static
 
     def _rf_register(self, specs, right: Batch) -> None:
@@ -1808,7 +1810,12 @@ class Executor:
         """The source ONCE; then each grouping set is the Aggregate of its
         keys over that one batch, with NULL columns for the keys the set
         leaves out and its index as the group id; the sets' rows are
-        concatenated as a UNION ALL's are."""
+        concatenated as a UNION ALL's are.  On a mesh the node comes in
+        two steps, as an Aggregate does: PARTIAL is the same with the
+        sets' states for aggregates, FINAL merges moved states
+        (`_merge_groupingsets`)."""
+        if node.step == "FINAL":
+            return self._merge_groupingsets(node)
         b = self.exec_node(node.source)
         self._count("grouping_set_sources")
         if node.hints:
@@ -1817,7 +1824,11 @@ class Executor:
                 b, node.hints[0].get("input_est_hint"))
         outs = []
         for i, keys in enumerate(node.sets):
-            with jax.named_scope("Aggregate"):
+            # a SINGLE node's sets read as Aggregates in a profile; a mesh
+            # node's partials, exchange and merge all read as the node
+            scope = jax.named_scope("Aggregate") if node.step == "SINGLE" \
+                else contextlib.nullcontext()
+            with scope:
                 out = self._aggregate_batch(node.set_aggregate(i), b)
             n = out.capacity
             cols = {}
@@ -1831,7 +1842,27 @@ class Executor:
             cols[node.group_id] = Column(jnp.full((n,), i, jnp.int32), None,
                                          T.INTEGER)
             outs.append(Batch(cols, out.sel))
-        return K.concat_batches(outs)
+        out = K.concat_batches(outs)
+        if node.step == "PARTIAL":
+            self._count("grouping_set_state_rows", out.capacity)
+        return out
+
+    def _merge_groupingsets(self, node: P.GroupingSets) -> Batch:
+        """FINAL: the shards' states, moved by the Exchange under the
+        node, merged by ONE Aggregate over (keys, group id).  A key that
+        a set leaves out is NULL in all of the set's rows and the group
+        id tells the sets apart, so the merged groups are the sets'.  The
+        Exchange is lowered here and not through exec_node: its
+        collective then lies under this node's scope in a profile, not
+        under `Exchange` with the broadcast builds."""
+        src = node.source
+        b = self._exec_exchange(src) if isinstance(src, P.Exchange) \
+            else self.exec_node(src)
+        merge = P.Aggregate(src, node.group_keys + [node.group_id],
+                            node.aggs, "FINAL")
+        vars(merge).update(node.merge_hints)
+        out = self._aggregate_batch(merge, b)
+        return Batch({s: out.columns[s] for s, _ in node.outputs()}, out.sel)
 
     def _aggregate_batch(self, node: P.Aggregate, b: Batch) -> Batch:
         """`node` over `b`, its source's rows."""
@@ -3414,7 +3445,7 @@ class Executor:
         if not (produce and node.join_type in ("INNER", "SEMI")
                 and self._df_enabled() and self._rf_build_complete(node)):
             produce = None
-        elif not self._rf_mask_pays():
+        elif not self._rf_mask_pays(node):
             # nothing is registered: the probe scans find no summary
             # and run filter-free, as under an unannotated join
             self._count("df_filters_declined", len(produce))
@@ -3486,6 +3517,12 @@ class Executor:
             self._copy_order(left, out)
         return out
 
+    def _index_build_whole(self, node: P.Join, il: dict,
+                           right: Batch) -> bool:
+        """Is the build batch the index's WHOLE table in natural order
+        (row i holds key min + i)?  Here a scan is the table."""
+        return right.capacity == il["rows"]
+
     def _join_batches(self, left: Batch, right: Batch, node: P.Join) -> Batch:
         jt = node.join_type
         if jt == "CROSS":
@@ -3543,8 +3580,10 @@ class Executor:
         bk = il.get("block_keys", 1) if il else 1
         br = il.get("block_rows", 1) if il else 1
         strided = (bk, br) != (1, 1)
-        full_build = il is not None and right.capacity == il["rows"]
-        use_index = (il is not None and self.allow_index_join
+        full_build = il is not None and self._index_build_whole(
+            node, il, right)
+        use_index = (il is not None
+                     and (self.allow_index_join or full_build)
                      and len(lkeys) == 1
                      # strided layouts also run over CHUNK-sized builds:
                      # bucket-aligned chunks are contiguous row ranges,

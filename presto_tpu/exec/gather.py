@@ -126,6 +126,28 @@ def sort_order_worthwhile(m: int, gain_words: int) -> bool:
             and gain_words > 0)
 
 
+# The staged route stacks a source's words into one (n, w) u32 matrix.
+# Past this many bytes the matrix goes in column groups of at most half
+# of it: a mesh shard of TPC-DS sf100's store_sales compacts 72 M slots
+# x 21 words after its first join, a 6 GB matrix beside 3.5 GB of
+# resident columns and its own copy in the gather (17.1 GB of
+# temporaries: the v5e's compiler, PERF.md section 6, PR 36).  A gather
+# costs by the index, not by the row's width (PR 34), so a group more is
+# ~8 ns an index more.  No source of the cells before PR 36 is past it
+# (the largest: 28.8 M x 21 words, 2.4 GB, TPC-DS q27 at sf10).
+STAGED_SOURCE_BYTES = 1 << 32
+
+
+def staged_word_groups(n: int, words: int):
+    """The slices of a staged source's `words` u32 columns that are
+    stacked and gathered together: all of them, unless n rows of them
+    are past STAGED_SOURCE_BYTES."""
+    if 4 * n * words <= STAGED_SOURCE_BYTES:
+        return [slice(0, words)]
+    per = max(STAGED_SOURCE_BYTES // 2 // (4 * n), 1)
+    return [slice(k, min(k + per, words)) for k in range(0, words, per)]
+
+
 def staged_gather(src: jnp.ndarray, sidx: jnp.ndarray) -> jnp.ndarray:
     """Gather rows of a (n, w) u32 matrix at ASCENDING i32 indices,
     pre-clipped to [0, n)."""

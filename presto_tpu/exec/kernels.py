@@ -931,9 +931,10 @@ def _take_rows_staged(arrays, idx, words, spec, presorted):
         n = ii.shape[0]
         sidx, spos = jax.lax.sort(
             (ii, jnp.arange(n, dtype=jnp.int32)), num_keys=1)
-    mat = jnp.stack(words, axis=1)
-    rows = G.staged_gather(mat, sidx)
-    cols = [rows[:, k] for k in range(len(words))]
+    cols = []
+    for part in G.staged_word_groups(words[0].shape[0], len(words)):
+        rows = G.staged_gather(jnp.stack(words[part], axis=1), sidx)
+        cols += [rows[:, k] for k in range(rows.shape[1])]
     directs = {i: arrays[i][sidx] for i, a in enumerate(arrays)
                if spec[i][0] == "direct"}
     if spos is not None:

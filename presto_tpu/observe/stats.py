@@ -220,10 +220,14 @@ class QueryStats:
     # lowered such a node's source (Executor._exec_groupingsets; trace
     # time, replayed): one a node, where the planner's former UNION ALL of
     # sub-queries ran it once a set.  1 - sources / branches is the share
-    # of source runs saved.
+    # of source runs saved.  grouping_set_state_rows: on a mesh, the
+    # capacity of the states every chip sends for such a node (its
+    # PARTIAL step's output: the sets' group capacities, added; trace
+    # time, replayed); 0 where the node ran whole on one chip.
     window_functions: int = 0
     grouping_set_branches: int = 0
     grouping_set_sources: int = 0
+    grouping_set_state_rows: int = 0
     result_cache_hit: int = 0
     resource_group: str = ""
     admission_wait_ms: float = 0.0

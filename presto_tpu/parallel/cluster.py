@@ -1007,7 +1007,7 @@ class _ClusterExecutor:
 
                 return complete(node.right)
 
-            def _rf_mask_pays(ex_self) -> bool:
+            def _rf_mask_pays(ex_self, node=None) -> bool:
                 # a cluster task does not ship a pruned row
                 return True
 

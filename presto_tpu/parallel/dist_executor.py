@@ -71,7 +71,8 @@ class DistExecutor(Executor):
     """Per-shard executor: inherits the whole static (compiled-mode)
     operator repertoire and adds Exchange lowering."""
 
-    # per-shard scan slices break the index join's whole-table layout
+    # per-shard scan slices break the index join's whole-table layout;
+    # a star join's replicated build restores it (_index_build_whole)
     allow_index_join = False
 
     def __init__(self, session, ndev: int, scan_inputs, sort_stats=None):
@@ -108,11 +109,26 @@ class DistExecutor(Executor):
         return not isinstance(node.right, P.Exchange) \
             and super()._build_presorted(node, right, rkeys)
 
-    def _rf_mask_pays(self) -> bool:
-        # kept as on the parent until sf1_mesh4_join can price it, not
-        # because it pays: a shard_map program's shapes are fixed too
-        # (PERF.md section 7: goes if that cell shows what sf1_join did)
-        return True
+    def _index_build_whole(self, node, il, right: Batch) -> bool:
+        """A star join's build on the mesh (`star_lookup`, the planner's
+        mark: plan/distribute._star_lookup): a dimension that a gather or
+        broadcast made whole on every shard.  `sharded_scan` cuts a table
+        into contiguous ranges and pads only behind a range's live rows,
+        and only the last range with rows is short, so the ranges laid
+        end to end are the table's rows in order, then dead rows: the
+        identity layout, which the join's layout guard verifies in the
+        trace."""
+        return getattr(node, "star_lookup", False) \
+            and self._rf_build_complete(node) \
+            and right.capacity >= il["rows"]
+
+    def _rf_mask_pays(self, node=None) -> bool:
+        # a star join's probe is one gather: no mask over the fact
+        # table's rows for it.  Every other join keeps the filter as on
+        # the parent until sf1_mesh4_join can price it, not because it
+        # pays: a shard_map program's shapes are fixed too (PERF.md
+        # section 7: goes if that cell shows what sf1_join did)
+        return not getattr(node, "star_lookup", False)
 
     def _exchange_bytes(self, b: Batch) -> int:
         """Trace-time byte estimate of one collective exchange: every
@@ -332,15 +348,16 @@ def shard_generator(table, cols, mesh, ndev: int, f32: bool):
     one-chip one in its static-shape form (the chunk grid's
     `build_scan`), the ranges arrive as traced per-shard scalars."""
     grid = table.shard_grid(ndev)
-    orders, lines = np.asarray(grid.order_edges), np.asarray(grid.line_offsets)
-    args = (orders[:-1].astype(np.int64), lines[:-1].astype(np.int64),
-            np.diff(orders).astype(np.int32), np.diff(lines).astype(np.int32))
+    # a shard is a chunk of the grid (TPC-H's or TPC-DS's): every chunk's
+    # `chunk_args` at once, an array an argument, a shard's own element in each
+    args = tuple(np.stack([np.asarray(a) for a in arg]) for arg in
+                 zip(*(grid.chunk_args(i) for i in range(ndev))))
 
-    def shard(o0, line0, n_ord, n_line):
+    def shard(*mine):
         return grid.build_scan(table.name, cols,
-                               (o0[0], line0[0], n_ord[0], n_line[0]), f32)
+                               tuple(a[0] for a in mine), f32)
 
-    return _shard_mapped(shard, mesh, (PS(AXIS),) * 4, PS(AXIS)), args
+    return _shard_mapped(shard, mesh, (PS(AXIS),) * len(args), PS(AXIS)), args
 
 
 def sharded_scan(table, node: P.TableScan, mesh, ndev: int,
@@ -374,7 +391,8 @@ def sharded_scan(table, node: P.TableScan, mesh, ndev: int,
     needed = list(dict.fromkeys(node.assignments.values()))
     missing = [c for c in needed if c not in cache_for(c)]
     # born sharded needs the table's own cut (`shard_grid`); a table that
-    # generates whole columns only (TPC-DS) is host-read and laid out
+    # generates whole columns only (a TPC-DS fact table of a catalog other
+    # than tpcds_mesh_catalog's) is host-read and laid out
     born = [c for c in missing if hasattr(table, "shard_grid")
             and table.device_generable(c)]
     sel_key = "__sel__"

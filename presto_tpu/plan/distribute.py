@@ -297,6 +297,61 @@ class Distributer:
                 f"{[a.fn for a in node.aggs.values()]}")
         return self._split_partial_final(node, src)
 
+    def _visit_groupingsets(self, node: P.GroupingSets):
+        """The node with PARTIAL calls on every shard (one source, every
+        set) -> the sets' states moved -> one FINAL merge over (keys,
+        group id): `_split_partial_final` for a node whose rows carry
+        their set's index.  The states are gathered where all the sets
+        together stay under `partial_aggregation_max_groups` (q36: 161
+        groups) and where an aggregate's state is a sketch; else they
+        are repartitioned by (keys, group id), so that a shard merges
+        its share of the groups (q27: ~0.5 M).  (keys, group id) is
+        unique in the node's output, and it is what the output is
+        hashed on then."""
+        src, dist = self.visit(node.source)
+        node.source = src
+        if dist.kind == "replicated":
+            return node, REPLICATED
+        if self.bucketed:
+            # a chunk's states would have to be rolled up across chunks:
+            # the chunk loop has no such stage for this node yet
+            raise Undistributable("GroupingSets in the chunk loop")
+        unsplit = sorted({("DISTINCT " if a.distinct else "") + a.fn
+                          for a in node.aggs.values() if a.distinct
+                          or not (a.fn in _MERGEABLE or _sketch_mergeable(a))})
+        if unsplit:
+            raise Undistributable(
+                f"GroupingSets with aggregates that have no partial state "
+                f"{unsplit}")
+        partial_aggs, final_aggs = self._decompose_aggs(node.aggs)
+        partial = P.GroupingSets(src, list(node.group_keys), node.sets,
+                                 partial_aggs, node.group_id, node.hints)
+        partial.step = "PARTIAL"
+        merge_keys = list(node.group_keys) + [node.group_id]
+        caps = [h.get("capacity_hint") for h in node.hints]
+        cap = sum(caps) if caps and None not in caps else None
+        sketch = any(_sketch_mergeable(a) for a in node.aggs.values())
+        if sketch or (cap is not None and cap <= self.partial_agg_groups):
+            moved, out = P.Exchange(partial, "gather"), REPLICATED
+            if sketch:
+                moved.sketch_only = True    # fixed-width states, as above
+        else:
+            moved = P.Exchange(partial, "repartition", merge_keys)
+            out = Dist("hashed", tuple(merge_keys))
+        # the merge is ONE Aggregate over (keys, group id): its hints are
+        # the sets' taken together
+        from presto_tpu.plan.stats import ColStats
+
+        key_stats = {node.group_id: ColStats(0, len(node.sets) - 1,
+                                             len(node.sets))}
+        for h in node.hints:
+            key_stats.update(h.get("key_stats") or {})
+        final = P.GroupingSets(moved, list(node.group_keys), node.sets,
+                               final_aggs, node.group_id)
+        final.step = "FINAL"
+        final.merge_hints = {"capacity_hint": cap, "key_stats": key_stats}
+        return final, out
+
     def decompose_aggs(self, aggs):
         """(partial_aggs, final_aggs) for a mergeable aggregate map, or
         (None, None) when some aggregate has no partial/final
@@ -530,9 +585,13 @@ class Distributer:
         if self._colocated(ldist, rdist, node.criteria):
             out_dist = Dist("hashed", ldist.keys)
             return node, out_dist
+        broadcast_ok = broadcast_ok or self._lookup_moves_less(node,
+                                                               build_rows)
         if broadcast_ok and node.distribution != "PARTITIONED":
             if rdist.kind != "replicated":
                 node.right = P.Exchange(right, "broadcast")
+            if self._star_lookup(node, build_rows):
+                node.star_lookup = True
             # probe side keeps its distribution
             return node, ldist
         # P1: repartition both sides on the join keys
@@ -544,6 +603,51 @@ class Distributer:
             node.right = P.Exchange(P.Exchange(right, "scatter"),
                                     "repartition", rkeys)
         return node, Dist("hashed", tuple(lkeys))
+
+    def _dense_lookup_rows(self, node: P.Join, build_rows):
+        """(build rows, probe rows), the planner's upper bounds, where
+        the probe finds its build row by the build's dense key
+        (`index_lookup`, identity layout: no sort of the build, so a
+        whole copy on every shard costs its transfer and nothing else);
+        else None.  Not in the chunk loop, whose broadcast threshold is
+        HBM headroom."""
+        il = getattr(node, "index_lookup", None)
+        if il is None or self.bucketed or build_rows is None or \
+                (il.get("block_keys", 1), il.get("block_rows", 1)) != (1, 1):
+            return None
+        probe_rows = self._estimated_rows(node.left)
+        return None if probe_rows is None else (build_rows, probe_rows)
+
+    def _lookup_moves_less(self, node: P.Join, build_rows) -> bool:
+        """A star join whose dimension is past the row threshold (sf100:
+        item 1.8 M rows, customer_demographics 1.92 M): the choice goes
+        by bytes moved.  A dense lookup's build is broadcast if its
+        columns times the shards are fewer bytes than the probe side's
+        rows, which a repartition of both sides would move instead.
+        `broadcast_join_threshold_rows` 0 still means no broadcast."""
+        rows = self._dense_lookup_rows(node, build_rows)
+        if rows is None or self.broadcast_rows <= 0:    # broadcasts are off
+            return False
+        from presto_tpu.plan.fusion_cost import _row_bytes
+
+        return rows[0] * _row_bytes(node.right.outputs()) * self.ndev \
+            <= rows[1] * _row_bytes(node.left.outputs())
+
+    def _star_lookup(self, node: P.Join, build_rows) -> bool:
+        """A dense lookup whose probe shard is at least
+        `gather._SMALL_SOURCE_RATIO` times its replicated build (a fact
+        table against a dimension): the mesh executor takes the index
+        join for it, which a shard's scan slices otherwise forbid, and
+        traces no runtime filter for it (a mask over the fact table's
+        rows for a probe that is one gather).  A join of nearer sizes
+        keeps the sort join and the filter that its program was compiled
+        with (TPC-H Q3's customer join on the mesh; PERF.md section 7)."""
+        rows = self._dense_lookup_rows(node, build_rows)
+        if rows is None:
+            return False
+        from presto_tpu.exec.gather import _SMALL_SOURCE_RATIO
+
+        return rows[1] >= _SMALL_SOURCE_RATIO * rows[0] * self.ndev
 
     def _to_replicated(self, node: P.PlanNode, dist: Dist) -> P.PlanNode:
         return node if dist.kind == "replicated" else P.Exchange(node, "gather")

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import itertools
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import ClassVar, Dict, List, Optional, Tuple
 
 from presto_tpu.plan.ir import AggCall, RowExpr
 from presto_tpu.types import Type
@@ -117,6 +117,13 @@ class GroupingSets(PlanNode):
     #: of that set's keys (capacity_hint, key_stats, input_est_hint,
     #: ordering_hint..., agg_strategy): annotate_sets fills it
     hints: List[dict] = field(default_factory=list)
+    #: SINGLE | PARTIAL | FINAL, as an Aggregate's.  The mesh planner
+    #: (plan/distribute._visit_groupingsets) sets the other two on the
+    #: instance: PARTIAL gives every set's states on a shard, FINAL
+    #: merges moved states over (keys, group id) under `merge_hints`.
+    #: Not a field: a SINGLE node's vars(), and so its plan's
+    #: fingerprint and its program's cache key, stay what they were.
+    step: ClassVar[str] = "SINGLE"
 
     def outputs(self):
         from presto_tpu.types import INTEGER
@@ -133,7 +140,7 @@ class GroupingSets(PlanNode):
     def set_aggregate(self, i: int) -> Aggregate:
         """Set i as the Aggregate it is lowered as: the node's source and
         calls, the set's keys, the hints kept for the set."""
-        agg = Aggregate(self.source, list(self.sets[i]), self.aggs)
+        agg = Aggregate(self.source, list(self.sets[i]), self.aggs, self.step)
         if i < len(self.hints):
             vars(agg).update(self.hints[i])
         return agg
@@ -431,7 +438,8 @@ def plan_tree_str(node: PlanNode, indent: int = 0, annotate=None) -> str:
         detail = (f" {node.step} keys={node.group_keys} "
                   + "{" + ", ".join(f"{s} := {a}" for s, a in node.aggs.items()) + "}")
     elif isinstance(node, GroupingSets):
-        detail = (f" sets={node.sets} id={node.group_id} "
+        detail = (("" if node.step == "SINGLE" else f" {node.step}")
+                  + f" sets={node.sets} id={node.group_id} "
                   + "{" + ", ".join(f"{s} := {a}" for s, a in node.aggs.items()) + "}")
     elif isinstance(node, Join):
         detail = f" {node.join_type} {node.criteria}" + (

@@ -3,7 +3,8 @@
 `Executor._exec_groupingsets`, against the sqlite oracle at SF0.01.  sqlite
 has no grouping sets: each oracle text is the UNION ALL of the sets'
 aggregations, which is what the planner used to make of the query itself.
-Every case runs in dynamic and in compiled mode.
+Every case runs in dynamic and in compiled mode, and on a four-device mesh
+(ISSUE 36) with the sets' states gathered and repartitioned.
 """
 
 import pytest
@@ -163,34 +164,89 @@ def test_grouping_takes_keys_only(tpch_catalog_tiny):
               "GROUP BY ROLLUP (o_orderstatus)")
 
 
-def test_mesh_session_answers_a_rollup_on_one_chip(tpch_catalog_tiny,
-                                                   tpch_sqlite_tiny):
-    """The mesh planner does not place the node (`Undistributable`), so a
-    `distributed=true` session answers on one chip, and the counter behind
-    `distributed_share` says so."""
+def queries_total(mode):
     from presto_tpu.observe import metrics as M
 
-    def queries_total(mode):
-        M.ensure_query_metrics()
-        return M.REGISTRY.get("presto_tpu_queries_total").value(
-            state="FINISHED", mode=mode)
+    M.ensure_query_metrics()
+    return M.REGISTRY.get("presto_tpu_queries_total").value(
+        state="FINISHED", mode=mode)
 
+
+#: partial_aggregation_max_groups -> how the sets' states move: gathered
+#: where all the sets' capacities together stay under it (the default, at
+#: this scale), repartitioned by (keys, group id) where they do not
+STATES_MOVE = {"gather": 8192, "repartition": 1}
+
+
+@pytest.fixture(scope="module", params=sorted(STATES_MOVE))
+def mesh_session(request, tpch_catalog_tiny):
+    s = presto_tpu.connect(tpch_catalog_tiny)
+    s.set("distributed", True)
+    s.set("mesh_devices", 4)
+    s.set("partial_aggregation_max_groups", STATES_MOVE[request.param])
+    s.states_move = request.param
+    return s
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_grouping_sets_on_a_mesh(mesh_session, tpch_sqlite_tiny, case):
+    """`plan/distribute._visit_groupingsets` (ISSUE 36): PARTIAL sets a
+    shard over ONE lowering of the source, the states moved, one FINAL
+    merge over (keys, group id).  An aggregate without a partial state (a
+    DISTINCT one) keeps the node off the mesh, and the reason says so."""
+    from presto_tpu.plan.distribute import distribute
+
+    text, oracle, ordered, n_sets = CASES[case]
+    got = mesh_session.sql(text)
+    want = tpch_sqlite_tiny.execute(to_sqlite(oracle)).fetchall()
+    assert_same_results(got.rows, want, ordered=ordered, rel_tol=1e-6)
+    assert got.stats.grouping_set_branches == n_sets
+    assert got.stats.grouping_set_sources == 1
+    if "DISTINCT" in text:
+        assert got.stats.execution_mode == "compiled"
+        assert ("distributed: Undistributable: GroupingSets with aggregates "
+                "that have no partial state ['DISTINCT count']") \
+            in got.stats.fallback_reason
+        assert got.stats.grouping_set_state_rows == 0
+        return
+    assert got.stats.execution_mode == "distributed", got.stats.fallback_reason
+    assert not got.stats.fallback_reason
+    plan = distribute(plan_statement(mesh_session, parse(text)), mesh_session, 4)
+    steps = [(n.step, type(n.source).__name__,
+              getattr(n.source, "kind", None))
+             for n in walk(plan.root) if isinstance(n, P.GroupingSets)]
+    assert steps == [("FINAL", "Exchange", mesh_session.states_move),
+                     ("PARTIAL", steps[1][1], None)]
+    partial = [n for n in walk(plan.root)
+               if isinstance(n, P.GroupingSets) and n.step == "PARTIAL"][0]
+    # the capacity of the states a chip sends: the sets' capacities, added
+    assert got.stats.grouping_set_state_rows == sum(
+        h["capacity_hint"] for h in partial.hints)
+    assert got.stats.exchange_bytes_collective > 0
+
+
+def test_mesh_session_answers_a_rollup_on_the_mesh(tpch_catalog_tiny,
+                                                   tpch_sqlite_tiny):
+    """The mesh planner places the node, so a `distributed=true` session
+    answers a ROLLUP on the mesh, cold and from its program's memo, and
+    the counter behind `distributed_share` says so."""
     s = presto_tpu.connect(tpch_catalog_tiny)
     s.set("distributed", True)
     s.set("mesh_devices", 4)
     text, oracle, ordered, n_sets = CASES["rollup_two_keys"]
     on_mesh, compiled = queries_total("distributed"), queries_total("compiled")
-    # the planner's refusal, then the session's memo of it
-    for why in ("GroupingSets", "static assumptions previously violated"):
+    counted = []
+    for _ in range(2):      # traced, then replayed by the cached program
         got = s.sql(text)
         want = tpch_sqlite_tiny.execute(to_sqlite(oracle)).fetchall()
         assert_same_results(got.rows, want, ordered=ordered, rel_tol=1e-6)
-        assert got.stats.execution_mode == "compiled"
-        assert f"distributed: Undistributable: {why}" \
-            in got.stats.fallback_reason
-        assert got.stats.grouping_set_sources == 1
-    assert queries_total("distributed") == on_mesh
-    assert queries_total("compiled") == compiled + 2
-    # a query the mesh does place still runs on it in the same session
-    assert s.sql("SELECT o_orderstatus, count(*) FROM orders GROUP BY 1") \
-        .stats.execution_mode == "distributed"
+        assert got.stats.execution_mode == "distributed"
+        assert not got.stats.fallback_reason
+        counted.append((got.stats.grouping_set_sources,
+                        got.stats.grouping_set_branches,
+                        got.stats.grouping_set_state_rows,
+                        got.stats.exchange_bytes_collective))
+    assert counted[0] == counted[1] and counted[0][:2] == (1, n_sets)
+    assert got.stats.compiles == 0
+    assert queries_total("distributed") == on_mesh + 2
+    assert queries_total("compiled") == compiled

@@ -171,6 +171,11 @@ SHARDED_SCANS = {
     "orders_sf1": ("orders", 1.0, ["o_orderkey", "o_custkey", "o_orderdate",
                                    "o_shippriority"]),
     "customer_sf1": ("customer", 1.0, ["c_custkey", "c_mktsegment"]),
+    # the cell ds100_mesh4_rollup: TPC-DS's store_sales at sf100, 72 M rows
+    # a chip, the columns q36 reads (the grid supplies the arguments)
+    "store_sales_sf100": ("store_sales", 100.0, [
+        "ss_sold_date_sk", "ss_item_sk", "ss_store_sk", "ss_ext_sales_price",
+        "ss_net_profit"]),
 }
 
 
@@ -183,14 +188,14 @@ def test_sharded_generation_program_compiles_for_four_chips(topology, scan):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-    from presto_tpu.catalog import TpchTable
+    from presto_tpu.catalog import TpcdsShardedTable, TpchTable
     from presto_tpu.parallel import dist_executor as DX
     from presto_tpu.parallel.mesh import AXIS
 
     name, sf, cols = SHARDED_SCANS[scan]
     mesh = Mesh(np.asarray(topology.devices[:4]), (AXIS,))
     spec = NamedSharding(mesh, PartitionSpec(AXIS))
-    table = TpchTable(name, sf)
+    table = (TpcdsShardedTable if name == "store_sales" else TpchTable)(name, sf)
     fn, args = DX.shard_generator(table, cols, mesh, 4, True)
     shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=spec)
               for a in args]
