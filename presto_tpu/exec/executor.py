@@ -64,7 +64,8 @@ def _merge_sort_stats(stats, counts: dict) -> None:
               "partial_aggs_bypassed", "partial_aggs_reenabled",
               "aggs_fused", "aggs_unfused",
               "window_functions", "grouping_set_branches",
-              "grouping_set_sources", "grouping_set_state_rows"):
+              "grouping_set_sources", "grouping_set_state_rows",
+              "grouping_set_merge_rows"):
         setattr(stats, k, getattr(stats, k, 0) + int(counts.get(k, 0)))
     if counts.get("partial_agg_ratio"):
         # a gauge, not a sum: the last ratio a partial stage observed
@@ -1860,7 +1861,13 @@ class Executor:
             else self.exec_node(src)
         merge = P.Aggregate(src, node.group_keys + [node.group_id],
                             node.aggs, "FINAL")
-        vars(merge).update(node.merge_hints)
+        hints = dict(node.merge_hints)
+        # repartitioned states carry the planner's bound on a chip's live
+        # ones: the merge (and its output) runs over the received buffer
+        # compacted to it, under the compaction's guard
+        b = self._maybe_compact_static(b, hints.pop("input_est_hint", None))
+        vars(merge).update(hints)
+        self._count("grouping_set_merge_rows", b.capacity)
         out = self._aggregate_batch(merge, b)
         return Batch({s: out.columns[s] for s, _ in node.outputs()}, out.sel)
 
@@ -3416,13 +3423,20 @@ class Executor:
             out = Batch(merged, eval_predicate(node.filter, out, self.ctx))
         return out
 
+    #: a batch under this many slots is never compacted, and no bound is
+    #: under 2 ** COMPACT_MIN_BOUND_BITS slots
+    COMPACT_MIN_CAPACITY = 1 << 19
+    COMPACT_MIN_BOUND_BITS = 14
+
     def _maybe_compact_static(self, b: Batch, est) -> Batch:
         """Guarded estimate-driven compaction (see _aggregate_static):
         dropping masked rows is always semantically safe; the guard
         covers the estimate being wrong."""
-        if not self.static or est is None or b.capacity < (1 << 19):
+        if not self.static or est is None \
+                or b.capacity < self.COMPACT_MIN_CAPACITY:
             return b
-        bound = 1 << max(int(np.ceil(np.log2(max(est, 1) * 2))), 14)
+        bound = 1 << max(int(np.ceil(np.log2(max(est, 1) * 2))),
+                         self.COMPACT_MIN_BOUND_BITS)
         # the top_k costs one sort of the capacity whatever the bound
         # (77.5 ms at 28.8 M slots for 2^20 and for 2^21: PERF.md section
         # 6, PR 34), the gather grows with the bound: up to 2^20 rows a

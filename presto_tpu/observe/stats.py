@@ -224,10 +224,16 @@ class QueryStats:
     # capacity of the states every chip sends for such a node (its
     # PARTIAL step's output: the sets' group capacities, added; trace
     # time, replayed); 0 where the node ran whole on one chip.
+    # grouping_set_merge_rows: the slots a chip's FINAL merge of such a
+    # node aggregates over: the received states compacted to the planner's
+    # bound on a chip's live ones where that engaged (repartitioned
+    # states), else the received capacity (trace time, replayed); 0 where
+    # the node ran whole on one chip.
     window_functions: int = 0
     grouping_set_branches: int = 0
     grouping_set_sources: int = 0
     grouping_set_state_rows: int = 0
+    grouping_set_merge_rows: int = 0
     result_cache_hit: int = 0
     resource_group: str = ""
     admission_wait_ms: float = 0.0

@@ -350,6 +350,14 @@ class Distributer:
                                final_aggs, node.group_id)
         final.step = "FINAL"
         final.merge_hints = {"capacity_hint": cap, "key_stats": key_stats}
+        ests = [h.get("input_est_hint") for h in node.hints]
+        if moved.kind == "repartition" and None not in caps + ests:
+            # the live states ONE chip receives: a shard's partial holds
+            # at most min(its groups, its rows) a set, the repartition
+            # spreads the sum over the chips (the compaction's own
+            # doubling is the margin for hash skew)
+            live = sum(min(c * self.ndev, e) for c, e in zip(caps, ests))
+            final.merge_hints["input_est_hint"] = -(-live // self.ndev)
         return final, out
 
     def decompose_aggs(self, aggs):

@@ -208,6 +208,7 @@ def test_grouping_sets_on_a_mesh(mesh_session, tpch_sqlite_tiny, case):
                 "that have no partial state ['DISTINCT count']") \
             in got.stats.fallback_reason
         assert got.stats.grouping_set_state_rows == 0
+        assert got.stats.grouping_set_merge_rows == 0
         return
     assert got.stats.execution_mode == "distributed", got.stats.fallback_reason
     assert not got.stats.fallback_reason
@@ -222,6 +223,13 @@ def test_grouping_sets_on_a_mesh(mesh_session, tpch_sqlite_tiny, case):
     # the capacity of the states a chip sends: the sets' capacities, added
     assert got.stats.grouping_set_state_rows == sum(
         h["capacity_hint"] for h in partial.hints)
+    # the merge runs over what a chip received (this scale is under the
+    # compaction's floor): every chip's states gathered, or four buckets
+    # of twice a chip's share (the repartition's slack)
+    sent = got.stats.grouping_set_state_rows
+    assert got.stats.grouping_set_merge_rows == (
+        4 * sent if mesh_session.states_move == "gather"
+        else 4 * -(-2 * sent // 4))
     assert got.stats.exchange_bytes_collective > 0
 
 

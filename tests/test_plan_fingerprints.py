@@ -53,7 +53,9 @@ PARENT.update({
 })
 
 #: the cell ds100_mesh4_rollup's two distributed plans, new in ISSUE 36
-#: (no parent has them: a change here is a change of the mesh's programs)
+#: (no parent has them: a change here is a change of the mesh's programs).
+#: ISSUE 37 gives a repartitioned merge an estimate; at SF0.01 both
+#: classes gather their states, so both pins stay ecc037c's.
 MESH_ROLLUP = {
     ("tpcds_store_sf100_mesh4", "tpcds_q27", None):
         "948faf5191e4816f16f526b7af80ca85c9dd55a3dd0e36c14cf05f7821d9b828",

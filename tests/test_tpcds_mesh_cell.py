@@ -12,7 +12,10 @@
 (c) the counters a mesh program replays, the planner's choices at sf100
     (dimensions past the row threshold broadcast by bytes moved, star
     lookups marked, the sets' states gathered or repartitioned), and what
-    a star lookup changes in a shard's program.
+    a star lookup changes in a shard's program;
+(d) ISSUE 37: the FINAL merge of repartitioned states runs over the
+    received buffer compacted to the planner's bound on a chip's live
+    states, under the compaction's guard.
 """
 
 import importlib.util
@@ -221,6 +224,10 @@ def test_a_mesh_program_replays_its_counters(served, cls):
     assert stats.exchange_bytes_collective == cold.exchange_bytes_collective > 0
     assert (stats.aggs_fused, stats.aggs_unfused) == (cold.aggs_fused,
                                                       cold.aggs_unfused)
+    # at this scale both classes gather their states: the merge runs over
+    # every chip's, uncompacted
+    assert stats.grouping_set_merge_rows == cold.grouping_set_merge_rows \
+        == 4 * stats.grouping_set_state_rows
     plan = distribute(plan_statement(served[1], parse(text_of(cls))),
                       served[1], 4)
     # the star lookups' filters were declined, the others' traced
@@ -414,3 +421,150 @@ def test_staged_gather_in_word_groups_equals_the_flat_gather(monkeypatch,
     for g, a in zip(got, arrays):
         assert g.dtype == a.dtype
         assert (np.asarray(g) == np.asarray(a)[np.asarray(idx)]).all()
+
+
+# ---------------------------------------------------------------------------
+# (d) the FINAL merge aggregates the states it received (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+
+def test_merge_estimate_at_sf100_is_a_chips_live_states():
+    """q27's sets (statistics alone, sf100): a shard's partial holds at
+    most min(the set's groups, its rows) live states, the repartition
+    spreads their sum over the four chips.  The compaction doubles the
+    estimate and rounds it up: 2^19 slots, where a chip receives
+    2 x (2^20 + 2^20 + 1) = 4,194,309.  q36's gathered states get none."""
+    cat = tpcds_mesh_catalog(100, cache_dir=None)
+    plan = planned(cat, text_of("q27"))
+    final, partial = [n for n in walk(plan.root) if isinstance(n, P.GroupingSets)]
+    caps = [h["capacity_hint"] for h in partial.hints]
+    ests = [h["input_est_hint"] for h in partial.hints]
+    assert caps == [33_554_432, 2_097_152, 1] and ests == [385_415] * 3
+    # (min(2^25 x 4, 385,415) + min(2^21 x 4, 385,415) + min(1 x 4, 385,415)) / 4
+    assert -(-(385_415 + 385_415 + 4) // 4) == 192_709
+    assert final.merge_hints["input_est_hint"] == 192_709
+    assert final.merge_hints["capacity_hint"] == sum(caps)
+    assert 1 << int(np.ceil(np.log2(2 * 192_709))) == 1 << 19
+    plan = planned(cat, text_of("q36"))
+    final = next(n for n in walk(plan.root) if isinstance(n, P.GroupingSets))
+    assert final.source.kind == "gather"
+    assert "input_est_hint" not in final.merge_hints
+
+
+def merge_over(received, est):
+    """The FINAL merge of `GROUP BY ROLLUP (k)`'s states in a compiled
+    (static) executor: -> (live rows sorted, slots merged, guards)."""
+    import presto_tpu
+    from presto_tpu import types as T
+    from presto_tpu.exec.executor import Executor
+    from presto_tpu.plan import ir
+    from presto_tpu.plan.stats import ColStats
+
+    types = {"k": T.BIGINT, "s": T.BIGINT, "c": T.BIGINT, "g": T.INTEGER}
+    scan = P.TableScan("states", {n: n for n in types}, types)
+    node = P.GroupingSets(scan, ["k"], [["k"], []], {
+        "total": ir.AggCall("sum", (ir.Ref("s", T.BIGINT),), T.BIGINT),
+        "n": ir.AggCall("merge_count", (ir.Ref("c", T.BIGINT),), T.BIGINT)},
+        "g")
+    node.step = "FINAL"
+    node.merge_hints = {"capacity_hint": received.capacity, "key_stats": {
+        "g": ColStats(0, 1, 2), "k": ColStats(0, 9_999, 10_000)}}
+    if est is not None:
+        node.merge_hints["input_est_hint"] = est
+    ex = Executor(presto_tpu.connect(tpch_catalog(SF, cache_dir=None)),
+                  static=True, scan_inputs={id(scan): received})
+    out = ex.exec_node(node)
+    cols = {n: (np.asarray(c.data), None if c.valid is None
+                else np.asarray(c.valid)) for n, c in out.columns.items()}
+    rows = sorted(
+        tuple(None if v is not None and not v[i] else int(d[i])
+              for d, v in (cols[n] for n in ("g", "k", "total", "n")))
+        for i in np.flatnonzero(np.asarray(out.sel)))
+    return rows, out.capacity, ex.sort_stats["grouping_set_merge_rows"], \
+        [bool(g) for g in ex.guards]
+
+
+@pytest.fixture(scope="module")
+def received():
+    """2^19 + 5 slots of states, 40,000 of them live (a chip's share of
+    the repartition: few live slots in a large buffer); dead slots hold
+    garbage."""
+    import jax.numpy as jnp
+
+    from presto_tpu import types as T
+    from presto_tpu.batch import Batch, Column
+
+    rng = np.random.default_rng(37)
+    n, live = (1 << 19) + 5, 40_000
+    sel = np.zeros(n, bool)
+    sel[rng.choice(n, live, replace=False)] = True
+    g = np.where(sel, rng.integers(0, 2, n), rng.integers(-9, 9, n))
+    k_valid = ~sel | (g == 0)                   # the total's key is NULL
+    cols = {"k": Column(jnp.asarray(rng.integers(0, 10_000, n)),
+                        jnp.asarray(k_valid), T.BIGINT),
+            "s": Column(jnp.asarray(rng.integers(-10**6, 10**6, n)), None, T.BIGINT),
+            "c": Column(jnp.asarray(rng.integers(1, 6, n)), None, T.BIGINT),
+            "g": Column(jnp.asarray(g.astype(np.int32)), None, T.INTEGER)}
+    return Batch(cols, jnp.asarray(sel))
+
+
+def test_merge_compacts_the_received_states_and_answers_the_same(received):
+    """An estimate under the live count (30,000 of 40,000, as q27's
+    192,709 of ~210 k) still bounds them once doubled: the merge runs
+    over 2^16 slots instead of 2^19 + 5, no guard trips, and every merged
+    row equals the uncompacted merge's."""
+    whole, whole_cap, whole_merged, _ = merge_over(received, None)
+    rows, cap, merged, guards = merge_over(received, 30_000)
+    assert (whole_cap, whole_merged) == (received.capacity, received.capacity)
+    assert (cap, merged) == (1 << 16, 1 << 16)
+    assert guards and not any(guards)
+    assert rows == whole
+    sel, g = np.asarray(received.sel), np.asarray(received.columns["g"].data)
+    c = np.asarray(received.columns["c"].data)
+    k = np.asarray(received.columns["k"].data)
+    assert len(rows) == len(set(k[sel & (g == 0)])) + 1
+    assert rows[-1] == (1, None, int(np.asarray(received.columns["s"].data)[
+        sel & (g == 1)].sum()), int(c[sel & (g == 1)].sum()))
+
+
+def test_merge_estimate_too_low_trips_the_guard(reference, served, monkeypatch):
+    """q27's states repartitioned (a bound under its 2,305 groups) and
+    the compaction's floors lowered to this scale: with the planner's
+    estimate the merge compacts and answers on the mesh; forced to 1, the
+    guard trips, the query re-runs off the mesh and still answers right."""
+    import presto_tpu
+    from presto_tpu.exec import compile_cache as CC
+    from presto_tpu.exec.executor import Executor
+    from presto_tpu.plan.distribute import Distributer
+
+    monkeypatch.setattr(Executor, "COMPACT_MIN_CAPACITY", 1)
+    monkeypatch.setattr(Executor, "COMPACT_MIN_BOUND_BITS", 0)
+    want = reference.streamed(SF, ["tpcds_q27"])["tpcds_q27"]
+
+    def q27():
+        CC.clear()      # a program traced under the floors lowered
+        s = presto_tpu.connect(served[1].catalog)
+        for k, v in CONFIG["session_properties"].items():
+            s.set(k, v)
+        s.set("partial_aggregation_max_groups", 1024)
+        got = s.sql(text_of("q27"))
+        CC.clear()
+        assert reference.rows_equal(got.rows, want, REL)
+        return got.stats
+
+    st = q27()
+    assert st.execution_mode == "distributed" and not st.fallback_reason
+    assert 0 < st.grouping_set_merge_rows < 2 * st.grouping_set_state_rows
+
+    real = Distributer._visit_groupingsets
+
+    def lying(self, node):
+        final, out = real(self, node)
+        assert final.merge_hints["input_est_hint"] > 1    # repartitioned
+        final.merge_hints["input_est_hint"] = 1
+        return final, out
+
+    monkeypatch.setattr(Distributer, "_visit_groupingsets", lying)
+    st = q27()
+    assert st.execution_mode != "distributed"
+    assert "static assumption violated at runtime" in st.fallback_reason
