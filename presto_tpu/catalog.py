@@ -305,7 +305,8 @@ def _generated_on_device(table, columns, f32, generate):
     (column set, lane), kept on the table so that a scan after
     release_device_caches() builds nothing again.  Zero-argument AOT:
     the generator's compile is part of a query's cold cost and belongs
-    in its compile-economics counters."""
+    in its compile-economics counters; its run is table birth
+    (`compile_cache.data_load`)."""
     from presto_tpu.exec import compile_cache as CC
 
     key = (tuple(sorted(columns)), f32)
@@ -316,7 +317,7 @@ def _generated_on_device(table, columns, f32, generate):
             return generate(list(key[0]))
 
         fn = cache[key] = CC.build_jit(gen, example=())
-    return fn()
+    return CC.data_load(fn)
 
 
 #: every live catalog, for bulk cache release (the test suite frees

@@ -40,7 +40,12 @@ Telemetry: every build routes through `build_jit`, so QueryStats gains
 exact `compiles` / `compile_ms` / `compile_cache_hits` /
 `compile_ahead_hits` per query.  Persistent-cache disk hits are
 observed through jax.monitoring's `/jax/compilation_cache/cache_hits`
-event.
+event.  The same listener books JAX's compile stages where they run,
+AOT builds and first-call builds alike: `lower_ms` (jaxpr trace +
+lowering to StableHLO), `xla_build_ms` + `programs_built` (backend
+compiles XLA ran), `cache_load_ms` (backend stages the persistent cache
+served).  `data_load` books table birth: `data_load_ms` /
+`data_load_bytes`.
 """
 
 from __future__ import annotations
@@ -68,7 +73,9 @@ DEFAULT_CACHE_DIR = os.path.join(
 #: QueryStats counter names this module maintains (observe/stats.py
 #: declares the same fields)
 COUNTERS = ("compiles", "compile_ms", "compile_cache_hits",
-            "compile_ahead_hits")
+            "compile_ahead_hits", "lower_ms", "xla_build_ms",
+            "cache_load_ms", "programs_built", "data_load_ms",
+            "data_load_bytes")
 
 
 class CompileStats:
@@ -80,6 +87,12 @@ class CompileStats:
         self.compile_ms = 0.0
         self.compile_cache_hits = 0
         self.compile_ahead_hits = 0
+        self.lower_ms = 0.0
+        self.xla_build_ms = 0.0
+        self.cache_load_ms = 0.0
+        self.programs_built = 0
+        self.data_load_ms = 0.0
+        self.data_load_bytes = 0
 
     def snapshot(self) -> Dict[str, Any]:
         return {k: getattr(self, k) for k in COUNTERS}
@@ -146,23 +159,132 @@ def resolve_cache_dir(session=None) -> Optional[str]:
     return None if d in ("0", "off") else d
 
 
+# JAX's compile stages (jax/_src/dispatch.py), each reported on the
+# compiling thread at its start (a scalar event holding the start time) and
+# at its end (a duration, then the same as a time span).  Stages nest: the
+# trace of a function holds the traces of the jitted functions it calls.
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_TO_MLIR = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_STAGES = (_TRACE, _TO_MLIR, _BACKEND)
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _stack() -> list:
+    """This thread's open stages: the seconds their nested stages took."""
+    st = getattr(_tls, "stages", None)
+    if st is None:
+        st = _tls.stages = []
+    return st
+
+
+def _staged_ms() -> float:
+    """Stage milliseconds booked on this thread so far (a running sum)."""
+    return getattr(_tls, "staged_ms", 0.0)
+
+
+def _book(field: str, ms: float) -> None:
+    _note(field, ms)
+    _tls.staged_ms = _staged_ms() + ms
+
+
+def _guarded(fn):
+    """A listener runs inside JAX's compile: a fault of its own is counted
+    in presto_tpu_trace_errors_total and never fails the build."""
+    def listener(*args, **kw):
+        try:
+            fn(*args, **kw)
+        except Exception:  # noqa: BLE001
+            from presto_tpu.observe import metrics as M
+
+            M.trace_error()
+    return listener
+
+
 def _on_event(event, **kw) -> None:
-    if event == "/jax/compilation_cache/cache_hits":
+    if event == _CACHE_HIT:
         _note("compile_cache_hits")
+        _tls.loaded = True      # the backend stage under way read the disk
+
+
+def _on_stage_start(event, _start, **kw) -> None:
+    if event in _STAGES:
+        _stack().append(0.0)
+        if event == _BACKEND:
+            _tls.loaded = False
+
+
+def _on_stage_end(event, secs, **kw) -> None:
+    """Book a stage's own time (less its nested stages'): trace and
+    lowering to `lower_ms`; a backend stage to `cache_load_ms` where the
+    persistent cache served it (JAX times the retrieval inside it), else
+    to `xla_build_ms` and one more `programs_built`.  JAX's cache_misses
+    event is no test of a build: it fires only when an entry is written."""
+    if event not in _STAGES:
+        return
+    stack = _stack()
+    nested = stack.pop() if stack else 0.0
+    if stack:
+        stack[-1] += secs
+    if event != _BACKEND:
+        field = "lower_ms"
+    elif getattr(_tls, "loaded", False):
+        field = "cache_load_ms"
+    else:
+        field = "xla_build_ms"
+        _note("programs_built")
+    _book(field, max(secs - nested, 0.0) * 1e3)
+
+
+def _on_stage_span(event, start, end, fun_name="", **kw) -> None:
+    """A first-call build's outermost stages on the query's Tracer, at
+    JAX's own start and end.  An AOT build opens its stages' spans itself
+    (`Executable.aot_compile`)."""
+    if event not in _STAGES or _stack() or getattr(_tls, "aot", False):
+        return
+    tracer = TR.current()
+    if tracer is None:
+        return
+    args = {"program": fun_name}
+    if event == _BACKEND:
+        name = "exec.backend"
+        args["source"] = "loaded" if getattr(_tls, "loaded", False) \
+            else "built"
+    else:
+        name = "exec.lower"
+        args["stage"] = "trace" if event == _TRACE else "to_mlir"
+    sp = tracer.begin(name, kind="compile", at_ns=TR.ns_of_wall(start),
+                      **args)
+    tracer.end(sp, at_ns=TR.ns_of_wall(end))
+
+
+def _listen() -> None:
+    """Install the jax.monitoring listeners, once a process."""
+    global _listener_installed
+    if _listener_installed:
+        return
+    with _conf_lock:
+        if _listener_installed:
+            return
+        jax.monitoring.register_event_listener(_guarded(_on_event))
+        jax.monitoring.register_scalar_listener(_guarded(_on_stage_start))
+        jax.monitoring.register_event_duration_secs_listener(
+            _guarded(_on_stage_end))
+        jax.monitoring.register_event_time_span_listener(
+            _guarded(_on_stage_span))
+        _listener_installed = True
 
 
 def configure(session=None) -> None:
     """Idempotently point JAX's persistent compilation cache at the
     resolved dir — unless JAX_COMPILATION_CACHE_DIR placed it from
     outside, in which case no directory is set here — and install the
-    disk-hit listener.  Safe to call per query: only reconfigures when
-    the resolved dir changes."""
-    global _configured_dir, _listener_installed
+    listeners (`_listen`).  Safe to call per query: only reconfigures
+    when the resolved dir changes."""
+    global _configured_dir
     d = resolve_cache_dir(session)
+    _listen()
     with _conf_lock:
-        if not _listener_installed:
-            jax.monitoring.register_event_listener(_on_event)
-            _listener_installed = True
         if d == _configured_dir:
             return
         _configured_dir = d
@@ -355,6 +477,38 @@ def scope_tables() -> Dict[str, Dict[str, str]]:
     return tables
 
 
+def _module_name(lowered) -> str:
+    """The HLO module's name (`jit_<fn>`), as a profile's events carry it."""
+    try:
+        return str(lowered._lowering.stablehlo().operation
+                   .attributes["sym_name"].value)
+    except Exception:  # noqa: BLE001 — a span argument, nothing more
+        return ""
+
+
+def _aot_stage(sp, run):
+    """`run()` under the span `sp` (`exec.lower` or `exec.backend`), one
+    stage of an AOT build.  JAX's events book the stage's parts as they
+    run; what they leave of its wall (JAX's own work around them) is
+    booked to the stage here, so that an AOT build's lower_ms +
+    xla_build_ms + cache_load_ms is its compile_ms."""
+    staged0, aot = _staged_ms(), getattr(_tls, "aot", False)
+    backend = sp.name == "exec.backend"
+    _tls.aot, _tls.loaded = True, False
+    try:
+        with sp as rec:
+            out = run()
+            loaded = backend and getattr(_tls, "loaded", False)
+            if rec is not None and backend:
+                rec.args["source"] = "loaded" if loaded else "built"
+    finally:
+        _tls.aot = aot
+    field = "cache_load_ms" if loaded else \
+        "xla_build_ms" if backend else "lower_ms"
+    _book(field, max(sp.elapsed_ns / 1e6 - (_staged_ms() - staged0), 0.0))
+    return out
+
+
 class Executable:
     """A counted jax.jit product.  With example args it AOT-compiles
     immediately (lower+compile timed as compile_ms — execution excluded);
@@ -381,7 +535,12 @@ class Executable:
         # compile-ahead builds appear on their own pool-thread lane.
         with TR.span("xla_compile", kind="compile"):
             shapes = jax.tree_util.tree_map(_shape_struct, example_args)
-            self._compiled = self._jitted.lower(*shapes).compile()
+            lowered = _aot_stage(TR.span("exec.lower", kind="compile"),
+                                 lambda: self._jitted.lower(*shapes))
+            self._compiled = _aot_stage(
+                TR.span("exec.backend", kind="compile",
+                        program=_module_name(lowered)),
+                lowered.compile)
         _note("compiles")
         _note("compile_ms", (TR.clock_ns() - t0) / 1e6)
 
@@ -412,11 +571,13 @@ def build_jit(fn: Callable, *, example=None, tag: Optional[str] = None,
     two executors).  `example`: concrete args to AOT-compile against —
     exact compile timing, and the executable is ready before first use.
     Without example the first call traces+compiles inside jit (counted
-    as one compile; its wall time is indistinguishable from execution,
-    so compile_ms only grows by AOT builds).  `tag`: a fingerprint of
+    as one compile; compile_ms grows by AOT builds only, and the first
+    call's stages are booked by the listener: lower_ms, xla_build_ms or
+    cache_load_ms).  `tag`: a fingerprint of
     the program that is the same in every process (the plan's): part of
     the module's name, so a profile tells one query's program from
     another's (`_versioned`)."""
+    _listen()
     ex = Executable(fn, jit_kwargs, tag)
     if example is not None:
         try:
@@ -432,6 +593,25 @@ def build_jit(fn: Callable, *, example=None, tag: Optional[str] = None,
     else:
         _note("compiles")
     return ex
+
+
+def data_load(make: Callable[[], Any]):
+    """Table birth: `make()` -> a column set's device arrays (a pytree),
+    timed to ready under the span `exec.data_load` into `data_load_ms`
+    and `data_load_bytes`.  Called where a table and column set is born,
+    once (never in a warm query), so its one block_until_ready costs a
+    warm query nothing.  A build inside it stays in the compile counters,
+    out of data_load_ms."""
+    staged0 = _staged_ms()
+    sp = TR.span("exec.data_load")
+    with sp:
+        out = make()
+        jax.block_until_ready(out)
+    _note("data_load_ms",
+          max(sp.elapsed_ns / 1e6 - (_staged_ms() - staged0), 0.0))
+    _note("data_load_bytes", sum(getattr(x, "nbytes", 0)
+                                 for x in jax.tree_util.tree_leaves(out)))
+    return out
 
 
 # ---------------------------------------------------------------------------

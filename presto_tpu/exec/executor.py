@@ -4338,20 +4338,10 @@ def scan_batch(table, node: P.TableScan, f32: bool = False,
             # generator connectors produce columns ON DEVICE (one jitted
             # program, no host materialization or H2D upload)
             dev = table.device_columns(missing, f32=f32)
-        if dev is not None:
-            for c in missing:
-                cache_for(c)[c] = dev[c]
-        else:
-            from presto_tpu.batch import column_from_numpy
-
-            data = table.read(missing)
-            for c in missing:
-                t = table.schema.get(c, T.BOOLEAN)  # virtual: BOOLEAN
-                col = column_from_numpy(data[c], t)
-                if f32 and t.name == "DOUBLE":
-                    col = Column(col.data.astype(jnp.float32), col.valid,
-                                 col.type, col.dictionary)
-                cache_for(c)[c] = col
+        if dev is None:
+            dev = CC.data_load(lambda: _placed(table, missing, f32))
+        for c in missing:
+            cache_for(c)[c] = dev[c]
     cols = {}
     n = None
     for sym, col in node.assignments.items():
@@ -4367,6 +4357,23 @@ def scan_batch(table, node: P.TableScan, f32: bool = False,
     if sel is None:
         sel = base[sel_key] = jnp.ones((n or 0,), bool)
     return Batch(cols, sel)
+
+
+def _placed(table, columns, f32: bool) -> Dict[str, Column]:
+    """A host-read column set put on the device (a table born on the
+    host: TPC-DS's dimensions, memory tables, files)."""
+    from presto_tpu.batch import column_from_numpy
+
+    data = table.read(columns)
+    out = {}
+    for c in columns:
+        t = table.schema.get(c, T.BOOLEAN)  # virtual: BOOLEAN
+        col = column_from_numpy(data[c], t)
+        if f32 and t.name == "DOUBLE":
+            col = Column(col.data.astype(jnp.float32), col.valid,
+                         col.type, col.dictionary)
+        out[c] = col
+    return out
 
 
 def _merge_range(a, b):

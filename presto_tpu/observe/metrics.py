@@ -3,7 +3,7 @@
 Reference parity: the reference engine exports every QueryStats /
 operator counter through JMX (presto-main jmx beans, scraped by the
 jmx connector and the ops dashboards).  Our answer is a dependency-free
-registry — counters, gauges, and histograms with bounded reservoirs —
+registry — counters, gauges, and histograms —
 served as Prometheus text exposition from `/v1/metrics` on BOTH the
 coordinator (server/protocol.py) and every cluster worker
 (parallel/cluster.py), replacing the ad-hoc JSON-only aggregation that
@@ -145,15 +145,8 @@ DEFAULT_BUCKETS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
                    500.0, 1000.0, 2500.0, 5000.0, 10000.0, 30000.0,
                    float("inf"))
 
-#: bounded reservoir size (per histogram) for host-side quantiles
-RESERVOIR_SIZE = 512
-
-
 class Histogram(Metric):
-    """Cumulative-bucket histogram + a BOUNDED reservoir for host-side
-    quantiles.  The reservoir is deterministic (a NumPy-free LCG seeded
-    at construction, never the wall clock or `random`), so tests replay
-    identical sampling decisions."""
+    """Cumulative-bucket histogram: Prometheus buckets, sum and count."""
 
     kind = "histogram"
 
@@ -166,12 +159,6 @@ class Histogram(Metric):
         self._counts = [0] * len(bs)
         self._sum = 0.0
         self._count = 0
-        self._reservoir: List[float] = []
-        self._lcg = 0x9E3779B9  # fixed seed: deterministic sampling
-
-    def _next_u32(self) -> int:
-        self._lcg = (self._lcg * 1664525 + 1013904223) & 0xFFFFFFFF
-        return self._lcg
 
     def observe(self, value: float) -> None:
         v = float(value)
@@ -182,20 +169,6 @@ class Histogram(Metric):
                 if v <= b:
                     self._counts[i] += 1
                     break
-            if len(self._reservoir) < RESERVOIR_SIZE:
-                self._reservoir.append(v)
-            else:  # algorithm-R replacement with the deterministic LCG
-                j = self._next_u32() % self._count
-                if j < RESERVOIR_SIZE:
-                    self._reservoir[j] = v
-
-    def quantile(self, q: float) -> Optional[float]:
-        with self._lock:
-            vals = sorted(self._reservoir)
-        if not vals:
-            return None
-        idx = min(int(q * len(vals)), len(vals) - 1)
-        return vals[idx]
 
     def render(self) -> List[str]:
         with self._lock:

@@ -98,6 +98,15 @@ SPANS: Dict[str, tuple] = {
                   "run_distributed: sharded_scan of every scan, cached shards when warm, "
                   "generated on their chips or put there when cold"),
     "xla_compile": ("executor", "compile_cache.Executable.aot_compile: lower + compile"),
+    "exec.lower": ("executor",
+                   "aot_compile: jaxpr trace + lowering to StableHLO (a first-call build's "
+                   "stages reach the Tracer from JAX's events)"),
+    "exec.backend": ("executor",
+                     "aot_compile: XLA's build, or the load from the persistent cache "
+                     "(program=, source=built|loaded)"),
+    "exec.data_load": ("data on device",
+                       "compile_cache.data_load: a column set born on the device or placed "
+                       "there, timed to ready"),
 }
 
 #: names built at run time (chunked and cluster modes), by their prefix

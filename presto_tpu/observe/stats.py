@@ -104,6 +104,21 @@ class QueryStats:
     compile_ms: float = 0.0
     compile_cache_hits: int = 0
     compile_ahead_hits: int = 0
+    # compile stages, booked where JAX runs them (jax.monitoring events on
+    # the compiling thread; AOT and first-call builds alike): lower_ms —
+    # jaxpr trace + lowering to StableHLO, paid on a disk hit too;
+    # xla_build_ms — backend compiles the persistent cache did not serve,
+    # programs_built of them; cache_load_ms — backend stages it served.
+    # An AOT build's three add up to its compile_ms.  Table birth
+    # (compile_cache.data_load, span exec.data_load): a column set made
+    # on the device or placed there, timed to ready, its bytes; a build
+    # inside it stays in the compile fields.
+    lower_ms: float = 0.0
+    xla_build_ms: float = 0.0
+    cache_load_ms: float = 0.0
+    programs_built: int = 0
+    data_load_ms: float = 0.0
+    data_load_bytes: int = 0
     # dynamic filtering (plan/runtime_filters.py): build-side runtime
     # filters produced / applied at probe scans, filters a compiled
     # program DECLINED to trace (Executor._rf_mask_pays: fixed shapes,

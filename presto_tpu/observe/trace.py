@@ -88,6 +88,13 @@ def epoch_us(ns: Optional[int] = None) -> float:
     return (ns + _EPOCH_OFFSET_NS) / 1_000.0
 
 
+def ns_of_wall(wall_s: float) -> int:
+    """The clock_ns() reading at Unix time `wall_s` (seconds): where a time
+    another clock stamped (JAX's compile events read time.time()) lies on
+    the span clock.  epoch_us(ns_of_wall(t)) == t * 1e6."""
+    return int(wall_s * 1e9) - _EPOCH_OFFSET_NS
+
+
 # ---------------------------------------------------------------------------
 # spans
 # ---------------------------------------------------------------------------

@@ -400,8 +400,10 @@ def sharded_scan(table, node: P.TableScan, mesh, ndev: int,
         fn, args = shard_generator(table, born, mesh, ndev, f32)
         args = tuple(_put(a, spec) for a in args)
         # AOT, counted: the generator's compile is part of a query's cold
-        # cost, as TpchTable.device_columns' is on one chip
-        cols, sel = CC.build_jit(fn, example=args)(*args)
+        # cost, as TpchTable.device_columns' is on one chip; its run is
+        # table birth
+        prog = CC.build_jit(fn, example=args)
+        cols, sel = CC.data_load(lambda: prog(*args))
         for c in born:
             cache_for(c)[c] = cols[c]
         base.setdefault(sel_key, sel)
@@ -415,11 +417,12 @@ def sharded_scan(table, node: P.TableScan, mesh, ndev: int,
                 out[i * per:i * per + b - a] = arr[a:b]
             return _put(out, spec)
 
-        if read:
+        def placed():
             from presto_tpu import types as T
             from presto_tpu.batch import column_from_numpy
 
-            data = table.read(read)
+            out = {}
+            data = table.read(read) if read else {}
             for c in read:
                 t = table.schema.get(c, T.BOOLEAN)
                 col = column_from_numpy(data[c], t)
@@ -428,10 +431,14 @@ def sharded_scan(table, node: P.TableScan, mesh, ndev: int,
                     arr = arr.astype(np.float32)
                 valid = None if col.valid is None \
                     else laid_out(np.asarray(col.valid))
-                cache_for(c)[c] = Column(laid_out(arr), valid, col.type,
-                                         col.dictionary)
-        if sel_key not in base:
-            base[sel_key] = laid_out(np.ones((edges[-1],), bool))
+                out[c] = Column(laid_out(arr), valid, col.type,
+                                col.dictionary)
+            if sel_key not in base:
+                out[sel_key] = laid_out(np.ones((edges[-1],), bool))
+            return out
+
+        for c, col in CC.data_load(placed).items():
+            (base if c == sel_key else cache_for(c))[c] = col
     cols = {}
     for sym, colname in node.assignments.items():
         c = cache_for(colname)[colname]
