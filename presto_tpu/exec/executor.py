@@ -63,6 +63,7 @@ def _merge_sort_stats(stats, counts: dict) -> None:
               "spill_recursions",
               "partial_aggs_bypassed", "partial_aggs_reenabled",
               "aggs_fused", "aggs_unfused",
+              "index_joins_packed", "index_joins_keyed",
               "window_functions", "grouping_set_branches",
               "grouping_set_sources", "grouping_set_state_rows",
               "grouping_set_merge_rows"):
@@ -3654,23 +3655,28 @@ class Executor:
                 in_slot = jnp.ones_like(off, bool)
             pos = jnp.clip(pos_raw, 0, nrows - 1).astype(jnp.int32)
             in_range = (off >= 0) & (pos_raw < nrows) & in_slot
-            if full_build and not strided and rkeys[0].valid is None \
-                    and GA.small_source(nrows, pos.shape[0]):
-                # a star join's probe: the fact table's rows against a
-                # dimension many times smaller.  The layout guard above
-                # has every live build row hold the key of its position,
-                # so a live row at `pos` IS the match: no gather of the
-                # build key, and the build's sel comes in the one packed
-                # gather that brings the row's columns (three one-word
-                # gathers and a staged one cost 1.2 s a join at 28.8 M
-                # rows, this one 0.2: PERF.md section 6, PR 34)
+            if full_build and not strided and rkeys[0].valid is None:
+                # the build is the whole table and the layout guard above
+                # has every live build row hold key base + its position,
+                # so a probe key in range lands on its match or on a dead
+                # row: the match test is the build's sel at `pos`, which
+                # comes in the one packed gather that brings the row's
+                # columns (a semi join's, which brings none, is one word).
+                # No gather of the build key: at 28.8 M probe rows three
+                # one-word gathers and a staged one cost 1.2 s a join,
+                # the packed one 0.2 (PERF.md section 6, PR 34)
                 index_rows = K.gather_batch(
                     right if jt in ("INNER", "LEFT")
                     else Batch({}, right.sel), pos)
                 found_idx = lsel & in_range & index_rows.sel
+                self._count("index_joins_packed")
             else:
+                # a strided build's base comes from its data, and a
+                # nullable key's NULL rows are live but unguarded:
+                # compare the keys
                 rkd = jnp.asarray(rkeys[0].data)[pos].astype(jnp.int64)
                 found_idx = lsel & in_range & rsel[pos] & (rkd == lk)
+                self._count("index_joins_keyed")
             counts = found_idx.astype(jnp.int32)
             index_ridx = pos
         elif self.static:

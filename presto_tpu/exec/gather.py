@@ -69,7 +69,8 @@ _STAGED_MIN_WORDS = 2
 # the compiler refused TPC-DS q36).  Measured on a v5e at 28.8 M indices
 # into 180,000 x 5 words (PERF.md section 6, PR 34): staged 495 ms, the
 # packed gather in blocks 198 ms, ascending indices 198 ms as well, five
-# one-word gathers 1838 ms.  No program of fewer indices changes.
+# one-word gathers 1838 ms.  The rule routes gathers only: a gather of
+# fewer indices, or from a larger source, takes the route it took before.
 _SMALL_SOURCE_MIN_INDICES = 1 << 23
 _SMALL_SOURCE_RATIO = 8
 #: indices a block of the packed gather holds (2 GB of padded rows)

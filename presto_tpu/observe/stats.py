@@ -227,6 +227,13 @@ class QueryStats:
     # reads all of its aggregates unfused.
     aggs_fused: int = 0
     aggs_unfused: int = 0
+    # index join engagement (Executor._join_batches; trace time, replayed
+    # like aggs_fused): index joins whose match test came from the one
+    # packed gather of the build row (the build whole, not strided, its
+    # key never NULL: the layout guard makes the key check redundant),
+    # and those that still gathered the build key to compare it.
+    index_joins_packed: int = 0
+    index_joins_keyed: int = 0
     # window_functions: window function calls the program's Window nodes
     # computed (exec/window.execute_window; trace time, replayed like
     # aggs_fused).  grouping_set_branches: grouping sets the plan's

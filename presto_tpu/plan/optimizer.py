@@ -232,8 +232,9 @@ def _index_lookup_info(node: P.Join, catalog):
     subtree is Filter/Project-over-TableScan ONLY (row positions reach
     the join unchanged — filters mask sel, never compact), the key is an
     identity Ref of the scan's dense unique column, and the executor
-    additionally verifies gathered key == probe key in-trace, so stale
-    stats degrade to no-match on rows a sort join would also not match.
+    verifies the build's layout against the hint (a guard in compiled
+    mode, a host check in dynamic mode), so stale stats fall back to
+    the sort join.
     """
     if len(node.criteria) != 1:
         return None

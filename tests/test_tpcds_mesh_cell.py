@@ -236,6 +236,11 @@ def test_a_mesh_program_replays_its_counters(served, cls):
     assert 0 < len(star) < len(joins)
     assert stats.df_filters_declined == sum(
         len(getattr(n, "rf_produce", None) or ()) for n in star)
+    # a star lookup is an index join over the whole broadcast dimension:
+    # its match comes from the packed gather of the build row, traced once
+    # and replayed by the warm request
+    for st in (cold, stats):
+        assert (st.index_joins_packed, st.index_joins_keyed) == (len(star), 0)
 
 
 def test_fact_table_has_no_host_copy_on_the_mesh(served):
