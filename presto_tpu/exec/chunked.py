@@ -39,7 +39,6 @@ adaptation:
 from __future__ import annotations
 
 import dataclasses
-import sys
 import threading
 from typing import Dict, List, Optional
 
@@ -49,6 +48,7 @@ import jax.numpy as jnp
 from presto_tpu.batch import Batch, Column
 from presto_tpu.exec import compile_cache as CC
 from presto_tpu.exec import kernels as K
+from presto_tpu.observe import names as NM
 from presto_tpu.observe import trace as TR
 from presto_tpu.plan import agg_strategy as AS
 from presto_tpu.plan import nodes as P
@@ -253,6 +253,8 @@ def _execute_prepared(session, dplan, frags, runner, table_family,
             # this run's host-side dynamic-filter chunk pruning
             _merge_sort_stats(mon.stats, runner.sort_stats)
             _merge_sort_stats(mon.stats, runner.run_stats)
+            mon.stats.chunk_loop_mode = ",".join(
+                runner.run_stats.get("chunk_loop_modes", ()))
         runner.buffers.clear()  # don't pin HBM between runs
 
 
@@ -573,9 +575,10 @@ class _FragmentRunner:
         self._bypass_lanes: Dict[object, _LaneFrag] = {}
         # trace-time sort-economics counters across fragment programs
         self.sort_stats: Dict[str, int] = {}
-        # PER-RUN counters (chunk pruning happens host-side every run,
-        # unlike the trace-time totals above which warm runs replay)
-        self.run_stats: Dict[str, int] = {}
+        # PER-RUN counters (chunk pruning, the loops' launches, redos
+        # and modes happen host-side every run, unlike the trace-time
+        # totals above which warm runs replay)
+        self.run_stats: Dict[str, object] = {}
         # per-RUN fragment wall clocks (EXPLAIN ANALYZE attribution +
         # the chunked fragment trace spans)
         self.frag_wall_ns: Dict[object, int] = {}
@@ -599,8 +602,12 @@ class _FragmentRunner:
             return Batch(cols, b.sel)
         if chunk_args is not None and node.table in self.table_family:
             cols = list(dict.fromkeys(node.assignments.values()))
-            raw, sel = grid.build_scan(node.table, cols, chunk_args,
-                                       self.f32)
+            # here and not in the grid's build_scan, which a mesh's table
+            # birth calls too: only the chunk programs open the scope, and
+            # only their names carry its mark (_loop_tag)
+            with NM.kernel_scope("k:chunk_scan"):
+                raw, sel = grid.build_scan(node.table, cols, chunk_args,
+                                           self.f32)
             cols_out = {}
             for sym, src in node.assignments.items():
                 c = raw[src]
@@ -707,6 +714,14 @@ class _FragmentRunner:
                 CC.plan_fingerprint(frag.root) or ""
         return fp or None
 
+    def _loop_tag(self, frag) -> str:
+        """The tag in a per-chunk program's name (compile_cache.build_jit):
+        the head of the fragment's fingerprint, the marks of the late
+        scopes its plan opens, and the mark of k:chunk_scan, which these
+        programs alone open."""
+        return ((self._frag_fp(frag) or "")[:8]
+                + NM.late_scope_marks(frag.root) + NM.CHUNK_SCAN_MARK)
+
     def _gkey(self, frag, kind: str, mult: int, avals_fp) -> Optional[str]:
         """Process-wide executable key: fragment serde fingerprint x
         compact-bound mult x mesh/kind x dtype layout of the resident
@@ -763,7 +778,8 @@ class _FragmentRunner:
                     scan_inputs[id(n)] = self._scan_builder(n, cargs, grid)
                 return self._execute(frag, scan_inputs, bound)
 
-            return CC.build_jit(fn, example=(args, grid.chunk_args(0)))
+            return CC.build_jit(fn, example=(args, grid.chunk_args(0)),
+                                tag=self._loop_tag(frag))
 
         return self._cached_exec((frag.fid, mult), gkey, build, ahead)
 
@@ -822,7 +838,8 @@ class _FragmentRunner:
                 return self._execute(frag, scan_inputs, bound,
                                      capture_partial_rows=True)
 
-            return CC.build_jit(fn, example=(args, grid.chunk_args(0)))
+            return CC.build_jit(fn, example=(args, grid.chunk_args(0)),
+                                tag=self._loop_tag(frag))
 
         return self._cached_exec((frag.fid, "pa", mult), gkey, build,
                                  ahead)
@@ -837,7 +854,8 @@ class _FragmentRunner:
         the exchange-buffer budget — bypass trades exchange volume for
         compute, and the trade is only taken when the buffer affords
         it."""
-        obs = jax.device_get(list(pending))
+        with TR.span("chunk.sync"):
+            obs = jax.device_get(list(pending))
         pending.clear()
         thr = AS.min_reduction(self.session)
         for rows, groups in obs:
@@ -942,7 +960,9 @@ class _FragmentRunner:
         round-3 dryrun fell off).  Miss-prone fragments pre-compile the
         next growth step in the background while the loop streams, so
         the recompile is ready when (if) the miss repeats."""
-        for _attempt in range(4):
+        for attempt in range(4):
+            if attempt:
+                self._count("chunk_loop_redos")
             try:
                 return self._run_chunk_loop(frag, fscans)
             except _CompactOverflow:
@@ -1004,15 +1024,19 @@ class _FragmentRunner:
         if not pipelined or grid.nchunks <= 1:
             return self._chunk_loop_syncing(jitted, res_list, grid, budget)
 
-        if mon is not None:
-            # chunk 0 always runs the grouped lane: it calibrates the
-            # compact capacity AND (when a warm run resumes bypassed)
-            # doubles as the hysteresis probe
-            out0, g0, ov0, rin0 = jitted4(res_list, grid.chunk_args(0))
-        else:
-            out0, g0, ov0 = jitted(res_list, grid.chunk_args(0))
-            rin0 = None
-        part0 = K.compact(out0)  # the ONE sync: calibrates capacity
+        with TR.span("chunk.launch"):
+            if mon is not None:
+                # chunk 0 always runs the grouped lane: it calibrates the
+                # compact capacity AND (when a warm run resumes bypassed)
+                # doubles as the hysteresis probe
+                out0, g0, ov0, rin0 = self._launch(jitted4, res_list,
+                                                   grid.chunk_args(0))
+            else:
+                out0, g0, ov0 = self._launch(jitted, res_list,
+                                             grid.chunk_args(0))
+                rin0 = None
+        with TR.span("chunk.sync"):
+            part0 = K.compact(out0)  # the ONE sync: calibrates capacity
         n0 = part0.capacity
         cap = 1 << max(16, (4 * max(n0, 1)).bit_length())
         cap = min(cap, out0.sel.shape[0])
@@ -1026,6 +1050,7 @@ class _FragmentRunner:
                                             budget, cap, out0, g0, ov0)
             if r is not None:
                 return r
+            self._count("chunk_loop_redos")
             return self._chunk_loop_syncing(
                 jitted, res_list, grid, budget,
                 prefix=[part0], guards=[g0], overflows=[ov0], start=1)
@@ -1036,8 +1061,6 @@ class _FragmentRunner:
         guards = [g0]
         overflows = [ov0]
         counts = []
-        profile = bool(self.session.properties.get("chunk_profile",
-                                                   False))
         # adaptive monitor state: pending (rows in, groups out) scalars
         # flushed (one host sync) every RATIO_WINDOW chunks; bypassed
         # chunks run the pass-through lane and buffer uncompacted
@@ -1058,7 +1081,9 @@ class _FragmentRunner:
                                                chunk_nodes, grid, mult)
             if bjit is not None and mon is not None and mon.bypassed \
                     and not mon.probe_due():
-                out, guard, ov = bjit(res_list, grid.chunk_args(i))
+                with TR.span("chunk.launch"):
+                    out, guard, ov = self._launch(bjit, res_list,
+                                                  grid.chunk_args(i))
                 parts.append(out)  # pass-through rows, uncompacted
                 buffered += chunk_cap
                 bypassed_chunks += 1
@@ -1066,21 +1091,15 @@ class _FragmentRunner:
                 guards.append(guard)
                 overflows.append(ov)
                 continue
-            t0 = TR.clock_ns() if profile else 0
-            if mon is not None:
-                out, guard, ov, rin = jitted4(res_list, grid.chunk_args(i))
-            else:
-                out, guard, ov = jitted(res_list, grid.chunk_args(i))
-                rin = None
-            part, cnt = cjit(out)  # async: no host sync in this loop
-            if profile:
-                # per-chunk wall time, device-synced (diagnostics only —
-                # syncing defeats the pipeline; keep the property off in
-                # production runs)
-                jax.block_until_ready(part)
-                print(f"chunk_profile: chunk {i} "
-                      f"{(TR.clock_ns() - t0) / 1e6:.0f}ms",
-                      file=sys.stderr)
+            with TR.span("chunk.launch"):
+                if mon is not None:
+                    out, guard, ov, rin = self._launch(jitted4, res_list,
+                                                       grid.chunk_args(i))
+                else:
+                    out, guard, ov = self._launch(jitted, res_list,
+                                                  grid.chunk_args(i))
+                    rin = None
+                part, cnt = cjit(out)  # async: no host sync in this loop
             guards.append(guard)
             overflows.append(ov)
             counts.append(cnt)
@@ -1099,15 +1118,37 @@ class _FragmentRunner:
             # a warm run resumed an earlier flip: no new flip event, but
             # this run DID serve pass-through chunks — count the bypass
             self.run_stats["partial_aggs_bypassed"] = flips_before + 1
-        cap_overflow = bool(jnp.any(jnp.stack(
-            [c > cap for c in counts]))) if counts else False
+        with TR.span("chunk.sync"):
+            cap_overflow, overflow, tripped = jax.device_get((
+                jnp.any(jnp.stack([c > cap for c in counts]))
+                if counts else False,
+                jnp.any(jnp.stack(overflows)), jnp.any(jnp.stack(guards))))
         if cap_overflow:
+            self._count("chunk_loop_redos")
             return self._chunk_loop_syncing(jitted, res_list, grid, budget)
-        if bool(jnp.any(jnp.stack(overflows))):
+        if overflow:
             raise _CompactOverflow
-        if bool(jnp.any(jnp.stack(guards))):
+        if tripped:
             raise Unchunkable("static guard tripped in chunk loop")
-        return K.concat_batches(parts) if len(parts) > 1 else parts[0]
+        self._ran_in("pipelined")
+        with TR.span("chunk.concat"):
+            return K.concat_batches(parts) if len(parts) > 1 else parts[0]
+
+    def _launch(self, program, res_list, chunk_args):
+        """One chunk's program, dispatched: QueryStats.chunks_run."""
+        self._count("chunks_run")
+        return program(res_list, chunk_args)
+
+    def _count(self, key: str) -> None:
+        self.run_stats[key] = self.run_stats.get(key, 0) + 1
+
+    def _ran_in(self, mode: str) -> None:
+        """A loop finished in `mode` (pipelined | accumulate | syncing):
+        QueryStats.chunk_loop_mode, the modes of a query's loops in the
+        order they first ran."""
+        modes = self.run_stats.setdefault("chunk_loop_modes", [])
+        if mode not in modes:
+            modes.append(mode)
 
     def _rf_chunk_view(self, frag, resident, chunk_nodes, grid):
         """Dynamic filtering at chunk grain: build summaries from the
@@ -1258,7 +1299,7 @@ class _FragmentRunner:
             # no AOT example: the live jit's automatic input resharding
             # (host-stacked superstep args -> the mesh axis) is load-
             # bearing here; an AOT signature would pin one placement
-            return CC.build_jit(sharded)
+            return CC.build_jit(sharded, tag=self._loop_tag(frag))
 
         return self._cached_exec(("mesh", frag.fid, mesh_n, mult), gkey,
                                  build, ahead)
@@ -1294,38 +1335,39 @@ class _FragmentRunner:
             guards = [g0]
             overflows = [ov0]
             cap_over = []  # a later chunk outgrew chunk-0's calibration
-            profile = bool(self.session.properties.get("chunk_profile",
-                                                       False))
             for i in range(1, grid.nchunks):
-                t0 = TR.clock_ns() if profile else 0
-                out, guard, ov = jitted(res_list, grid.chunk_args(i))
-                part, cnt = cjit(out)
-                if profile:  # diagnostics only: syncing kills pipelining
-                    jax.block_until_ready(part)
-                    print(f"chunk_profile: chunk {i} "
-                          f"{(TR.clock_ns() - t0) / 1e6:.0f}ms",
-                          file=sys.stderr)
-                if any(part.columns[name].dictionary is not d
-                       for name, d in dicts0.items()):
-                    return None  # unstable dictionaries: caller falls back
+                with TR.span("chunk.launch"):
+                    out, guard, ov = self._launch(jitted, res_list,
+                                                  grid.chunk_args(i))
+                    part, cnt = cjit(out)
+                    if any(part.columns[name].dictionary is not d
+                           for name, d in dicts0.items()):
+                        return None  # unstable dictionaries: caller falls back
+                    acc, n = fjit(acc, n, part)
                 guards.append(guard)
                 overflows.append(ov)
                 cap_over.append(cnt > cap)
-                acc, n = fjit(acc, n, part)
-            n_host = int(n)
-            if cap_over and bool(jnp.any(jnp.stack(cap_over))):
+            with TR.span("chunk.sync"):
+                n_host, cap_overflow, overflow, tripped = jax.device_get((
+                    n, jnp.any(jnp.stack(cap_over)) if cap_over else False,
+                    jnp.any(jnp.stack(overflows)),
+                    jnp.any(jnp.stack(guards))))
+            if cap_overflow:
                 return None  # recalibrate via the exact syncing loop
-            if bool(jnp.any(jnp.stack(overflows))):
+            if overflow:
                 raise _CompactOverflow
-            if bool(jnp.any(jnp.stack(guards))):
+            if tripped:
                 raise Unchunkable("static guard tripped in chunk loop")
+            n_host = int(n_host)
             if n_host <= A:
+                self._ran_in("accumulate")
                 sel = jnp.arange(A + 1) < n_host
                 out_cols = {name: c for name, c in acc.columns.items()}
                 return Batch(out_cols, sel)
             if A >= budget:
                 raise Unchunkable(
                     f"accumulator exceeds budget ({n_host} rows)")
+            self._count("chunk_loop_redos")
             A *= 4  # grown accumulator, re-run the loop
 
     def _chunk_loop_syncing(self, jitted, res_list, grid, budget,
@@ -1336,10 +1378,13 @@ class _FragmentRunner:
         overflows = list(overflows or [])
         buffered = sum(p.capacity for p in parts)
         for i in range(start, grid.nchunks):
-            out, guard, ov = jitted(res_list, grid.chunk_args(i))
+            with TR.span("chunk.launch"):
+                out, guard, ov = self._launch(jitted, res_list,
+                                              grid.chunk_args(i))
             guards.append(guard)
             overflows.append(ov)
-            part = K.compact(out)  # host-syncs the live count
+            with TR.span("chunk.sync"):
+                part = K.compact(out)  # host-syncs the live count
             parts.append(part)
             buffered += part.capacity
             if buffered > budget:
@@ -1347,8 +1392,13 @@ class _FragmentRunner:
                 # be buffered chunk-wise — bail BEFORE exhausting HBM
                 raise Unchunkable(
                     f"exchange buffer exceeds budget ({buffered} rows)")
-        if bool(jnp.any(jnp.stack(overflows))):
+        with TR.span("chunk.sync"):
+            overflow, tripped = jax.device_get((
+                jnp.any(jnp.stack(overflows)), jnp.any(jnp.stack(guards))))
+        if overflow:
             raise _CompactOverflow
-        if bool(jnp.any(jnp.stack(guards))):
+        if tripped:
             raise Unchunkable("static guard tripped in chunk loop")
-        return K.concat_batches(parts) if len(parts) > 1 else parts[0]
+        self._ran_in("syncing")
+        with TR.span("chunk.concat"):
+            return K.concat_batches(parts) if len(parts) > 1 else parts[0]

@@ -42,6 +42,12 @@ SCOPE_VERSION = 2
 #: of SCOPE_VERSION empties this table.
 LATE_SCOPES: Dict[str, str] = {"Window": "w1"}     # k:window
 
+#: The mark behind the fingerprint in the name of every program of the
+#: chunk loop (`exec/chunked._FragmentRunner._loop_tag`), which alone opens
+#: `k:chunk_scan`: a scope younger than SCOPE_VERSION, like LATE_SCOPES',
+#: that no plan node but the loop decides.  Emptied by the same bump.
+CHUNK_SCAN_MARK = "c1"
+
 
 def late_scope_marks(node) -> str:
     """The marks of LATE_SCOPES for the plan under `node`, in the table's
@@ -107,6 +113,14 @@ SPANS: Dict[str, tuple] = {
     "exec.data_load": ("data on device",
                        "compile_cache.data_load: a column set born on the device or placed "
                        "there, timed to ready"),
+    "chunk.launch": ("executor",
+                     "chunked runner: one chunk's program and its compaction, dispatched "
+                     "(the host waits here too while the device's queue is full)"),
+    "chunk.sync": ("executor",
+                   "chunked runner: the host blocked on the device in a loop (chunk 0's "
+                   "calibrating compact, the partial aggregate's ratio window, the guards "
+                   "after the loop, every chunk of the syncing loop)"),
+    "chunk.concat": ("executor", "chunked runner: a loop's buffered parts into one batch"),
 }
 
 #: names built at run time (chunked and cluster modes), by their prefix
@@ -129,6 +143,7 @@ KERNEL_SCOPES: Dict[str, str] = {
     "k:fused_group_sums.operand": "the stack and pad that build fused_group_sums' operand",
     "k:compact": "executor._compact_batch and kernels.compact: live rows to the front",
     "k:scan_filter": "executor._exec_filter: the predicate over a scan's rows, into the selection mask",
+    "k:chunk_scan": "chunked runner._scan_builder: a chunk's columns and selection mask generated in its program (the family grid's build_scan)",
     "k:window": "window.execute_window: partition and peer boundaries, frame bounds, the functions' prefix and segmented scans, the gather into sorted order (its sort stays k:sort's)",
     "x:repartition": "parallel/exchange.repartition_batch: one sort by destination (key hash) that carries the columns, the send buffer as contiguous slices, all_to_all",
     "x:all_gather": "parallel/exchange.all_gather_batch: a shard's rows on every shard",

@@ -134,6 +134,17 @@ class QueryStats:
     df_chunks_pruned: int = 0
     df_splits_pruned: int = 0
     df_wait_ms: float = 0.0
+    # the chunk loop (exec/chunked.py; counted every run, warm or cold):
+    # per-chunk program launches, the calibrating chunk 0 and every
+    # redo's included (a query over a grid of n chunks reads n a loop);
+    # loops run again (a compact bound grown, a chunk past chunk 0's
+    # calibrated capacity, an accumulator grown or refused); the mode the
+    # loops finished in: pipelined | accumulate | syncing, comma-joined
+    # where a query's loops differ.  The chunks a loop skipped are
+    # df_chunks_pruned above.
+    chunks_run: int = 0
+    chunk_loop_redos: int = 0
+    chunk_loop_mode: str = ""
     # fragment fusion (plan/distribute.fuse_fragments): fragments this
     # cluster query spliced into fused shard_map super-fragments (0 =
     # the per-fragment HTTP path ran, incl. after a fused-attempt
