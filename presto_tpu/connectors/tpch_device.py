@@ -32,8 +32,10 @@ from __future__ import annotations
 import itertools
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from presto_tpu import types as T
 from presto_tpu.batch import Column, Dictionary
@@ -155,6 +157,141 @@ def _order_custkey(row0, n, sf):
 
 
 # ---------------------------------------------------------------------------
+# line slots -> their orders, tile by tile
+# ---------------------------------------------------------------------------
+
+#: Order values are laid out in rows of _ROW orders, so that every slice
+#: the expansion takes is whole rows: a gather XLA runs as one op.
+_ROW = 128
+#: Line slots of a tile (on a TPU v5e, an SF100 chunk's 8 M lines took
+#: 5.5 ms at 128 and 5.9 ms at 256).  A tile's lines belong to the
+#: WINDOW_LINES orders from the one that holds its first line (every
+#: order has a line), which lie in the WINDOW_LINES // _ROW + 1 rows from
+#: that order's row: each line finds its order among their starts by a
+#: compare and a max over the window (`_window_max`).
+WINDOW_LINES = 128
+#: Line slots of a coarse tile.  A search of the rows' first starts finds
+#: each coarse tile's first row; its tiles' first rows are counted among
+#: the first starts of the 2 * _ROW rows from there.
+COARSE_LINES = 128 * _ROW
+_PAYLOAD_BITS = 12   # an order's payload: its date less START_DATE (< 2376)
+_START_BIAS = 8      # a tile's first order starts at most 6 lines before it
+_WINDOW_ROWS = WINDOW_LINES // _ROW + 1
+_SLOT_BITS = (_WINDOW_ROWS * _ROW - 1).bit_length()
+_START_SHIFT = _PAYLOAD_BITS + _SLOT_BITS
+assert WINDOW_LINES % _ROW == 0 and COARSE_LINES % WINDOW_LINES == 0 \
+    and COARSE_LINES <= _ROW * _ROW \
+    and (WINDOW_LINES + _START_BIAS + 1) << _START_SHIFT < 2 ** 31
+#: tiles a grid step of the window kernel holds, one a lane
+_KERNEL_TILES = 128
+
+
+def _window_max(key):
+    """got[t, q] = the largest key[t, j] under (q + _START_BIAS + 1) <<
+    _START_SHIFT, for the WINDOW_LINES line slots q of each tile t: a
+    Pallas kernel that holds _KERNEL_TILES tiles' windows in VMEM, tiles
+    along the lanes, and walks the window a row at a time.  The compare
+    is never in memory: XLA's CPU backend writes it out whole, and on a
+    TPU v5e XLA's fused reduction took 8.0 ms an SF100 chunk, the kernel
+    5.5 ms."""
+    from jax.experimental import pallas as pl
+
+    from presto_tpu.exec import kernels as K
+
+    nt, width = key.shape
+    L, TB = WINDOW_LINES, _KERNEL_TILES
+    key = jnp.pad(key, ((0, -nt % TB), (0, 0)))
+
+    def kernel(k_ref, o_ref):
+        below = (lax.broadcasted_iota(jnp.int32, (L, TB), 0)
+                 + _START_BIAS + 1) << _START_SHIFT
+
+        def step(j, acc):
+            rows = k_ref[pl.ds(pl.multiple_of(j * 8, 8), 8), :]
+            for r in range(8):
+                row = rows[r:r + 1, :]
+                acc = jnp.maximum(acc, jnp.where(row < below, row, 0))
+            return acc
+
+        o_ref[...] = lax.fori_loop(0, width // 8, step,
+                                   jnp.zeros((L, TB), jnp.int32))
+
+    # Mosaic takes 32-bit types only: trace the kernel with x64 off
+    with jax.enable_x64(False):
+        got = pl.pallas_call(
+            kernel, grid=(key.shape[0] // TB,),
+            in_specs=[pl.BlockSpec((width, TB), lambda i: (0, i))],
+            out_specs=pl.BlockSpec((L, TB), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((L, key.shape[0]), jnp.int32),
+            interpret=K._pallas_interpret(), name="line_orders")(key.T)
+    return got.T[:nt]
+
+
+def expand_orders(counts: jnp.ndarray, n: int, payload=None):
+    """For `n` line slots of orders laid end to end, order i holding
+    counts[i] >= 1 lines: each slot's order index, its line number in the
+    order (from 1) and the order's `payload` (ints in [0, 2**12), or
+    None), int32 (n,) each.  The order index is np.repeat(arange(len(
+    counts)), counts), without the scatter-add, cumsum over the slots and
+    gather of one index a slot that a repeat takes on the device: the
+    starts are cumsummed over the orders, and each tile of WINDOW_LINES
+    slots takes whole rows of them.  Slots past sum(counts) hold garbage
+    for the caller to mask."""
+    L, W, L1, span = WINDOW_LINES, _ROW, COARSE_LINES, _WINDOW_ROWS
+    m = counts.shape[0]
+    counts = counts.astype(jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    if payload is None:
+        payload = jnp.zeros(m, jnp.int32)
+    n_coarse = -(-n // L1)
+    far = n_coarse * L1 + L1               # a start past every slot
+    nrow = m // W + span + 1               # real rows, then rows of far starts
+
+    def rows(a, fill, nr):
+        return jnp.concatenate(
+            [a, jnp.full(nr * W - a.shape[0], fill, jnp.int32)]).reshape(nr, W)
+
+    start_rows = rows(starts, far, nrow)
+    table = jnp.concatenate([start_rows, rows(payload.astype(jnp.int32), 0,
+                                              nrow)], axis=1)
+    heads = start_rows[:, 0]
+    # coarse tiles: the row that holds each one's first line, by search
+    t1 = jnp.arange(n_coarse, dtype=jnp.int32) * L1
+    row1 = jnp.searchsorted(heads, t1, side="right").astype(jnp.int32) - 1
+    # its tiles' rows: counted among the heads of 2 * W rows from the
+    # row-of-rows that holds row1 (a coarse tile spans <= L1 / W + 1 rows)
+    base1 = row1 // W * W
+    hwin = jnp.take(rows(heads, far, nrow // W + 2),
+                    row1[:, None] // W + jnp.arange(2), axis=0,
+                    mode="clip").reshape(n_coarse, 2 * W)
+    x = t1[:, None] + jnp.arange(0, L1, L, dtype=jnp.int32)
+    first_row = (base1[:, None] - 1 + jnp.sum(
+        hwin[:, :, None] <= x[:, None, :], axis=1,
+        dtype=jnp.int32)).reshape(-1)
+    # each tile's window: the starts and payloads of `span` whole rows
+    win = jnp.take(table, first_row[:, None] + jnp.arange(span), axis=0,
+                   mode="clip")
+    nt = first_row.shape[0]
+    t0 = jnp.arange(nt, dtype=jnp.int32) * L
+    # key: the start (tile-relative, biased, clipped), the window slot, the
+    # payload.  The starts increase along the window, so the largest key
+    # of a start at or before a line is its order's.
+    rel = jnp.clip(win[:, :, :W].reshape(nt, span * W) - t0[:, None]
+                   + _START_BIAS, 0, L + _START_BIAS)
+    slot = jnp.arange(span * W, dtype=jnp.int32)
+    key = (rel << _START_SHIFT) | (slot << _PAYLOAD_BITS) \
+        | win[:, :, W:].reshape(nt, span * W)
+    got = _window_max(key)
+    order = first_row[:, None] * W + ((got >> _PAYLOAD_BITS)
+                                      & ((1 << _SLOT_BITS) - 1))
+    line = (jnp.arange(L, dtype=jnp.int32) + _START_BIAS + 1
+            - (got >> _START_SHIFT))
+    flat = lambda a: a.reshape(-1)[:n]  # noqa: E731
+    return (flat(order), flat(line),
+            flat(got & ((1 << _PAYLOAD_BITS) - 1)))
+
+
+# ---------------------------------------------------------------------------
 # enum dictionaries: device code -> sorted-unique dictionary code LUTs
 # ---------------------------------------------------------------------------
 
@@ -273,6 +410,13 @@ def _gen_orders(sf, row0, n, cols):
     return out
 
 
+#: lineitem columns made from their order's date
+_ODATE_COLUMNS = {"l_shipdate", "l_commitdate", "l_receiptdate",
+                  "l_returnflag", "l_linestatus"}
+#: lineitem columns that take their order's values from expand_orders
+WINDOW_COLUMNS = _ODATE_COLUMNS | {"l_orderkey", "l_linenumber"}
+
+
 def _gen_lineitem(sf, order_row0, order_row1, cols,
                   n_orders=None, line_row0=None, pad=None):
     """row0/row1 index ORDERS rows, like the host generator.  Chunked
@@ -283,8 +427,8 @@ def _gen_lineitem(sf, order_row0, order_row1, cols,
     E = _enums()
     if n_orders is None:
         n_orders = order_row1 - order_row0
-    oi = jnp.arange(n_orders, dtype=jnp.int64) + order_row0
-    counts = _lines_per_order(oi)
+    counts = _lines_per_order(jnp.arange(n_orders, dtype=jnp.int64)
+                              + order_row0)
     if pad is None:
         lo, hi = H.lineitem_offsets(order_row0, order_row1)
         n = hi - lo
@@ -293,16 +437,16 @@ def _gen_lineitem(sf, order_row0, order_row1, cols,
         n = pad
         row0 = line_row0
     out = {}
-    need_odate = any(c in cols for c in
-                     ("l_shipdate", "l_commitdate", "l_receiptdate",
-                      "l_returnflag", "l_linestatus"))
-    if "l_orderkey" in cols:
-        out["l_orderkey"] = (jnp.repeat(_orderkey(oi), counts,
-                                        total_repeat_length=n), None)
+    need_odate = bool(_ODATE_COLUMNS & cols)
     odate = None
-    if need_odate:
-        odate = jnp.repeat(_order_dates(order_row0, len(oi)), counts,
-                           total_repeat_length=n).astype(jnp.int64)
+    if WINDOW_COLUMNS & cols:
+        line_order, linenumber, odate = expand_orders(
+            counts, n, (_order_dates(order_row0, n_orders) - H.START_DATE)
+            if need_odate else None)
+        odate = odate.astype(jnp.int64) + H.START_DATE
+    if "l_orderkey" in cols:
+        out["l_orderkey"] = (_orderkey(line_order.astype(jnp.int64)
+                                       + order_row0), None)
     pk = None
     if "l_partkey" in cols or "l_suppkey" in cols \
             or "l_extendedprice" in cols:
@@ -314,11 +458,7 @@ def _gen_lineitem(sf, order_row0, order_row1, cols,
         slot = _u(t, "suppslot", row0, n, 0, H.SUPP_PER_PART - 1)
         out["l_suppkey"] = (_ps_suppkey(pk, slot, sf), None)
     if "l_linenumber" in cols:
-        starts = jnp.cumsum(counts) - counts
-        out["l_linenumber"] = ((jnp.arange(n, dtype=jnp.int64)
-                                - jnp.repeat(starts, counts,
-                                             total_repeat_length=n) + 1)
-                               .astype(jnp.int32), None)
+        out["l_linenumber"] = (linenumber, None)
     qty = None
     if "l_quantity" in cols or "l_extendedprice" in cols:
         qty = _u(t, "quantity", row0, n, 1, 50).astype(jnp.float64)
@@ -606,6 +746,13 @@ class TpchChunkGrid:
             return None
         key = lambda oi: (oi // 8) * 32 + oi % 8 + 1  # noqa: E731
         return int(key(o0)), int(key(o1 - 1))
+
+    def window_lines(self, table: str, cols) -> int:
+        """Line slots a chunk's scan of `cols` expands from its orders
+        (expand_orders): QueryStats.lineitem_window_lines a launch."""
+        if table == "lineitem" and WINDOW_COLUMNS & set(cols):
+            return self.cap_lines
+        return 0
 
     def chunk_args(self, i: int):
         """Traced scalars for chunk i — a fixed pytree so ONE jitted

@@ -579,6 +579,7 @@ class _FragmentRunner:
         # and modes happen host-side every run, unlike the trace-time
         # totals above which warm runs replay)
         self.run_stats: Dict[str, object] = {}
+        self._launch_window_lines = 0  # the running loop's, a launch
         # per-RUN fragment wall clocks (EXPLAIN ANALYZE attribution +
         # the chunked fragment trace spans)
         self.frag_wall_ns: Dict[object, int] = {}
@@ -991,6 +992,10 @@ class _FragmentRunner:
         mult = self.bound_mult.get(frag.fid, 1)
         ids = list(resident)
         mesh_n = int(self.session.properties.get("chunk_mesh_devices", 1))
+        window = getattr(grid, "window_lines", None)
+        self._launch_window_lines = max(mesh_n, 1) * sum(
+            window(n.table, n.assignments.values()) for n in chunk_nodes) \
+            if window is not None else 0
         mon = jitted4 = None
         if mesh_n > 1:
             jitted = self._mesh_exec(frag, chunk_nodes, resident, ids,
@@ -1135,8 +1140,12 @@ class _FragmentRunner:
             return K.concat_batches(parts) if len(parts) > 1 else parts[0]
 
     def _launch(self, program, res_list, chunk_args):
-        """One chunk's program, dispatched: QueryStats.chunks_run."""
+        """One chunk's program, dispatched: QueryStats.chunks_run and
+        lineitem_window_lines."""
         self._count("chunks_run")
+        if self._launch_window_lines:
+            self.run_stats["lineitem_window_lines"] = self.run_stats.get(
+                "lineitem_window_lines", 0) + self._launch_window_lines
         return program(res_list, chunk_args)
 
     def _count(self, key: str) -> None:
@@ -1270,9 +1279,9 @@ class _FragmentRunner:
         across nodes (execution/scheduler/group/LifespanScheduler.java).
         Callers stream it over a _MeshGridView whose "chunks" are
         supersteps."""
-        from jax import shard_map
         from jax.sharding import PartitionSpec as PS
 
+        from presto_tpu.parallel.dist_executor import _shard_mapped
         from presto_tpu.parallel.mesh import AXIS, make_mesh
 
         args = [resident[i] for i in ids]
@@ -1293,9 +1302,8 @@ class _FragmentRunner:
                 return (out, jnp.asarray(guard).reshape(1),
                         jnp.asarray(ov).reshape(1))
 
-            sharded = shard_map(fn, mesh=mesh,
-                                in_specs=(PS(), PS(AXIS)),
-                                out_specs=(PS(AXIS), PS(AXIS), PS(AXIS)))
+            sharded = _shard_mapped(fn, mesh, (PS(), PS(AXIS)),
+                                    (PS(AXIS), PS(AXIS), PS(AXIS)))
             # no AOT example: the live jit's automatic input resharding
             # (host-stacked superstep args -> the mesh axis) is load-
             # bearing here; an AOT signature would pin one placement

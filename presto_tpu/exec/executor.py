@@ -56,7 +56,7 @@ def _merge_sort_stats(stats, counts: dict) -> None:
               "df_filters_produced", "df_filters_applied",
               "df_filters_declined",
               "df_rows_pruned", "df_chunks_pruned", "df_splits_pruned",
-              "chunks_run", "chunk_loop_redos",
+              "chunks_run", "lineitem_window_lines", "chunk_loop_redos",
               "fragments_fused", "exchange_bytes_host",
               "exchange_bytes_collective", "exchange_bytes_sketch",
               "approx_rewrites",

@@ -141,8 +141,11 @@ class QueryStats:
     # calibrated capacity, an accumulator grown or refused); the mode the
     # loops finished in: pipelined | accumulate | syncing, comma-joined
     # where a query's loops differ.  The chunks a loop skipped are
-    # df_chunks_pruned above.
+    # df_chunks_pruned above.  lineitem_window_lines: the lineitem line
+    # slots whose order values the launches took from a tile's window
+    # (connectors/tpch_device.expand_orders), a chunk's capacity a launch.
     chunks_run: int = 0
+    lineitem_window_lines: int = 0
     chunk_loop_redos: int = 0
     chunk_loop_mode: str = ""
     # fragment fusion (plan/distribute.fuse_fragments): fragments this

@@ -105,25 +105,27 @@ def test_mesh_rollup_plan_keeps_its_bytes(case):
 
 #: sha256 of the lowered text (no debug info, as JAX's persistent cache
 #: hashes it) of every program the TPC-H mesh cell builds at this scale,
-#: the generators of its born-sharded scans among them, on f4878b1.  The
-#: same text under the same module name is the same compile-cache key in
-#: one environment: `sf1_mesh4_join` keeps hitting its entries although
-#: `shard_generator` now takes its arguments from the table's grid.
+#: the generators of its born-sharded scans among them, on f4878b1 (the
+#: lineitem generators' since a line takes its order's values from
+#: tpch_device.expand_orders, not jnp.repeat).  The same text under the
+#: same module name is the same compile-cache key in one environment:
+#: `sf1_mesh4_join` keeps hitting its entries although `shard_generator`
+#: now takes its arguments from the table's grid.
 PARENT_MESH_PROGRAMS = {
     "tpch_q1": [
         ["jit_fn_s2_eaf2a55c",
          "87743111648dbd8fd53d762e03b7cad7804a3fe1f95ae06f87078b01c1169c7b"],
         ["jit_shard_s2",
-         "5dc7bfdce73633921d937c1e251eb56787f7205416b5220f164105498e33230a"]],
+         "7a767414f2d70f69c08eefd1282d949fb3cf98f0c7d4e280034386ce4c5e4e23"]],
     "tpch_q3": [
         ["jit_fn_s2_07d4fa6a",
          "4181a28768a2902f5c6eca451854f71b47367e95efbe4312ff2b2be41e7db55f"],
         ["jit_shard_s2",
          "5ecbd63be135743a38d17eb14e98c2c56f19cbd3c26867d2a0745fa8012d0b50"],
         ["jit_shard_s2",
-         "9089da0dd36ff61a9197f3e726e935b43b2550ab1c41974c9d3b681bd127efae"],
+         "84d71a90b4c24aa0f413cf2f5886df54b18b0995cbea71a14047c4fd89478e24"],
         ["jit_shard_s2",
-         "d090539f9e2d83cfa58d69c96fa025459ab82b7d220678ab00e1b9e67792eff0"]],
+         "9089da0dd36ff61a9197f3e726e935b43b2550ab1c41974c9d3b681bd127efae"]],
 }
 
 

@@ -111,6 +111,20 @@ def test_pack_fetch_12_columns(one_chip):
              *[((n,), d) for d in dts])
 
 
+def test_line_orders_kernel_at_an_sf100_chunk(one_chip, as_tpu):
+    """connectors/tpch_device.expand_orders over one SF100 chunk (2 M
+    orders, 8,003,552 line slots): the window kernel is the chip's, no
+    scatter, and the compare of each tile's lines with its window stays
+    out of memory (written out it would be ~8 GB of temporaries)."""
+    from presto_tpu.connectors import tpch_device as D
+
+    c = _compile(lambda n, p: D.expand_orders(n, 8_003_552, p), one_chip,
+                 ((2_000_000,), jnp.int64), ((2_000_000,), jnp.int32))
+    text = c.as_text()
+    assert "tpu_custom_call" in text and " scatter(" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
 SCOPED_ROWS = 8_192   # the names are the point here, not the sizes: the
 # v5e's compiler takes minutes over 64-bit sorts of N_ROWS
 
@@ -158,14 +172,13 @@ def test_scoped_kernels_lower_and_keep_their_names(one_chip, as_tpu, kernel):
         assert scope in text, scope
 
 
-#: table, scale, columns: what the cell sf1_mesh4_join scans, at SF1 — but
-#: lineitem's columns that repeat an order's value over its lines at
-#: SF0.01: `jnp.repeat` over a shard's 1.5 M lines takes the v5e's compiler
-#: ~110 s (5 s at SF0.01; on the chip it is part of a cold set-up)
+#: table, scale, columns: what the cell sf1_mesh4_join scans, at SF1
+#: (lineitem's columns that take their order's values run
+#: connectors/tpch_device.expand_orders, its window kernel the chip's)
 SHARDED_SCANS = {
     "lineitem_sf1": ("lineitem", 1.0, ["l_quantity", "l_extendedprice",
                                        "l_discount", "l_tax"]),
-    "lineitem_repeats": ("lineitem", 0.01, [
+    "lineitem_repeats": ("lineitem", 1.0, [
         "l_orderkey", "l_quantity", "l_extendedprice", "l_discount", "l_tax",
         "l_returnflag", "l_linestatus", "l_shipdate"]),
     "orders_sf1": ("orders", 1.0, ["o_orderkey", "o_custkey", "o_orderdate",
@@ -180,7 +193,8 @@ SHARDED_SCANS = {
 
 
 @pytest.mark.parametrize("scan", sorted(SHARDED_SCANS))
-def test_sharded_generation_program_compiles_for_four_chips(topology, scan):
+def test_sharded_generation_program_compiles_for_four_chips(topology, as_tpu,
+                                                          scan):
     """The program in which every chip of the mesh generates its own
     range of a table (parallel/dist_executor.shard_generator): one
     shard_map over the described 2x2, the ranges as traced per-shard
